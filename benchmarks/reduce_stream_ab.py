@@ -26,9 +26,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import thrill_tpu  # noqa: F401,E402
-from thrill_tpu.common.platform import force_cpu_unless_accelerator  # noqa: E402
+from thrill_tpu.common.platform import require_accelerator  # noqa: E402
 
-force_cpu_unless_accelerator()
+require_accelerator()
 
 import jax  # noqa: E402
 

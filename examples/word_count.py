@@ -73,6 +73,15 @@ def word_count_text_device(ctx: Context, path: str,
                              FieldReduce({"w": "first", "c": "sum"}))
 
 
+def _word_key(t):
+    return t["w"]
+
+
+# module-level functors: compiled programs are cached on the function
+# objects, so a second word_count_fixed() reuses the first's
+_COUNT_WORDS = FieldReduce({"w": "first", "c": "sum"})
+
+
 def word_count_fixed(ctx: Context, packed: np.ndarray):
     """Device WordCount over pre-packed fixed-width words.
 
@@ -81,8 +90,7 @@ def word_count_fixed(ctx: Context, packed: np.ndarray):
     """
     d = ctx.Distribute({"w": packed,
                         "c": np.ones(len(packed), dtype=np.int64)})
-    return d.ReduceByKey(lambda t: t["w"],
-                         FieldReduce({"w": "first", "c": "sum"}))
+    return d.ReduceByKey(_word_key, _COUNT_WORDS)
 
 
 def main():

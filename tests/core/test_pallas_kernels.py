@@ -128,3 +128,46 @@ def test_pallas_knob_cached_at_mesh_construction(monkeypatch):
     # no mesh in scope: the live env read is the documented fallback
     monkeypatch.setenv("THRILL_TPU_PALLAS", "1")
     assert pk.pallas_enabled(_Mex())
+
+
+def _pallas_calls(jaxpr):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            yield e
+        for v in e.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("kernel", [
+    "partition_histogram", "segment_sum", "presence_fill",
+    "stable_partition_offsets"])
+def test_block_index_maps_trace_to_i32_under_x64(kernel):
+    """The package runs with x64 on, where a Python ``0`` in a BlockSpec
+    index map traces to i64 beside the i32 grid index — Mosaic refuses
+    that mix on the chip while interpret mode accepts it silently."""
+    import jax
+    from thrill_tpu.core import pallas_sort as ps
+
+    assert jax.config.jax_enable_x64
+    ids = jnp.zeros(600, jnp.int32)
+    vals = jnp.zeros(600, jnp.float32)
+    fn = {
+        "partition_histogram":
+            lambda: pk.partition_histogram_pallas(ids, 4, interpret=True),
+        "segment_sum":
+            lambda: pk.segment_sum_pallas(ids, vals, 4, interpret=True),
+        "presence_fill":
+            lambda: pk.presence_fill_pallas(ids, vals > 0, 4,
+                                            interpret=True),
+        "stable_partition_offsets":
+            lambda: ps.stable_partition_offsets_pallas(ids, 4,
+                                                       interpret=True),
+    }[kernel]
+    calls = list(_pallas_calls(jax.make_jaxpr(fn)().jaxpr))
+    assert calls
+    for eqn in calls:
+        for bm in eqn.params["grid_mapping"].block_mappings:
+            dts = [v.aval.dtype for v in bm.index_map_jaxpr.jaxpr.outvars]
+            assert all(dt == np.int32 for dt in dts), dts

@@ -7,20 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from thrill_tpu.common.platform import has_ragged_all_to_all
 
-# this container's jax/jaxlib predates lax.ragged_all_to_all entirely
-# (added in jax 0.5); the trace/lowering contract can only be checked
-# where the op exists — on platforms without it these cases are a
-# known environment limit, not a regression. The capability probe is
-# the shared common/platform helper, not a per-file hasattr copy.
-_NEEDS_RAGGED_OP = pytest.mark.skipif(
-    not has_ragged_all_to_all(),
-    reason="jax.lax.ragged_all_to_all not available in this jax "
-           "version (XLA:CPU container); execution is TPU-only anyway")
-
-
-@_NEEDS_RAGGED_OP
 def test_ragged_path_traces_and_lowers(monkeypatch):
     from thrill_tpu.parallel.mesh import MeshExec
     from thrill_tpu.data import exchange
@@ -44,7 +31,6 @@ def test_ragged_path_traces_and_lowers(monkeypatch):
         "UNIMPLEMENTED" in str(ei.value), str(ei.value)[:200]
 
 
-@_NEEDS_RAGGED_OP
 def test_lower_ragged_exchange_plan():
     """The dryrun's plan validation (lower WITHOUT compiling): the
     lowered module must contain the ragged collective, for multiple
@@ -76,13 +62,6 @@ def test_ragged_off_tpu_warns_loudly(capsys, monkeypatch):
         exchange._exchange_planned(mex, treedef, None, leaves, S)
     err = capsys.readouterr().err
     assert "UNIMPLEMENTED" in err and "ragged" in err
-
-
-def test_probe_single_sourced():
-    """The capability probe is one common helper; the exchange planner
-    and every skipif gate share it (no hasattr copies to drift)."""
-    assert has_ragged_all_to_all() == hasattr(jax.lax,
-                                              "ragged_all_to_all")
 
 
 def test_landing_offsets_math():

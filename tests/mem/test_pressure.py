@@ -388,6 +388,24 @@ def test_is_oom_error_classification():
     assert not pressure.is_oom_error(KeyError("RESOURCE_EXHAUSTED"))
 
 
+def test_compile_time_vmem_refusal_is_not_an_oom():
+    """The v5e compiler's refusal of a kernel that does not fit VMEM
+    says RESOURCE_EXHAUSTED too (text as printed for presence_fill at
+    8192 registers, PR 22). Spilling HBM cannot make it compile, so the
+    ladder must let it surface as itself."""
+    refusal = RuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem "
+        "while allocating on stack for %_lambda_.1 = "
+        "s32[8192,1]{1,0:T(8,128)S(1)} custom-call(%reshape.2, "
+        "%reshape.59), custom_call_target=\"tpu_custom_call\". Scoped "
+        "allocation with size 28.12M and limit 16.00M exceeded scoped "
+        "vmem limit by 12.12M.")
+    assert not pressure.is_oom_error(refusal)
+    assert pressure.is_oom_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm. "
+        "Used 17.2G of 15.75G hbm."))
+
+
 def test_admission_never_spills_the_dispatchs_own_sources(monkeypatch):
     """Spilling a node whose buffers feed the IN-FLIGHT dispatch frees
     no HBM (args keep the arrays alive) and buys a restore round trip

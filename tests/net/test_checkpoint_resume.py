@@ -48,7 +48,7 @@ def _launch(nproc, ckpt_dir, extra_env=None):
             "PYTHONPATH": repo_root + os.pathsep
             + env.get("PYTHONPATH", ""),
             "THRILL_TPU_SECRET": "test-cluster-secret",
-            "THRILL_TPU_COMPILE_CACHE": _COMPILE_CACHE_DIR,
+            "JAX_COMPILATION_CACHE_DIR": _COMPILE_CACHE_DIR,
             "THRILL_TPU_HOSTLIST": hostlist,
             "THRILL_TPU_RANK": str(rank),
             "THRILL_TPU_CKPT_DIR": ckpt_dir,

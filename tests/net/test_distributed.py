@@ -62,13 +62,13 @@ def _launch_children(nproc, net="tcp", child=CHILD, extra_env=None):
             "PYTHONPATH": repo_root + os.pathsep
             + env.get("PYTHONPATH", ""),
             "THRILL_TPU_SECRET": "test-cluster-secret",
-            # persistent XLA compile cache (explicit non-default dir is
-            # honored even on CPU): children recompiling every jitted
-            # program from scratch is what pushed the fuzz configs past
+            # persistent XLA compile cache (jax reads the variable
+            # itself, on every backend): children recompiling every
+            # jitted program from scratch is what pushed the fuzz configs past
             # their load-scaled deadlines on a contended 1-core box —
             # with the cache, the second child reuses the first's
             # compiles within a run and repeat suite runs start warm
-            "THRILL_TPU_COMPILE_CACHE": _COMPILE_CACHE_DIR,
+            "JAX_COMPILATION_CACHE_DIR": _COMPILE_CACHE_DIR,
         })
         env.update(extra_env or {})
         if net == "mpi":

@@ -52,7 +52,7 @@ def _run_supervised(tmp_path, extra_env=None, timeout_s=420):
         + env.get("PYTHONPATH", ""),
         "THRILL_TPU_CKPT_DIR": ck,
         "TEST_STATE_DIR": state,
-        "THRILL_TPU_COMPILE_CACHE": _COMPILE_CACHE_DIR,
+        "JAX_COMPILATION_CACHE_DIR": _COMPILE_CACHE_DIR,
     })
     env.update(extra_env or {})
     p = subprocess.run(

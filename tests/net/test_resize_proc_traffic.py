@@ -45,7 +45,7 @@ def _env(ck, ports):
         "THRILL_TPU_CKPT_DIR": ck,
         "TEST_PORTS": " ".join(str(p) for p in ports),
         "THRILL_TPU_SECRET": "resize-traffic-secret",
-        "THRILL_TPU_COMPILE_CACHE": _COMPILE_CACHE_DIR,
+        "JAX_COMPILATION_CACHE_DIR": _COMPILE_CACHE_DIR,
         "THRILL_TPU_HANG_TIMEOUT_S": "60",
         # drain budget for the in-flight a2/b2 jobs: at W=3 they miss
         # the W=2 XLA compile cache, and three ranks compiling

@@ -183,7 +183,7 @@ class DIABase:
         elif fusion.enabled() and consume \
                 and type(self).compute_plan is not DIABase.compute_plan:
             # statically fusible op that cannot defer THIS pull: name
-            # the reason (the explain() barrier taxonomy). Reaching
+            # the reason (the explain() barrier kinds). Reaching
             # this branch with consume=True means exactly one of these
             # two defer conditions failed.
             self._barrier_decision(

@@ -100,19 +100,9 @@ class SortNode(DIABase):
         key_fn = self.key_fn
 
         def trace(fctx, tree, mask, _bound):
-            cap = mask.shape[0]
-            words = keymod.encode_key_words(key_fn(tree))
-            iota = jnp.arange(cap, dtype=jnp.uint64)
-            from ...core.device_sort import argsort_words
-            sort_words = ([(~mask).astype(jnp.uint32)] + list(words)
-                          + [iota])
-            perm = argsort_words(sort_words)
-            from ...core import rowmove
-            leaves, td = jax.tree.flatten(tree)
-            out = rowmove.take_rows_multi(leaves, perm)
             count = jnp.sum(mask.astype(jnp.int32))
-            return (jax.tree.unflatten(td, out),
-                    jnp.arange(cap) < count)
+            return (_sort_rows_local(key_fn, tree, mask),
+                    jnp.arange(mask.shape[0]) < count)
 
         return fusion.Segment(label="Sort",
                               token=("sort_w1_fused", self.key_fn),
@@ -720,6 +710,39 @@ def _spill_run(pool, run, sort_key):
     return f
 
 
+def _sort_rows_local(key_fn: Callable, tree, valid):
+    """One worker's whole local sort, traced: key-only argsort of
+    (validity, key words, index), then the single payload gather.
+    ``valid=None`` means every row is valid — the validity word is
+    statically dropped (one fewer sort operand). Shared by the W == 1
+    program below and the fused W == 1 segment (SortNode._fuse_segment),
+    so the two cannot drift."""
+    from ...core.device_sort import argsort_words
+    from ...core.rowmove import take_rows_multi
+    leaves, td = jax.tree.flatten(tree)
+    words = keymod.encode_key_words(key_fn(tree))
+    sort_words = list(words) + [jnp.arange(leaves[0].shape[0],
+                                           dtype=jnp.uint64)]
+    if valid is not None:
+        sort_words = [(~valid).astype(jnp.uint32)] + sort_words
+    perm = argsort_words(sort_words)
+    return jax.tree.unflatten(td, take_rows_multi(leaves, perm))
+
+
+def _w1_sort_fn(key_fn: Callable, treedef, full: bool):
+    """The single-worker sort's per-shard function for ``mex.smap``. A
+    module-level builder so that tests/core/test_tpu_aot_compile.py
+    compiles this very program for the chip."""
+    def f(counts_dev, *ls):
+        tree = jax.tree.unflatten(treedef, [l[0] for l in ls])
+        valid = None if full \
+            else jnp.arange(ls[0].shape[1]) < counts_dev[0, 0]
+        out = _sort_rows_local(key_fn, tree, valid)
+        return tuple(o[None] for o in jax.tree.leaves(out))
+
+    return f
+
+
 def _device_sample_sort(shards: DeviceShards, key_fn: Callable,
                         token) -> DeviceShards:
     mex = shards.mesh_exec
@@ -751,28 +774,8 @@ def _device_sample_sort(shards: DeviceShards, key_fn: Callable,
         key1 = ("sort_w1", token, cap, full, treedef,
                 tuple((l.dtype, l.shape[2:]) for l in leaves))
 
-        def build_w1():
-            def f(counts_dev, *ls):
-                count = counts_dev[0, 0]
-                tree = jax.tree.unflatten(treedef, [l[0] for l in ls])
-                words = keymod.encode_key_words(key_fn(tree))
-                iota = jnp.arange(cap, dtype=jnp.uint64)
-                from ...core.device_sort import argsort_words
-                if full:
-                    sort_words = list(words) + [iota]
-                else:
-                    valid = jnp.arange(cap) < count
-                    sort_words = ([(~valid).astype(jnp.uint32)]
-                                  + list(words) + [iota])
-                perm = argsort_words(sort_words)
-                from ...core.rowmove import take_rows_multi
-                return tuple(
-                    o[None] for o in take_rows_multi([l[0] for l in ls],
-                                                     perm))
-
-            return mex.smap(f, 1 + len(leaves))
-
-        f1 = mex.cached(key1, build_w1)
+        f1 = mex.cached(key1, lambda: mex.smap(
+            _w1_sort_fn(key_fn, treedef, full), 1 + len(leaves)))
         out1 = f1(shards.counts_device(), *leaves)
         tree = jax.tree.unflatten(treedef, list(out1))
         return DeviceShards(mex, tree, shards.counts.copy())

@@ -47,8 +47,12 @@ COLS = BLOCK // SUBLANES   # 64 lanes per tile row
 MAX_ROWS = 1 << 24
 # one-hot register fill / segment sum are O(bins*n) lane-compares: a
 # clear win only while the bin column stays small (the preshuffle
-# _REG_MIN clamp's home turf); above these XLA's native scatter wins
-PRESFILL_MAX_REGS = 1 << 13
+# _REG_MIN clamp's home turf); above these XLA's native scatter wins.
+# They are also what fits VMEM: the (bins, 1) counter column pads 128x
+# under the (8, 128) tiling, and at 1 << 13 registers the v5e compiler
+# refuses presence_fill (28 MiB scoped against a 16 MiB limit;
+# tests/core/test_tpu_aot_compile.py holds both gates to the compiler)
+PRESFILL_MAX_REGS = 1 << 12
 SEGSUM_MAX_SEGS = 1 << 12
 
 _MISSING = object()
@@ -91,6 +95,17 @@ def _round_up(n: int, g: int) -> int:
     return ((n + g - 1) // g) * g
 
 
+# BlockSpec index maps. The package turns x64 on, under which a Python
+# ``0`` traces to i64 beside the i32 grid index, and Mosaic refuses the
+# mixed (i32, i64) return — so the constant is spelled as an i32.
+def _row_block(i):
+    return i, jnp.int32(0)
+
+
+def _first_block(i):
+    return jnp.int32(0), jnp.int32(0)
+
+
 def _hist_kernel(dest_ref, out_ref, *, num_bins_padded: int):
     from jax.experimental import pallas as pl
 
@@ -131,8 +146,8 @@ def partition_histogram_pallas(dest: jnp.ndarray, num_bins: int,
     out = pl.pallas_call(
         kernel,
         grid=(n_pad // BLOCK,),
-        in_specs=[pl.BlockSpec((SUBLANES, COLS), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bpad, 1), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec((SUBLANES, COLS), _row_block)],
+        out_specs=pl.BlockSpec((bpad, 1), _first_block),
         out_shape=jax.ShapeDtypeStruct((bpad, 1), jnp.int32),
         interpret=interpret,
     )(d2)
@@ -206,9 +221,9 @@ def segment_sum_pallas(seg_ids: jnp.ndarray, values: jnp.ndarray,
     out = pl.pallas_call(
         kernel,
         grid=(n_pad // BLOCK,),
-        in_specs=[pl.BlockSpec((SUBLANES, COLS), lambda i: (i, 0)),
-                  pl.BlockSpec((SUBLANES, COLS), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((spad, 1), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec((SUBLANES, COLS), _row_block),
+                  pl.BlockSpec((SUBLANES, COLS), _row_block)],
+        out_specs=pl.BlockSpec((spad, 1), _first_block),
         out_shape=jax.ShapeDtypeStruct((spad, 1), jnp.float32),
         interpret=interpret,
     )(s.reshape(-1, COLS), v.reshape(-1, COLS))
@@ -255,9 +270,9 @@ def presence_fill_pallas(h: jnp.ndarray, valid: jnp.ndarray,
     out = pl.pallas_call(
         kernel,
         grid=(n_pad // BLOCK,),
-        in_specs=[pl.BlockSpec((SUBLANES, COLS), lambda i: (i, 0)),
-                  pl.BlockSpec((SUBLANES, COLS), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((mpad, 1), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec((SUBLANES, COLS), _row_block),
+                  pl.BlockSpec((SUBLANES, COLS), _row_block)],
+        out_specs=pl.BlockSpec((mpad, 1), _first_block),
         out_shape=jax.ShapeDtypeStruct((mpad, 1), jnp.int32),
         interpret=interpret,
     )(hp.reshape(-1, COLS), vp.reshape(-1, COLS))

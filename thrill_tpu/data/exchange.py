@@ -418,10 +418,13 @@ def leaf_ranges_traced(xs, mask):
             x = jnp.minimum(x, jnp.uint64(i64max))
             smax = i64max
         m = mask.reshape((-1,) + (1,) * (x.ndim - 1))
-        lo = lax.pmin(jnp.min(jnp.where(m, x, smax))
-                      .astype(jnp.int64), AXIS)
-        hi = lax.pmax(jnp.max(jnp.where(m, x, info.min))
-                      .astype(jnp.int64), AXIS)
+        # gather the W local scalars and reduce them here: the TPU
+        # compiler lowers a 64-bit all-reduce only for sums ("Supported
+        # lowering only of Sum all reduce" for an s64 pmin)
+        lo = jnp.min(lax.all_gather(
+            jnp.min(jnp.where(m, x, smax)).astype(jnp.int64), AXIS))
+        hi = jnp.max(lax.all_gather(
+            jnp.max(jnp.where(m, x, info.min)).astype(jnp.int64), AXIS))
         rows.append(jnp.stack([lo, hi]))
     return jnp.stack(rows)
 
@@ -922,18 +925,19 @@ def leaf_item_bytes(leaves) -> int:
 #   * virtual 8-device CPU mesh (this image, 2026-07-30, plan pinned
 #     during calibration): round_overhead 119 us, dense bw 378 MB/s
 #     -> BYTES_EQ ~45 KiB
-#   * TPU ICI meshes: ~10-30 us launch overhead at multi-GB/s effective
-#     -> O(1 MiB); re-measure with the same script on real hardware.
-# Override with THRILL_TPU_XCHG_BYTES_EQ.
-_BYTES_EQ_MEASURED = {"cpu": 45_000}
-_BYTES_EQ_FALLBACK = 1 << 20
+#   * "tpu": NOT MEASURED. The value is the guess earlier rounds fell
+#     through to (~10-30 us launch overhead at multi-GB/s effective
+#     -> O(1 MiB)); measure it with the same script on a four-chip host.
+# A platform with no entry raises: a device nobody priced is an error,
+# not a default. Override with THRILL_TPU_XCHG_BYTES_EQ.
+_BYTES_EQ_MEASURED = {"cpu": 45_000, "tpu": 1 << 20}
 # Exchange bandwidth (bytes/s) for the LIVE calibration below — the
 # other factor of BYTES_EQ. The launch-overhead factor is measured on
 # this very mesh (the dispatch-latency spine); bandwidth stays a
 # baked platform constant because measuring it needs a sized payload
 # sweep (benchmarks/exchange_crossover.py), not a passive observer.
-_BYTES_EQ_BANDWIDTH = {"cpu": 378e6}
-_BYTES_EQ_BANDWIDTH_FALLBACK = 4e9      # TPU ICI order of magnitude
+_BYTES_EQ_BANDWIDTH = {"cpu": 378e6,
+                       "tpu": 4e9}      # not measured (see above)
 _BYTES_EQ_MIN_SAMPLES = 256
 
 
@@ -946,7 +950,7 @@ def _bytes_eq(mex: MeshExec) -> int:
         except ValueError:
             pass
     platform = mex.devices[0].platform if mex.devices else "cpu"
-    static = _BYTES_EQ_MEASURED.get(platform, _BYTES_EQ_FALLBACK)
+    static = _BYTES_EQ_MEASURED[platform]
     # Live calibration: the dispatch-latency spine's running minimum
     # (parallel/mesh.py) is this mesh's pure launch overhead — compile
     # calls and data-bound dispatches are strictly slower, so the min
@@ -960,8 +964,7 @@ def _bytes_eq(mex: MeshExec) -> int:
     # pins the static value regardless of history.
     if (os.environ.get("THRILL_TPU_XCHG_BYTES_EQ_CAL", "1") != "0"
             and getattr(mex, "_disp_lat_n", 0) >= _BYTES_EQ_MIN_SAMPLES):
-        bw = _BYTES_EQ_BANDWIDTH.get(platform,
-                                     _BYTES_EQ_BANDWIDTH_FALLBACK)
+        bw = _BYTES_EQ_BANDWIDTH[platform]
         cal = int(mex._disp_lat_min * bw)
         cal = max(static // 4, min(cal, static * 4))
         led = _decisions.ledger_of(mex)

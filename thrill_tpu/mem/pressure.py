@@ -89,10 +89,18 @@ _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "RESOURCE EXHAUSTED",
                 "Out of memory", "out of memory",
                 "Failed to allocate", "failed to allocate",
                 "Attempting to allocate")
+# ...and what the TPU compiler puts in its refusal of a kernel that does
+# not fit the chip's FAST memory ("RESOURCE_EXHAUSTED: Ran out of memory
+# in memory space vmem ... exceeded scoped vmem limit"). No amount of
+# HBM spilling makes that program compile, so the ladder must not
+# spill and retry it: it surfaces as itself.
+_NOT_HBM_MARKERS = ("memory space vmem", "memory space smem",
+                    "scoped vmem")
 
 
 def is_oom_error(exc: BaseException) -> bool:
-    """Is this exception a device/allocator out-of-memory failure?"""
+    """Is this exception a device/allocator out-of-memory failure
+    that freeing HBM could cure?"""
     if isinstance(exc, SimulatedOom):
         return True
     if isinstance(exc, faults.InjectedFault):
@@ -102,6 +110,8 @@ def is_oom_error(exc: BaseException) -> bool:
     if not isinstance(exc, (RuntimeError, ValueError, OSError)):
         return False            # XlaRuntimeError is a RuntimeError
     msg = str(exc)
+    if any(m in msg for m in _NOT_HBM_MARKERS):
+        return False
     return any(m in msg for m in _OOM_MARKERS)
 
 

@@ -32,7 +32,7 @@ payloads on unauthenticated links by construction).
 
 Robustness is the headline — overload is a designed regime:
 
-* every rejection is TYPED (:class:`~.scheduler.ShedLoad` taxonomy:
+* every rejection is TYPED (:class:`~.scheduler.ShedLoad` kinds:
   ``rate_limited`` / ``tenant_queue_full`` / ``queue_full`` /
   ``draining`` / ``unknown_pipeline`` / ``deadline``) and carries a
   retry-after hint; nothing is ever silently dropped or left hanging;
@@ -148,7 +148,7 @@ class _Conn:
     # -- egress ---------------------------------------------------------
     def enqueue(self, frame, nbytes: int = 0) -> bool:
         """Queue a CONTROL frame (accept/reject/done/error/bye):
-        always admitted — the taxonomy's never-silent rule — unless
+        always admitted — the never-silent rule — unless
         the connection is already dead."""
         with self.cv:
             if self.dead:

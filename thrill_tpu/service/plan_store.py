@@ -5,8 +5,8 @@ exchange capacities, narrow specs, plan kinds (data/exchange.py) and
 pre-shuffle verdicts (core/preshuffle.py) are learned once per
 ``MeshExec.cached`` / ``FusionPlan`` composite identity and reused for
 every later query. A process RESTART used to throw all of it away:
-every exchange site paid the synced host plan step again (~one link
-RTT each — the 140 ms/dispatch class of cost the whole dispatch budget
+every exchange site paid the synced host plan step again (a blocking
+device->host sync each — the class of cost the whole dispatch budget
 fights), every auto-prune site re-ran its cost model. This store
 persists that state through the vfs (file://, s3://, hdfs://) so a
 warm restart re-runs a known pipeline with ``plan_builds == 0``.
@@ -39,8 +39,9 @@ Key/versioning rules:
   new one, never a torn prefix.
 
 Compiled XLA executables are deliberately NOT stored here: jax's own
-persistent compilation cache (THRILL_TPU_COMPILE_CACHE, wired since
-round 1) already buries repeat compile costs; this store covers the
+persistent compilation cache (JAX_COMPILATION_CACHE_DIR, or the
+checkout's .jax_cache/ — api/context.py) already buries repeat compile
+costs; this store covers the
 DATA-DRIVEN half of planning that jax cannot know about.
 """
 

@@ -52,7 +52,7 @@ _F_SUBMIT = faults.declare("service.submit")
 class ShedLoad(RuntimeError):
     """Base of every typed admission rejection — a shed job's future
     (and the front door's reject frame) always carries one of these,
-    never a silent drop. ``kind`` is the rejection taxonomy label
+    never a silent drop. ``kind`` is the rejection kind label
     (ARCHITECTURE.md "Front door & overload control"); ``retry_after_s``
     is the server's backoff hint — the earliest moment a retry could
     plausibly be admitted (queue drain estimate for depth sheds, token

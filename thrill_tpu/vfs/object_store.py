@@ -149,7 +149,7 @@ def _connect(secure: bool, host: str, port: int) -> http.client.HTTPConnection:
 
 
 def _raise_for_status(status: int, url: str, body: bytes = b"") -> None:
-    """Map a failure status onto the retry taxonomy: 404/403 become the
+    """Map a failure status onto the retry classes: 404/403 become the
     (permanent) errno exceptions the rest of the stack already knows;
     everything else carries ``http_status`` for classify()."""
     if status == 404:
