@@ -102,8 +102,13 @@ def _stats_line(ctx) -> str:
     keys = ("device_dispatches", "device_uploads", "device_fetches",
             "exchanges", "bytes_moved", "oom_retries", "segment_splits",
             "host_fallbacks", "admission_spills", "pressure_spilled_bytes",
-            "hbm_spills", "hbm_restores")
-    return "stats: " + " ".join(f"{k}={st[k]}" for k in keys)
+            "hbm_spills", "hbm_restores",
+            # the host phases (seconds on the host's clock, bytes)
+            "upload_s", "upload_bytes", "fetch_s", "fetch_bytes",
+            "sync_wait_s", "compiles", "compile_s")
+    return "stats: " + " ".join(
+        f"{k}={st[k]:.3f}" if isinstance(st[k], float) else f"{k}={st[k]}"
+        for k in keys)
 
 
 def _hbm(devices, stat: str) -> str:

@@ -959,6 +959,18 @@ class Context:
             "device_dispatches": mex.stats_dispatches,
             "device_uploads": mex.stats_uploads,
             "device_fetches": mex.stats_fetches,
+            # host-phase seconds and bytes, added where each phase's
+            # span ends (parallel/mesh.py): an upload ends when
+            # device_put returns; sync_wait is the thread blocked on
+            # the device before a fetch's copy; compiles are backend
+            # compiles or compile-cache loads under a dispatch
+            "upload_s": mex.stats_upload_s,
+            "upload_bytes": mex.stats_upload_bytes,
+            "fetch_s": mex.stats_fetch_s,
+            "fetch_bytes": mex.stats_fetch_bytes,
+            "sync_wait_s": mex.stats_sync_wait_s,
+            "compiles": mex.stats_compiles,
+            "compile_s": mex.stats_compile_s,
             # program stitching (api/fusion.py): how many dispatches
             # the fused runner launched, how many DOp segments they
             # carried (ops/dispatch > 1 means chains actually fused),

@@ -17,8 +17,10 @@ consume counters, so memory can be reclaimed mid-pipeline.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, List, Optional, Sequence, Tuple, Union
 
+from ..common.trace import span_of
 from ..data.shards import DeviceShards, HostShards
 from .stack import Stack, apply_stack_host_list, stack_cache_token
 
@@ -68,6 +70,19 @@ class ParentLink:
         return (self.node.id, stack_cache_token(self.stack))
 
 
+def staged_action(action):
+    """An action ``action(dia, ...)`` under a ``stage`` span named for
+    it: the root of its pipeline's spans, so that what the action does
+    after its pull (a counts fetch, the egress) has a stage too."""
+
+    @functools.wraps(action)
+    def run(dia, *args, **kwargs):
+        with dia.node.stage_span(action.__name__):
+            return action(dia, *args, **kwargs)
+
+    return run
+
+
 class DIABase:
     """A node of the DIA dataflow DAG."""
 
@@ -77,6 +92,12 @@ class DIABase:
         self.label = label
         self.parents: List[ParentLink] = list(parents)
         self.id = ctx._register_node(self)
+        # the pipeline this node belongs to: a source starts one, every
+        # other node joins its parents' oldest. The stage spans carry
+        # it, so that the spans of one Distribute -> ... -> action
+        # share an identifier (common/trace.py)
+        self.pipe = min((p.node.pipe for p in self.parents),
+                        default=self.id)
         self.state = NEW
         self._shards: Optional[Shards] = None
         # number of remaining consuming pulls before data is freed; every
@@ -113,6 +134,16 @@ class DIABase:
         return None
 
     # -- driver ---------------------------------------------------------
+    def stage_span(self, name: Optional[str] = None):
+        """The ``stage`` span of work done for this node: the parent
+        of every upload, dispatch, wait and fetch underneath, so that a
+        stage's self time is its duration minus its children's. Pulls
+        nest by the pull recursion. ``name`` is an action's, which
+        works on this node's output."""
+        return span_of(getattr(self.context, "tracer", None), "stage",
+                       name or self.label, dia_id=self.id,
+                       pipe=self.pipe)
+
     def _barrier_decision(self, reason: str) -> None:
         """Ledger entry for a declined fusion deferral: WHY this node
         ends the stitched chain (common/decisions.py; explain() shows
@@ -163,7 +194,8 @@ class DIABase:
             negotiated = self.context.negotiate_mem(self)
             led = self._bind_ledger_node()
             try:
-                plan = self.compute_plan()
+                with self.stage_span():
+                    plan = self.compute_plan()
             finally:
                 if led is not None:
                     led.pop_node()
@@ -207,35 +239,37 @@ class DIABase:
             # resume path (api/checkpoint.py): a committed epoch holds
             # this node's shards — rebuild them instead of computing,
             # and the pull recursion never touches the upstream graph
-            mgr = getattr(self.context, "checkpoint", None)
-            restored = mgr.try_restore(self) if mgr is not None else None
-            if restored is not None:
-                self._shards = restored
-            else:
-                # stage-level HBM admission (mem/pressure.py): before
-                # a new stage computes, bring the cached-results
-                # ledger back under the watermark — the pull-model
-                # analog of the reference's per-stage RAM distribution
-                pres = getattr(self.context, "pressure", None)
-                if pres is not None and pres.enabled:
-                    pres.admit_stage(self)
-                # stage memory negotiation: EM operators get a host-RAM
-                # grant split among concurrently computing
-                # max-requesters (nested pulls, e.g. recursive DC3
-                # sorts, shrink the inner grants exactly like the
-                # reference's per-stage split)
-                negotiated = self.context.negotiate_mem(self)
-                led = self._bind_ledger_node()
-                try:
-                    self._shards = self.compute()
-                finally:
-                    if led is not None:
-                        led.pop_node()
-                    if negotiated:
-                        self.context.release_mem(self)
-                if mgr is not None:
-                    # stage-barrier auto-checkpoint (opt-in)
-                    mgr.maybe_autosave(self, self._shards)
+            with self.stage_span():
+                mgr = getattr(self.context, "checkpoint", None)
+                restored = mgr.try_restore(self) if mgr is not None \
+                    else None
+                if restored is not None:
+                    self._shards = restored
+                else:
+                    # stage-level HBM admission (mem/pressure.py): before
+                    # a new stage computes, bring the cached-results
+                    # ledger back under the watermark — the pull-model
+                    # analog of the reference's per-stage RAM distribution
+                    pres = getattr(self.context, "pressure", None)
+                    if pres is not None and pres.enabled:
+                        pres.admit_stage(self)
+                    # stage memory negotiation: EM operators get a host-RAM
+                    # grant split among concurrently computing
+                    # max-requesters (nested pulls, e.g. recursive DC3
+                    # sorts, shrink the inner grants exactly like the
+                    # reference's per-stage split)
+                    negotiated = self.context.negotiate_mem(self)
+                    led = self._bind_ledger_node()
+                    try:
+                        self._shards = self.compute()
+                    finally:
+                        if led is not None:
+                            led.pop_node()
+                        if negotiated:
+                            self.context.release_mem(self)
+                    if mgr is not None:
+                        # stage-barrier auto-checkpoint (opt-in)
+                        mgr.maybe_autosave(self, self._shards)
             self.state = EXECUTED
             if not (consume and self.consume_budget <= 1):
                 # a result released by this very pull is never worth

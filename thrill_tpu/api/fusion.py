@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 from typing import Any, Callable, List, Optional, Tuple
 
 import jax
@@ -312,7 +313,12 @@ class FusionPlan:
             nd = len(srcs) + sum(len(f_[0]) for f_ in src_flat)
             nb = sum(len(bf[0]) for bf in bound_flat)
             in_specs = (P(AXIS),) * nd + (P(),) * nb
-            return mex.smap(f, nd + nb, in_specs=in_specs), holder
+            # the program's name on the device plane says which ops it
+            # carries: jit_fused_Sort, jit_fused_ReduceByKey.pre_...
+            name = re.sub(r"[^A-Za-z0-9_.]+", "_", "fused_" + "_".join(
+                s.label for s in segs))[:64]
+            return mex.smap(f, nd + nb, in_specs=in_specs,
+                            name=name), holder
 
         fn, h = mex.cached(key, build)
         split = self._proactive_split(fn, srcs, segs)

@@ -20,12 +20,14 @@ from jax import lax
 from ...data import multiplexer
 from ...data.shards import DeviceShards, HostShards
 from ...parallel.mesh import AXIS
+from ..dia_base import staged_action
 
 
 def _pull(dia, consume: bool = True):
     return dia._link().pull(consume)
 
 
+@staged_action
 def Size(dia) -> int:
     shards = _pull(dia)
     if isinstance(shards, HostShards):
@@ -33,6 +35,7 @@ def Size(dia) -> int:
     return int(shards.counts.sum())
 
 
+@staged_action
 def AllGather(dia) -> list:
     shards = _pull(dia)
     if isinstance(shards, DeviceShards):
@@ -40,6 +43,7 @@ def AllGather(dia) -> list:
     return multiplexer.all_items(dia.context.mesh_exec, shards)
 
 
+@staged_action
 def AllGatherArrays(dia):
     """Columnar egress: the DIA's items as ONE pytree of stacked
     arrays, leaves ``[total, ...]``. On the device path the leaves are
@@ -122,6 +126,7 @@ def AllGatherArrays(dia):
     return jax.tree.map(cat, tree)
 
 
+@staged_action
 def Gather(dia, root: int = 0) -> list:
     """Items of the whole DIA, delivered to worker ``root`` only
     (reference: api/gather.hpp:28). Single-controller runs ARE every
@@ -201,6 +206,7 @@ def _dtype_min(dt):
     return -jnp.inf if jnp.issubdtype(dt, jnp.floating) else jnp.iinfo(dt).min
 
 
+@staged_action
 def Sum(dia, initial: Any = 0, device: bool = False) -> Any:
     """``device=True`` (device-storage DIAs): return the summed pytree
     as replicated DEVICE arrays, no host fetch — feed it straight back
@@ -254,6 +260,7 @@ def Sum(dia, initial: Any = 0, device: bool = False) -> Any:
     return functools.reduce(lambda a, b: a + b, items, initial)
 
 
+@staged_action
 def MinMax(dia, is_min: bool) -> Any:
     shards = _pull(dia)
     if isinstance(shards, DeviceShards):

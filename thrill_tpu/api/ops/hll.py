@@ -19,6 +19,7 @@ from ...core import keys as keymod
 from ...data.shards import DeviceShards, HostShards
 from ...parallel.mesh import AXIS
 from ..dia import DIA
+from ..dia_base import staged_action
 
 
 def _alpha(m: int) -> float:
@@ -45,6 +46,7 @@ def _estimate(registers: np.ndarray, p: int) -> float:
     return raw
 
 
+@staged_action
 def HyperLogLog(dia: DIA, precision: int = 14) -> float:
     p = int(precision)
     m = 1 << p

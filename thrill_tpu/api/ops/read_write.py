@@ -17,7 +17,7 @@ import numpy as np
 from ...data.shards import DeviceShards, HostShards
 from ...vfs import file_io
 from ..dia import DIA
-from ..dia_base import DIABase
+from ..dia_base import DIABase, staged_action
 from ...common.partition import dense_range_bounds
 
 
@@ -315,6 +315,7 @@ def _local_worker_ids(dia):
     return set(range(mex.num_workers))
 
 
+@staged_action
 def WriteLines(dia, path_pattern: str) -> None:
     """One text file per worker (reference: api/write_lines.hpp:33).
     Multi-controller: each process writes only its own workers' files."""
@@ -329,6 +330,7 @@ def WriteLines(dia, path_pattern: str) -> None:
                 f.write(b"\n")
 
 
+@staged_action
 def WriteLinesOne(dia, path: str) -> None:
     """Single coordinated output file (reference: write_lines_one.hpp:31).
     Multi-controller: items gather to process 0, which writes the file
@@ -352,6 +354,7 @@ def WriteLinesOne(dia, path: str) -> None:
                 f.write(b"\n")
 
 
+@staged_action
 def WriteBinary(dia, path_pattern: str) -> None:
     """Raw fixed-size records, one file per worker
     (reference: api/write_binary.hpp:36)."""

@@ -1088,9 +1088,10 @@ def _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
             # the ONE payload gather of this phase (clip: slots past the
             # valid total may point at synthetic pad rows)
             perm = jnp.minimum(perm, W * M_pad - 1)
-            return tuple(
-                rowmove.unpack_rows(jnp.take(p, perm, axis=0), m)[None]
-                for p, m in zip(payload_r, pmetas))
+            with jax.named_scope(rowmove.SCOPE):
+                return tuple(
+                    rowmove.unpack_rows(jnp.take(p, perm, axis=0), m)[None]
+                    for p, m in zip(payload_r, pmetas))
 
         return mex.smap(f, 5 + len(sorted_payload))
 

@@ -336,8 +336,12 @@ def critical_path(records: List[dict], k: int = 5) -> List[dict]:
     children can outlive the parent window). The critical path starts
     at the longest root span across ALL ranks (multi-rank logs merged
     by the caller: whichever rank's chain ran longest bounds the
-    cluster) and at each level follows the child that FINISHES last.
-    Every span on that path becomes an edge record ``{name, cat,
+    cluster). Under each span it walks back from the end: the child
+    that FINISHES last, then the child that finishes last before that
+    one started, and so on — of children that overlap (async work) the
+    last to finish, of children in sequence (one thread's uploads,
+    dispatches and fetches under a stage) every one — and descends
+    into each. Every span on that walk becomes an edge record ``{name, cat,
     rank, excl_us, dur_us, path}`` where ``path`` is the ancestor
     chain (``job:x > exchange:phase_b > dispatch``); edges rank by
     exclusive time."""
@@ -376,23 +380,14 @@ def critical_path(records: List[dict], k: int = 5) -> List[dict]:
     root = max(roots, key=lambda c: (int(spans[c]["dur_us"]),
                                      -int(spans[c]["ts"] or 0),
                                      c[2] if c[2] is not None else 0))
-    path: List[tuple] = [root]
-    cur = root
-    while True:
-        kids = children.get(cur)
-        if not kids:
-            break
-        cur = max(kids, key=lambda c: (end_us(c),
-                                       int(spans[c]["dur_us"]),
-                                       c[2] if c[2] is not None else 0))
-        path.append(cur)
-
     def label(key: tuple) -> str:
         r = spans[key]
         return f"{r.get('cat', '?')}:{r.get('name', '?')}"
 
     edges = []
-    for i, key in enumerate(path):
+    todo = [(root, label(root))]
+    while todo:
+        key, path = todo.pop()
         r = spans[key]
         edges.append({
             "name": str(r.get("name", "?")),
@@ -401,8 +396,15 @@ def critical_path(records: List[dict], k: int = 5) -> List[dict]:
             "dur_us": int(r.get("dur_us", 0)),
             "excl_us": excl_us(key),
             "job": r.get("job"),
-            "path": " > ".join(label(p) for p in path[:i + 1]),
+            "path": path,
         })
+        before = None       # the start of the child taken last
+        for c in sorted(children.get(key, ()), reverse=True,
+                        key=lambda c: (end_us(c), int(spans[c]["dur_us"]),
+                                       c[2] if c[2] is not None else 0)):
+            if before is None or end_us(c) <= before:
+                before = int(spans[c]["ts"])
+                todo.append((c, path + " > " + label(c)))
     edges.sort(key=lambda e: -e["excl_us"])
     return edges[:k]
 

@@ -6,19 +6,75 @@ adds the CORRELATION the grown system needs: lightweight spans
 subsystem, tagged with rank, generation (PR-8 failure domains), tenant
 and job name (PR-9 service plane) — so a Perfetto timeline can show
 *which dispatch, in which exchange, of which job, on which rank* was on
-the critical path. Instrumented at the natural choke points the earlier
-refactors created:
+the critical path.
 
-* ``parallel/mesh.py::_CountedJit.__call__`` — every device dispatch
-  (cat ``dispatch``), including the whole-loop fori program;
-* ``api/fusion.py::FusionPlan.execute`` — stitched segments (``fusion``);
-* ``data/exchange.py`` — phase A / chunked phase B / optimistic-vs-
-  synced verdicts / capacity-miss heals (``exchange``);
-* ``data/multiplexer.py`` — host frames + async sends (``host``);
-* ``net/group.py`` — collectives, generation heals (``net``);
-* ``mem/pressure.py`` — escalation-ladder rungs (``mem``);
-* ``api/loop.py`` — capture/replay/fori iterations (``loop``);
-* ``service/scheduler.py`` — queue-wait and run per job (``service``).
+**The inventory**: every site that records on this spine, and who reads
+it. Every record also reaches three readers that take all of them: the
+flight recorder's dump (below), ``tools/trace2perfetto.py`` (one lane per
+category) and ``Tracer.lane_counts`` (``trace_spans{lane=...}`` on the
+metrics endpoint, ``bench.py``); every span (not instant) reaches
+``common/doctor.py critical_path`` (``ctx.doctor_report()``,
+``tools/doctor_report.py``). The last column names who reads THAT site
+besides: a chip-benchmark metric (``chipbench/layer_metrics/``, through
+``chipbench/span_window.py``) or a test that pins it.
+
+Category / name; site; read by:
+
+* ``stage`` / node or action label; ``api/dia_base.py
+  DIABase.stage_span`` (``materialize`` around restore-or-compute,
+  ``materialize_plan`` around a deferred ``compute_plan``,
+  ``staged_action`` around an action), with ``dia_id`` and ``pipe``;
+  ``host_plan_s_per_job`` (self time) and the window rule (``pipe``),
+  tests/common/test_phase_spans.py.
+* ``upload`` / ``put``, ``put_replicated``; ``parallel/mesh.py
+  MeshExec._upload`` (not the ``put_small`` hit), with ``bytes``,
+  ``shape``, ``dtype``; ``upload_s_per_job``, ``upload_bytes_per_job``.
+* ``dispatch`` / program label; ``parallel/mesh.py
+  _CountedJit.__call__``, every device dispatch, the whole-loop fori
+  program included; ``dispatch_call_s_per_job``,
+  tests/common/test_trace.py.
+* ``compile`` / program label; ``parallel/mesh.py _on_jax_duration``
+  (``jax.monitoring``), a backend compile or cache load under a
+  dispatch, by ``emit_span``; ``compile_s_in_window``, and taken out of
+  ``dispatch_call_s_per_job``.
+* ``wait`` / ``device``; ``parallel/mesh.py MeshExec._fetch_raw``,
+  blocked on the device before the copy; ``sync_wait_s_per_job``.
+* ``fetch`` / ``fetch``, or ``check`` where a deferred check fetches
+  uncounted; ``parallel/mesh.py MeshExec._fetch_raw``, the copy, with
+  ``bytes``; ``fetch_s_per_job``.
+* ``fusion`` / op chain; ``api/fusion.py FusionPlan.execute``, one per
+  stitched launch; ``host_plan_s_per_job`` (self time).
+* ``exchange`` / ``phase_a``, ``phase_b``, ``optimistic`` (with a verdict
+  instant), ``synced``, ``sort_fused``; ``data/exchange.py``,
+  ``api/ops/sort.py``; ``host_plan_s_per_job`` (self time),
+  tests/common/test_doctor.py, test_trace.py (the flight dump names it).
+* ``plan`` / decision kind (instants); ``common/decisions.py`` (each
+  ledger record and audit), ``common/doctor.py`` (skew verdict);
+  tests/common/test_doctor.py.
+* ``mem`` / ladder rung (instants); ``mem/pressure.py``,
+  ``api/fusion.py`` degradations; tests/common/test_trace.py (lane).
+* ``loop`` / ``capture``, ``replay``; ``api/loop.py``;
+  tests/common/test_trace.py (lane).
+* ``service`` / ``queue_wait`` (``emit_span``), ``job:<name>``;
+  ``service/scheduler.py``; tests/common/test_trace.py.
+* ``front_door`` / ``admit``, ``stream:<name>``;
+  ``service/front_door.py``; nobody by name.
+* ``net`` / collective site, ``heal``, ``reconnect`` (instant);
+  ``net/group.py``, ``net/tcp.py``; tests/common/test_trace.py (lane).
+* ``host`` / host frames, ``async_send``; ``data/multiplexer.py``;
+  tests/common/test_trace.py (lane).
+* ``io`` / ``hbm_restore``, ``writeback``, ``prefetch_reader``;
+  ``mem/hbm.py``, ``data/writeback.py``, ``vfs/file_io.py``; nobody by
+  name.
+
+No cell of the benchmark runs the last seven entries' planes; what nothing
+reads by name is listed under ROADMAP D7 for the PR that folds the
+observability mechanisms.
+
+A span of the categories in :data:`MIRRORED` is also a
+``jax.profiler.TraceAnnotation``; a record carries ``ts`` (wall-anchored
+microseconds, for the log files) and ``t0_s`` (its start on
+``time.perf_counter()``, the clock of a reader in the process).
 
 Spans emit through the existing JsonLogger as ``event=span`` lines
 (json2profile ignores unknown events, so the HTML report keeps
@@ -58,6 +114,8 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 #: total Span objects ever allocated in this process — the pin the
 #: THRILL_TPU_TRACE=0 no-op test asserts stays flat across dispatches
 SPANS_CREATED = 0
@@ -67,6 +125,27 @@ SPANS_CREATED = 0
 _NULL = contextlib.nullcontext()
 
 _FLIGHT_SEQ = itertools.count()
+
+#: categories whose spans are also ``jax.profiler.TraceAnnotation``s:
+#: the program's host phases, a few per job, never per row / chunk /
+#: tile. In a profile taken with the host's tracer on they lie on the
+#: profiler's clock beside the device plane; with
+#: ``host_tracer_level=0`` (every benchmark run) they vanish by design.
+#: ``compile`` spans are known only afterwards (``emit_span``) and have
+#: no mirror: the profiler carries JAX's own compile events.
+MIRRORED = frozenset(("stage", "upload", "wait", "fetch", "exchange"))
+
+#: the process's most recently constructed Tracer (see :func:`latest`)
+_LATEST: Optional["Tracer"] = None
+
+
+def latest() -> Optional["Tracer"]:
+    """The process's most recently constructed :class:`Tracer`, still
+    reachable after ``Run()`` has returned and its Context is closed:
+    a reader in the process (the chip benchmark's span metrics) takes
+    the finished run's records from ``latest().ring`` and asks
+    ``latest().wrapped`` whether the ring still holds all of them."""
+    return _LATEST
 
 
 def trace_enabled() -> bool:
@@ -119,7 +198,8 @@ class Span:
     the flight recorder's final spans name the failing site this way."""
 
     __slots__ = ("tracer", "span_id", "parent", "cat", "name", "ts_us",
-                 "t0", "t1", "attrs", "generation", "tenant", "job")
+                 "t0", "t1", "attrs", "generation", "tenant", "job",
+                 "ann")
 
     def __init__(self, tracer: "Tracer", span_id: int,
                  parent: Optional[int], cat: str, name: str,
@@ -138,6 +218,15 @@ class Span:
         self.tenant = tracer.tenant_fn() if tracer.tenant_fn is not None \
             else None
         self.job = tracer.current_job
+        self.ann = None
+        if cat in MIRRORED:
+            self.ann = TraceAnnotation(f"{cat}:{name}")
+            self.ann.__enter__()
+
+    def close_mirror(self) -> None:
+        ann, self.ann = self.ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def __enter__(self) -> "Span":
         return self
@@ -151,6 +240,9 @@ class Span:
         r = {"event": "span", "cat": self.cat, "name": self.name,
              "trace": self.tracer.trace_id, "span": self.span_id,
              "rank": self.tracer.rank, "ts": self.ts_us,
+             # the start on time.perf_counter()'s clock, which is the
+             # clock of a reader in the process (ts is for the logs)
+             "t0_s": self.t0,
              "dur_us": int(((self.t1 if self.t1 is not None
                              else time.perf_counter()) - self.t0) * 1e6)}
         if self.parent is not None:
@@ -196,12 +288,24 @@ class Tracer:
         self.current_job: Optional[str] = None
         # finished spans per category lane (bench.py trace lane counts)
         self.lane_counts: Dict[str, int] = {}
+        # records ever written to the ring, against its capacity
+        self.records_written = 0
+        global _LATEST
+        _LATEST = self
         if logger is not None and hasattr(logger, "now_us"):
             self._now_us = logger.now_us
         else:
             wall0, perf0 = time.time(), time.perf_counter()
             self._now_us = lambda: int(
                 (wall0 + time.perf_counter() - perf0) * 1e6)
+
+    @property
+    def wrapped(self) -> bool:
+        """The ring has dropped records: it no longer holds every span
+        since this Tracer was constructed (always true without a
+        ring)."""
+        return self.ring is None \
+            or self.records_written > self.ring.maxlen
 
     # -- span lifecycle -------------------------------------------------
     def _stack(self) -> list:
@@ -247,8 +351,11 @@ class Tracer:
             # that skipped a child's end must not corrupt parenting)
             for i in range(len(st) - 1, -1, -1):
                 if st[i] is sp:
+                    for leaked in reversed(st[i + 1:]):
+                        leaked.close_mirror()
                     del st[i:]
                     break
+        sp.close_mirror()
         self.lane_counts[sp.cat] = self.lane_counts.get(sp.cat, 0) + 1
         self._record(sp.rec())
 
@@ -265,6 +372,7 @@ class Tracer:
         rec = {"event": "span", "cat": cat, "name": name,
                "trace": self.trace_id, "span": next(self._ids),
                "rank": self.rank, "ts": now_us - elapsed_us,
+               "t0_s": start_s,
                "dur_us": int(max(end_s - start_s, 0.0) * 1e6)}
         if parent is not None:
             rec["parent"] = parent
@@ -303,6 +411,7 @@ class Tracer:
     def _record(self, rec: dict) -> None:
         if self.ring is not None:
             self.ring.append(rec)
+            self.records_written += 1
         log = self.logger
         if log is not None and log.enabled:
             log.line(**rec)

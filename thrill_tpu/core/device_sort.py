@@ -36,6 +36,11 @@ import numpy as np
 from jax import lax
 
 # above this row count, accelerator backends switch engines in auto
+# HLO metadata only (jax.named_scope adds, moves and fuses nothing): a
+# device profile tells the sort engine's operations from the row
+# movement's and the exchange's by this name in their op_name
+SCOPE = "sort_engine"
+
 XLA_SORT_MAX_N = 1 << 16
 # rows per tile of the chunked engine's base-case sort. The TPU
 # compiler's time for a 7-operand lax.sort grows steeply with rows
@@ -180,6 +185,7 @@ def prepare_sort_words(words: List[jnp.ndarray], n: int):
     return words, idt
 
 
+@jax.named_scope(SCOPE)
 def argsort_words(words: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable argsort by uint64 key words (lexicographic). [n] int32."""
     n = words[0].shape[0]
@@ -292,6 +298,7 @@ def _chunked_argsort(words: List[jnp.ndarray],
     return arrs[-1].reshape(-1)[:n_real].astype(jnp.int32)
 
 
+@jax.named_scope(SCOPE)
 def merge_sorted_runs(arrs: List[jnp.ndarray]) -> List[jnp.ndarray]:
     """Bitonic merge of C sorted runs; [C, L] arrays -> [1, C*L].
 

@@ -28,6 +28,11 @@ import jax.numpy as jnp
 from jax import lax
 
 
+# the name the gathers carry in a device profile (jax.named_scope: HLO
+# metadata, no operation added), beside core/device_sort.py's
+SCOPE = "row_move"
+
+
 def enabled() -> bool:
     mode = os.environ.get("THRILL_TPU_PACK_MOVE", "auto")
     if mode in ("0", "false"):
@@ -92,6 +97,7 @@ def unpack_leaves(packed: List, metas: List):
     return [unpack_rows(p, m) for p, m in zip(packed, metas)]
 
 
+@jax.named_scope(SCOPE)
 def take_rows(x, perm):
     """jnp.take(x, perm, axis=0) through the packed view when enabled
     and profitable — the drop-in gather for payload columns."""
@@ -168,6 +174,7 @@ def unpack_rows_wide(words, meta):
     return flat.reshape((n,) + tuple(trail_shape))
 
 
+@jax.named_scope(SCOPE)
 def take_rows_multi(leaves, perm):
     """Gather MANY leaves by one shared row permutation through a
     single concatenated u32 word matrix.

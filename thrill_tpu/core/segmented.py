@@ -17,6 +17,8 @@ from typing import Any, Callable, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import rowmove
+
 
 def sort_by_key_words(words: List[jnp.ndarray], tree: Any, valid: jnp.ndarray,
                       extra_words: List[jnp.ndarray] = ()):
@@ -29,10 +31,13 @@ def sort_by_key_words(words: List[jnp.ndarray], tree: Any, valid: jnp.ndarray,
     sort_keys = [invalid_first_word] + list(words) + list(extra_words)
     perm = _argsort_multi(sort_keys)
     take = lambda x: jnp.take(x, perm, axis=0)
-    return ([take(w) for w in words],
-            jax.tree.map(take, tree),
-            take(valid),
-            [take(w) for w in extra_words])
+    # row movement by a permutation, like core/rowmove.py's: the same
+    # name in a device profile
+    with jax.named_scope(rowmove.SCOPE):
+        return ([take(w) for w in words],
+                jax.tree.map(take, tree),
+                take(valid),
+                [take(w) for w in extra_words])
 
 
 def _argsort_multi(keys: List[jnp.ndarray]) -> jnp.ndarray:
@@ -97,6 +102,7 @@ def _bshape(flag, leaf):
     return flag.reshape(flag.shape + (1,) * (leaf.ndim - 1))
 
 
+@jax.named_scope("segmented_reduce")
 def reduce_runs(words, tree, valid, reduce_fn, specs):
     """One dispatch point for every device reduce program: the
     segment-op engine when ``specs`` (from FieldReduce, pre-gated by

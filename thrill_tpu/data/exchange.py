@@ -71,6 +71,12 @@ from ..common.retry import default_policy
 from ..parallel.mesh import AXIS, MeshExec
 from .shards import DeviceShards
 
+# the name the exchange's device work carries in a device profile (the
+# scatter into per-destination blocks and the collective; jax.named_scope:
+# HLO metadata, no operation added), beside core/device_sort.py's and
+# core/rowmove.py's
+SCOPE = "exchange"
+
 # per-chunk injection at the chunked phase-B dispatch loop: fires
 # BEFORE the chunk program launches (nothing dispatched yet), so a
 # transient retry is safe — mirrors the fused per-op site discipline
@@ -483,6 +489,7 @@ def send_slot_index(dest, S_row, W: int, M_pad: int, cap: int):
     return jnp.where(dest < W, dc * M_pad + slot, W * M_pad)
 
 
+@jax.named_scope(SCOPE)
 def ship_blocks(x, send_idx, W: int, M_pad: int):
     """Traced helper: scatter one leaf into [W, M_pad] padded
     per-destination blocks and all_to_all them; returns the received
@@ -746,9 +753,10 @@ def exchange_stream(shards: DeviceShards, dest_builder: Callable,
                     buf = jnp.zeros((M_r + 1,) + x.shape[1:], x.dtype)
                     buf = buf.at[send_idx].set(x)[:M_r]
                     if to is not None:
-                        buf = lax.ppermute(
-                            buf, AXIS,
-                            perm=[(w, int(to[w])) for w in range(W)])
+                        with jax.named_scope(SCOPE):
+                            buf = lax.ppermute(
+                                buf, AXIS,
+                                perm=[(w, int(to[w])) for w in range(W)])
                     outs.append(buf[None])
                 return tuple(outs)
 
@@ -1708,7 +1716,8 @@ def _exchange_onefactor(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
                 for li, x in enumerate(xs):
                     buf = jnp.zeros((M_r + 1,) + x.shape[1:], x.dtype)
                     buf = buf.at[send_idx].set(x)[:M_r]
-                    recv = lax.ppermute(buf, AXIS, perm=perm)
+                    with jax.named_scope(SCOPE):
+                        recv = lax.ppermute(buf, AXIS, perm=perm)
                     outs[li] = outs[li].at[pos].set(recv)
             res = []
             for li, (o, m) in enumerate(zip(outs, metas)):
@@ -1754,9 +1763,10 @@ def _ragged_builder(mex: MeshExec, out_cap: int, num_leaves: int,
                 x0 = x0.astype(np.dtype(nd))
             x, m = rowmove.pack_rows(x0) if pack else (x0, None)
             out = jnp.zeros((out_cap,) + x.shape[1:], x.dtype)
-            res = lax.ragged_all_to_all(
-                x, out, in_off, S_row, out_off, S_col,
-                axis_name=AXIS)
+            with jax.named_scope(SCOPE):
+                res = lax.ragged_all_to_all(
+                    x, out, in_off, S_row, out_off, S_col,
+                    axis_name=AXIS)
             y = rowmove.unpack_rows(res, m)
             if y.dtype != wide_dt:
                 y = y.astype(wide_dt)              # widen back

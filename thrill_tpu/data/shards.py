@@ -383,6 +383,7 @@ class HostShards:
         return DeviceShards.from_worker_arrays(mesh_exec, per_worker)
 
 
+@jax.named_scope("compact")
 def compact_valid(tree, mask):
     """Inside-jit compaction: move valid items to the front, stably.
 

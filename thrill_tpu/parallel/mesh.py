@@ -28,6 +28,7 @@ import numpy as np
 
 from ..common import faults
 from ..common.retry import default_policy
+from ..common.trace import span_of
 from ..mem import pressure as _pressure
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -61,6 +62,61 @@ def current_program() -> Optional["_CountedJit"]:
     return getattr(_TL, "prog", None)
 
 
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_listener_on = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
+    """Process-wide ``jax.monitoring`` listener: a backend compile (or a
+    load from the compile cache, which JAX times under the same event
+    and announces just before it) on a dispatching thread becomes a
+    ``compile`` span of that mesh's Tracer, named by the dispatching
+    program and parented to its open ``dispatch`` span."""
+    if event == _CACHE_LOAD_EVENT:
+        _TL.cache_load = True
+        return
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    cache_load = getattr(_TL, "cache_load", False)
+    _TL.cache_load = False
+    mex = current_mex()
+    if mex is None:
+        return
+    mex.stats_compiles += 1
+    mex.stats_compile_s += duration_secs
+    tr = mex.tracer
+    if tr is not None and tr.enabled:
+        now = time.perf_counter()
+        tr.emit_span("compile", current_program()._label(),
+                     now - duration_secs, now, parent=tr.current_id(),
+                     seconds=duration_secs, jax_event="backend_compile",
+                     cache_load=cache_load)
+
+
+def _listen_for_compiles() -> None:
+    global _compile_listener_on
+    if not _compile_listener_on:
+        _compile_listener_on = True
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+
+
+def _named(fn: Callable, name: Optional[str]) -> Callable:
+    """``fn`` under ``name``: ``jax.jit`` calls the module
+    ``jit_<__name__>``, which is what the device plane's ``XLA
+    Modules`` line of a profile shows."""
+    if not name or getattr(fn, "__name__", None) == name:
+        return fn
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 class _CountedJit:
     """Dispatch-counting proxy around a ``jax.jit`` callable.
 
@@ -75,7 +131,8 @@ class _CountedJit:
     whole-loop ``lax.fori_loop`` body."""
 
     def __init__(self, mex: "MeshExec", jitted: Callable,
-                 raw: Optional[Callable] = None) -> None:
+                 raw: Optional[Callable] = None,
+                 label: Optional[str] = None) -> None:
         self._mex = mex
         self._jitted = jitted
         self.raw = raw
@@ -95,7 +152,9 @@ class _CountedJit:
         # unknown names to the jitted function, so it must exist here)
         self._adm_est: Optional[Tuple[int, int]] = None
         self._donate_base: Optional["_CountedJit"] = None
-        self._trace_label: Optional[str] = None
+        # the name the jitted callable carries (module ``jit_<label>``
+        # on the device plane) and every host span of this program
+        self._trace_label: Optional[str] = label
         # sort-engine decisions recorded while THIS program traced
         # (core/device_sort.py via current_program()); resolved with
         # the first post-compile dispatch latency (the tracing call's
@@ -105,16 +164,8 @@ class _CountedJit:
         functools.update_wrapper(self, jitted, updated=())
 
     def _label(self) -> str:
-        lbl = self._trace_label
-        if lbl is None:
-            key = self.cache_key
-            if isinstance(key, tuple) and key \
-                    and isinstance(key[0], str):
-                lbl = key[0]                # "fused", "xchg_chunk"...
-            else:
-                lbl = getattr(self._jitted, "__name__", None) or "jit"
-            self._trace_label = lbl
-        return lbl
+        return self._trace_label \
+            or getattr(self._jitted, "__name__", None) or "jit"
 
     def __call__(self, *args, **kwargs):
         # tracing fast path (the pinned overhead contract,
@@ -225,7 +276,8 @@ class _CountedJit:
                                  "build a donating twin")
             fn = _CountedJit(self._mex,
                              jax.jit(self.raw,
-                                     donate_argnums=donate_argnums))
+                                     donate_argnums=donate_argnums),
+                             label=self._label())
             # the OOM ladder (mem/pressure.py) retries a failed
             # donating dispatch through THIS base so the retry never
             # re-donates buffers the failed attempt may have consumed
@@ -295,6 +347,20 @@ class MeshExec:
         self.stats_uploads = 0
         self.stats_fetches = 0
         self.stats_upload_cache_hits = 0
+        # host-phase seconds and bytes, added where the phase's span
+        # ends (plain adds that run with tracing off too): uploads end
+        # when jax.device_put RETURNS, not when the bytes are on the
+        # device; sync_wait is the thread blocked on the device before
+        # a fetch's copy; compiles are backend compiles or loads from
+        # the compile cache under a dispatch
+        self.stats_upload_s = 0.0
+        self.stats_upload_bytes = 0
+        self.stats_fetch_s = 0.0
+        self.stats_fetch_bytes = 0
+        self.stats_sync_wait_s = 0.0
+        self.stats_compiles = 0
+        self.stats_compile_s = 0.0
+        _listen_for_compiles()
         # program stitching (api/fusion.py): dispatches launched by the
         # fused runner, total DOp segments they carried, and per-stage
         # composition (tuple of op labels -> launch count) — the
@@ -460,7 +526,9 @@ class MeshExec:
         across processes — but builds like ReadWordsPacked/ReadBinary
         legitimately hold real data only for their own workers' rows,
         with agreed shapes/counts and zero padding elsewhere)."""
-        self.stats_uploads += 1
+        return self._upload("put", arr, self._place_sharded)
+
+    def _place_sharded(self, arr) -> jax.Array:
         if self.num_processes > 1:
             arr = np.asarray(arr)
             assert arr.shape[0] % self.num_workers == 0, arr.shape
@@ -468,9 +536,25 @@ class MeshExec:
             local = [jax.device_put(arr[w * k:(w + 1) * k],
                                     self.devices[w])
                      for w in self.local_workers]
-            return self._bless(jax.make_array_from_single_device_arrays(
-                arr.shape, self.sharded, local))
-        return self._bless(jax.device_put(arr, self.sharded))
+            return jax.make_array_from_single_device_arrays(
+                arr.shape, self.sharded, local)
+        return jax.device_put(arr, self.sharded)
+
+    def _upload(self, name: str, arr, place: Callable) -> jax.Array:
+        """One counted host->device upload through ``place(arr)``, as an
+        ``upload`` span. It ends when ``jax.device_put`` returns, which
+        may be before the bytes are on the device: nothing here waits
+        for them."""
+        self.stats_uploads += 1
+        nbytes = int(getattr(arr, "nbytes", 0) or 0)
+        t0 = time.perf_counter()
+        with span_of(self.tracer, "upload", name, bytes=nbytes,
+                     shape=list(getattr(arr, "shape", ())),
+                     dtype=str(getattr(arr, "dtype", ""))):
+            buf = self._bless(place(arr))
+        self.stats_upload_s += time.perf_counter() - t0
+        self.stats_upload_bytes += nbytes
+        return buf
 
     def _bless(self, buf: jax.Array) -> jax.Array:
         """Mark a host-uploaded buffer as a legitimate tape constant.
@@ -537,9 +621,9 @@ class MeshExec:
         """Upload one identical copy per device (values must already
         agree across processes — exchange plan arrays derive from the
         replicated send matrix, so they do)."""
-        self.stats_uploads += 1
-        return self._bless(jax.device_put(np.asarray(arr),
-                                          self.replicated))
+        return self._upload(
+            "put_replicated", np.asarray(arr),
+            lambda a: jax.device_put(a, self.replicated))
 
     def fetch(self, arr) -> np.ndarray:
         """Device -> host fetch that is multi-controller safe.
@@ -551,7 +635,7 @@ class MeshExec:
         if isinstance(arr, jax.Array):
             self.stats_fetches += 1
         self.drain_checks()
-        return self._fetch_raw(arr)
+        return self._fetch_raw(arr, "fetch")
 
     def drain_checks(self) -> None:
         """Run every queued deferred validation (hinted-join overflow
@@ -587,11 +671,16 @@ class MeshExec:
         self.loop_recorder = None
         return dropped
 
-    def _fetch_raw(self, arr) -> np.ndarray:
-        """fetch() without stats or check-draining — for the deferred
-        checks themselves (their transfers are tiny, ride a completed
-        program, and must not read as mid-pipeline syncs in the
-        dispatch-budget accounting)."""
+    def _fetch_raw(self, arr, name: str = "check") -> np.ndarray:
+        """fetch() without the fetch count or check-draining — for the
+        deferred checks themselves (their transfers are tiny, ride a
+        completed program, and must not read as mid-pipeline syncs in
+        the dispatch-budget accounting).
+
+        A device array is brought over in two timed phases: ``wait``
+        (``block_until_ready``: the thread blocked on the device, where
+        the copy would block anyway) and ``fetch`` (the copy), the
+        latter named ``name``."""
         rec = self.loop_recorder
         if rec is not None:
             # a capture is watching: host plan logic reading a value a
@@ -600,6 +689,22 @@ class MeshExec:
             # recorder checks the producer's carry-dependence and
             # rejects such captures (api/loop.py)
             rec.on_fetch(arr)
+        if not isinstance(arr, jax.Array):
+            return self._copy_to_host(arr)
+        tr = self.tracer
+        nbytes = int(arr.nbytes)
+        t0 = time.perf_counter()
+        with span_of(tr, "wait", "device"):
+            jax.block_until_ready(arr)
+        t1 = time.perf_counter()
+        with span_of(tr, "fetch", name, bytes=nbytes):
+            out = self._copy_to_host(arr)
+        self.stats_sync_wait_s += t1 - t0
+        self.stats_fetch_s += time.perf_counter() - t1
+        self.stats_fetch_bytes += nbytes
+        return out
+
+    def _copy_to_host(self, arr) -> np.ndarray:
         if getattr(arr, "is_fully_addressable", True):
             return np.asarray(arr)
         from jax.experimental import multihost_utils
@@ -610,12 +715,25 @@ class MeshExec:
         return jax.tree.map(self.fetch, tree)
 
     # -- compiled SPMD programs ----------------------------------------
+    def _counted(self, fn: Callable,
+                 name: Optional[str] = None) -> "_CountedJit":
+        """The one place a jit is constructed. The jitted callable
+        carries its label — ``name``, else the tag of the cache key it
+        is being built under (``"xchg_chunk"``, ``"sort_fused"``...) —
+        so the device plane of a profile reads ``jit_<label>``."""
+        label = name or getattr(_TL, "build_tag", None)
+        fn = _named(fn, label)
+        return _CountedJit(self, jax.jit(fn), raw=fn, label=label)
+
     def smap(self, fn: Callable, num_args: int, out_specs=P(AXIS),
-             in_specs=None, check_vma: bool = False) -> Callable:
+             in_specs=None, check_vma: bool = False,
+             name: Optional[str] = None) -> Callable:
         """jit(shard_map(fn)) with all-sharded inputs by default.
 
         Inside ``fn`` every array argument has its leading worker axis
         sliced to size 1 (this worker's shard); collectives use AXIS.
+        ``name`` labels the program where the cache key's tag says too
+        little (a stitched chain names its ops).
         """
         if in_specs is None:
             in_specs = (P(AXIS),) * num_args
@@ -626,7 +744,7 @@ class MeshExec:
         # the real jit object through the counting proxy; the raw
         # shard_map program rides along for loop-replay donation twins
         # and whole-loop fori lowering (api/loop.py)
-        return _CountedJit(self, jax.jit(sm), raw=sm)
+        return self._counted(sm, name)
 
     def jit_cached(self, key: Tuple, fn: Callable) -> Callable:
         """A cached plain-``jax.jit`` program behind the counting
@@ -634,18 +752,18 @@ class MeshExec:
         driver's small update step — becomes a RECORDABLE dispatch the
         loop layer (api/loop.py) can tape and replay, instead of eager
         ops the capture must reject."""
-        return self.cached(key, lambda: _CountedJit(self, jax.jit(fn),
-                                                    raw=fn))
+        return self.cached(key, lambda: self._counted(fn))
 
     def counted_jit(self, fn: Callable) -> "_CountedJit":
         """``jax.jit`` behind the counting proxy, uncached — for
         callers managing their own cache entry (the whole-loop
         fori_loop program, api/loop.py). This and the two methods
-        above are the ONLY places the codebase constructs a jit:
-        admission control, the OOM ladder and the dispatch counters
-        depend on every device entry passing through _CountedJit
-        (pinned by tests/common/test_tracing.py's source audit)."""
-        return _CountedJit(self, jax.jit(fn), raw=fn)
+        above construct every jit of the codebase (through
+        ``_counted``): admission control, the OOM ladder and the
+        dispatch counters depend on every device entry passing through
+        _CountedJit (pinned by tests/common/test_tracing.py's source
+        audit)."""
+        return self._counted(fn)
 
     def cached(self, key: Tuple, builder: Callable[[], Callable]) -> Callable:
         """Memoize a compiled program per (mesh, key).
@@ -663,7 +781,14 @@ class MeshExec:
                      os.environ.get("THRILL_TPU_PACK_MOVE", "auto"))
         fn = self._cache.get(key)
         if fn is None:
-            fn = builder()
+            # the key's tag ("fused", "xchg_chunk"...) names every jit
+            # the builder constructs (_counted)
+            prev_tag = getattr(_TL, "build_tag", None)
+            _TL.build_tag = key[0] if isinstance(key[0], str) else None
+            try:
+                fn = builder()
+            finally:
+                _TL.build_tag = prev_tag
             target = fn[0] if isinstance(fn, tuple) else fn
             if isinstance(target, _CountedJit):
                 target.cache_key = key
