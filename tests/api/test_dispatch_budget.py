@@ -75,6 +75,37 @@ def test_terasort_w1_single_dispatch():
     assert tuple(_snap(mex) - s0) == (1, 0, 0)
 
 
+def _off_alignment(a):
+    """``a`` at an address that is 16 modulo 64: jax's CPU client keeps
+    a 64-byte aligned host array as the device buffer itself, so there
+    the staging copies after all (tests/data/test_shards_staging.py);
+    on the chip no address does that."""
+    raw = np.empty(a.nbytes + 128, np.uint8)
+    start = (16 - raw.ctypes.data) % 64
+    out = raw[start:start + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+@pytest.mark.parametrize("W", [1, 4])
+def test_terasort_power_of_two_stages_without_a_host_copy(W):
+    """Distribute -> Sort at a power-of-two size pads nothing, so the
+    input goes up as a view of itself: ``stage_copy_bytes`` 0 while
+    every byte still reaches the device. A copy put back into the
+    staging fails here before it costs a chip run (ISSUE 27: it was
+    1.8 of 3.1 s of a 2^23-record job on the chip)."""
+    mex = MeshExec(num_workers=W)
+    ctx = Context(mex)
+    data = {k: _off_alignment(v) for k, v in _terasort_data(4096).items()}
+    want = data["key"][np.lexsort(data["key"].T[::-1])]
+    s0 = ctx.overall_stats()
+    got = ctx.Distribute(data).Sort(key_fn=_key).AllGatherArrays()
+    s1 = ctx.overall_stats()
+    assert s1["stage_copy_bytes"] - s0["stage_copy_bytes"] == 0
+    assert s1["upload_bytes"] - s0["upload_bytes"] >= 4096 * 100
+    assert np.array_equal(got["key"], want)
+
+
 def test_wordcount_w1_single_dispatch():
     mex = MeshExec(num_workers=1)
     ctx = Context(mex)

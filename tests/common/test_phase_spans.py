@@ -144,8 +144,12 @@ def test_span_counts_equal_the_counters_deltas(traced_sort):
     assert count("dispatch") == delta["device_dispatches"] >= 1
     assert count("upload") == delta["device_uploads"]
     assert count("fetch", "fetch") == delta["device_fetches"]
-    # a wait before every copy, counted nowhere
-    assert count("wait") == count("fetch")
+    # a wait before every copy, counted nowhere; and one after the puts
+    # of a Distribute that lent the caller's arrays (data/shards.py),
+    # which on a CPU goes by the arrays' addresses
+    assert count("wait", "device") == count("fetch")
+    assert count("wait") - count("wait", "device") \
+        == count("wait", "upload") <= 1
     assert count("compile") == delta["compiles"] >= 1
 
 

@@ -966,6 +966,7 @@ class Context:
             # compiles or compile-cache loads under a dispatch
             "upload_s": mex.stats_upload_s,
             "upload_bytes": mex.stats_upload_bytes,
+            "stage_copy_bytes": mex.stats_stage_copy_bytes,
             "fetch_s": mex.stats_fetch_s,
             "fetch_bytes": mex.stats_fetch_bytes,
             "sync_wait_s": mex.stats_sync_wait_s,

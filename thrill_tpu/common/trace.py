@@ -23,9 +23,12 @@ Category / name; site; read by:
 * ``stage`` / node or action label; ``api/dia_base.py
   DIABase.stage_span`` (``materialize`` around restore-or-compute,
   ``materialize_plan`` around a deferred ``compute_plan``,
-  ``staged_action`` around an action), with ``dia_id`` and ``pipe``;
-  ``host_plan_s_per_job`` (self time) and the window rule (``pipe``),
-  tests/common/test_phase_spans.py.
+  ``staged_action`` around an action), with ``dia_id`` and ``pipe``,
+  and ``copied_bytes`` where host arrays were staged under it
+  (``data/shards.py _put_staged`` by ``add_to_open``: 0 where they went
+  up as views); ``host_plan_s_per_job`` (self time) and the window rule
+  (``pipe``), tests/common/test_phase_spans.py,
+  tests/data/test_shards_staging.py.
 * ``upload`` / ``put``, ``put_replicated``; ``parallel/mesh.py
   MeshExec._upload`` (not the ``put_small`` hit), with ``bytes``,
   ``shape``, ``dtype``; ``upload_s_per_job``, ``upload_bytes_per_job``.
@@ -38,7 +41,9 @@ Category / name; site; read by:
   dispatch, by ``emit_span``; ``compile_s_in_window``, and taken out of
   ``dispatch_call_s_per_job``.
 * ``wait`` / ``device``; ``parallel/mesh.py MeshExec._fetch_raw``,
-  blocked on the device before the copy; ``sync_wait_s_per_job``.
+  blocked on the device before the copy; ``upload``;
+  ``MeshExec.wait_uploaded``, blocked until uploads of lent host memory
+  are on the device; ``sync_wait_s_per_job``.
 * ``fetch`` / ``fetch``, or ``check`` where a deferred check fetches
   uncounted; ``parallel/mesh.py MeshExec._fetch_raw``, the copy, with
   ``bytes``; ``fetch_s_per_job``.
@@ -358,6 +363,18 @@ class Tracer:
         sp.close_mirror()
         self.lane_counts[sp.cat] = self.lane_counts.get(sp.cat, 0) + 1
         self._record(sp.rec())
+
+    def add_to_open(self, cat: str, key: str, amount: int) -> None:
+        """Add ``amount`` to attribute ``key`` of the calling thread's
+        innermost open span of category ``cat`` (so an amount of 0
+        still leaves the attribute on the record); nothing where
+        tracing is off or no such span is open."""
+        if not self.enabled:
+            return
+        for sp in reversed(self._stack()):
+            if sp.cat == cat:
+                sp.attrs[key] = sp.attrs.get(key, 0) + amount
+                return
 
     def emit_span(self, cat: str, name: str, start_s: float,
                   end_s: float, parent: Optional[int] = None,

@@ -187,6 +187,46 @@ class DeviceShards:
 
     # -- conversion -----------------------------------------------------
     @staticmethod
+    def _put_staged(mesh_exec: MeshExec, stage: Callable, trees: Sequence[Any],
+                    counts: np.ndarray) -> "DeviceShards":
+        """Upload the ``[W, cap, ...]`` host tree that ``stage(*leaves)
+        -> (array, lent)`` makes of corresponding leaves of ``trees``.
+
+        ``lent`` says the array is the caller's own memory under
+        another shape (nothing was written). jax keeps reading a numpy
+        argument after ``device_put`` has returned, so a stage that
+        lent memory waits for its uploads: the caller's array is taken
+        as it stands while the stage runs and is the caller's again
+        when it ends. The CPU client alone never copies a 64-byte
+        aligned array (the device buffer IS that memory for good), so
+        there such a leaf is copied after all. ``stage_copy_bytes``
+        (and ``copied_bytes`` on the covering ``stage`` span) count
+        the bytes of the arrays written here; a lent one counts 0."""
+        copied = 0
+        any_lent = False
+
+        def one(*leaves):
+            nonlocal copied, any_lent
+            staged, lent = stage(*leaves)
+            if lent and mesh_exec.keeps_host_memory(staged):
+                staged, lent = np.array(staged, order="C"), False
+            if lent:
+                any_lent = True
+            else:
+                copied += staged.nbytes
+            return staged
+
+        host_tree = tree_map(one, *trees)
+        mesh_exec.stats_stage_copy_bytes += copied
+        tracer = mesh_exec.tracer
+        if tracer is not None:
+            tracer.add_to_open("stage", "copied_bytes", copied)
+        dev_tree = mesh_exec.put_tree(host_tree)
+        if any_lent:
+            mesh_exec.wait_uploaded(dev_tree)
+        return DeviceShards(mesh_exec, dev_tree, counts)
+
+    @staticmethod
     def from_worker_arrays(mesh_exec: MeshExec, per_worker: Sequence[Any],
                            cap: int = 0,
                            counts: Optional[np.ndarray] = None
@@ -195,7 +235,13 @@ class DeviceShards:
 
         ``counts`` overrides the per-worker lengths (multi-controller
         builds pass globally agreed counts while supplying data only
-        for the workers this process owns)."""
+        for the workers this process owns).
+
+        Staging, per leaf: one worker whose leaf fills ``cap`` is
+        uploaded as a view of that leaf; anything else is written once
+        into a zeroed ``[W, cap, ...]`` buffer. Either way the leaves
+        are read while this call runs and never afterwards
+        (:meth:`_put_staged`)."""
         W = mesh_exec.num_workers
         assert len(per_worker) == W
         if counts is None:
@@ -205,16 +251,19 @@ class DeviceShards:
         if cap <= 0:
             cap = max(1, round_up_pow2(int(counts.max()) if len(counts) else 1))
 
-        def pad_stack(*leaves):
-            out = []
-            for leaf in leaves:
-                leaf = np.asarray(leaf)
-                pad = [(0, cap - leaf.shape[0])] + [(0, 0)] * (leaf.ndim - 1)
-                out.append(np.pad(leaf, pad))
-            return np.stack(out)
+        def stage(*leaves):
+            leaves = [np.asarray(leaf) for leaf in leaves]
+            # by the leaf's own length, never by counts: a process may
+            # hold no rows of a worker whose count it knows
+            if W == 1 and leaves[0].shape[0] == cap:
+                return leaves[0][None], True
+            buf = np.zeros((W, cap) + leaves[0].shape[1:],
+                           np.result_type(*leaves))
+            for w, leaf in enumerate(leaves):
+                buf[w, :leaf.shape[0]] = leaf
+            return buf, False
 
-        host_tree = tree_map(pad_stack, *per_worker)
-        return DeviceShards(mesh_exec, mesh_exec.put_tree(host_tree), counts)
+        return DeviceShards._put_staged(mesh_exec, stage, per_worker, counts)
 
     @staticmethod
     def from_global_numpy(mesh_exec: MeshExec, tree: Any) -> "DeviceShards":
@@ -225,21 +274,29 @@ class DeviceShards:
         no device->host round trip. An iterative driver can therefore
         feed an ``AllGatherArrays`` result (or any eager jnp math on
         it) straight back into ``Distribute`` without leaving jax's
-        dispatch stream (the suffix-sorting doubling loop pattern)."""
+        dispatch stream (the suffix-sorting doubling loop pattern).
+
+        Staging of host leaves: where the split is exact and pads
+        nothing (``n == W * cap``) a leaf is uploaded as its own
+        ``[W, cap, ...]`` reshape, a view whatever its strides
+        (splitting axis 0 needs no copy); otherwise its rows are written once
+        (:meth:`from_worker_arrays`). Either way the leaves are read
+        while this call runs and never afterwards: the caller may
+        overwrite them as soon as it returns."""
         W = mesh_exec.num_workers
         leaves = tree_leaves(tree)
         n = leaves[0].shape[0] if leaves else 0
         all_device = bool(leaves) and all(
             isinstance(l, jax.Array) for l in leaves) and \
             getattr(mesh_exec, "num_processes", 1) == 1
+        bnd = dense_range_bounds(n, W)
+        counts = np.diff(bnd)
+        cap = max(1, round_up_pow2(int(counts.max())))
         if all_device and n > 0:
             # device-side split for ANY n/W: one eager gather per leaf
             # builds the [W, cap] layout (rows past each worker's count
             # repeat row n-1 — masked by counts like all pad rows).
             # Validity counts are host-known (n is), so no sync.
-            bnd = dense_range_bounds(n, W)
-            counts = np.diff(bnd)
-            cap = max(1, round_up_pow2(int(counts.max())))
             idx = jnp.asarray(np.minimum(
                 np.arange(cap)[None, :] + bnd[:W, None], n - 1
             ).reshape(-1))
@@ -250,7 +307,14 @@ class DeviceShards:
                 return jax.device_put(arr, mesh_exec.sharded)
 
             return DeviceShards(mesh_exec, tree_map(place, tree), counts)
-        bounds = dense_range_bounds(n, W).tolist()
+        if n == W * cap:
+            def stage(leaf):
+                leaf = np.asarray(leaf)
+                staged = leaf.reshape((W, cap) + leaf.shape[1:])
+                return staged, np.may_share_memory(staged, leaf)
+
+            return DeviceShards._put_staged(mesh_exec, stage, [tree], counts)
+        bounds = bnd.tolist()
         per_worker = [tree_map(lambda a: np.asarray(a)[bounds[w]:bounds[w + 1]], tree)
                       for w in range(W)]
         return DeviceShards.from_worker_arrays(mesh_exec, per_worker)

@@ -355,6 +355,10 @@ class MeshExec:
         # the compile cache under a dispatch
         self.stats_upload_s = 0.0
         self.stats_upload_bytes = 0
+        # bytes the staging of host arrays wrote on the host before
+        # their put (data/shards.py): 0 where the caller's arrays went
+        # up as views of themselves
+        self.stats_stage_copy_bytes = 0
         self.stats_fetch_s = 0.0
         self.stats_fetch_bytes = 0
         self.stats_sync_wait_s = 0.0
@@ -555,6 +559,27 @@ class MeshExec:
         self.stats_upload_s += time.perf_counter() - t0
         self.stats_upload_bytes += nbytes
         return buf
+
+    def keeps_host_memory(self, arr: np.ndarray) -> bool:
+        """Whether ``put(arr)`` ([W, ...], one shard per worker) would
+        leave a device buffer that IS the host memory, for the buffer's
+        life: jax's CPU client does not copy a shard that starts at a
+        64-byte aligned address. No other platform's device memory is
+        the host's."""
+        if self.devices[0].platform != "cpu":
+            return False
+        base, step = arr.ctypes.data, arr.strides[0]
+        return any((base + w * step) % 64 == 0 for w in range(arr.shape[0]))
+
+    def wait_uploaded(self, tree) -> None:
+        """Block until the uploads behind ``tree`` are on the device,
+        as a ``wait`` span: from then on jax no longer reads the host
+        arrays they came from. For a caller that handed ``put`` memory
+        it does not own (data/shards.py)."""
+        t0 = time.perf_counter()
+        with span_of(self.tracer, "wait", "upload"):
+            jax.block_until_ready(tree)
+        self.stats_sync_wait_s += time.perf_counter() - t0
 
     def _bless(self, buf: jax.Array) -> jax.Array:
         """Mark a host-uploaded buffer as a legitimate tape constant.
