@@ -5,10 +5,20 @@ links grouped by source, ranks joined to outgoing links, contributions
 reduced by target index, dampened; iterated with Collapse'd loop DIAs.
 
 TPU-native: the adjacency is a columnar edge list (src, dst) on device;
-one iteration = join ranks to edges by src index (ReduceToIndex for
-out-degrees + edge gather via device join), contribution ReduceToIndex
-by dst. Entirely jitted device programs around two exchanges per
-iteration.
+one iteration = Zip the ranks with the out-degree table (ReduceToIndex,
+once, before the loop), gather the scaled rank along every edge by its
+source (a dense-index InnerJoin: no sort, no hash exchange, no size
+sync) and scatter-add the contributions by target (sort-free
+ReduceToIndex). Entirely jitted device programs; on one worker nothing
+is exchanged inside an iteration, on several the join all-gathers the
+dense table and ReduceToIndex routes each contribution to the worker
+that owns its target.
+
+The loop body is a module-level function that takes the edge list, the
+degree table and the per-call constants as ``invariants`` of
+``Iterate``, so a later call of the same sizes replays the tape the
+first one captured. ``chipbench/jobs/pagerank.py`` is the benchmark's
+copy of this job (a copy, not an import).
 """
 
 from __future__ import annotations
@@ -75,6 +85,29 @@ def _dampen(t, base):
     return base[0] + DAMPENING * t["v"]
 
 
+def _iteration(ranks, edges_dia, deg_dia, num_pages, base):
+    """One iteration = three dense-table steps, no sort at any worker
+    count and no exchange on one worker (on several, step 2 all-gathers
+    the dense table and step 3 routes by target range):
+
+    1. Zip ranks with the degree table and pre-divide — each page's
+       outgoing contribution, one elementwise pass over [n] rows (the
+       reference divides per EDGE, m/n times more divisions);
+    2. a DENSE INDEX join: the right side is the dense per-page
+       contribution table (row at global position p has key p by
+       construction), so dense_right_index turns the join into a pure
+       device gather — no sort, no hash exchange, no size sync (the
+       generic sort-merge join pays two XLA argsorts per call);
+    3. scatter-add by destination (sort-free FieldReduce engine) and
+       dampen — the O(n+m) shape of the numpy proxy's np.add.at."""
+    scaled = Zip(ranks, deg_dia, zip_fn=_scale_rank)
+    contrib = InnerJoin(edges_dia, scaled, _edge_src, None,
+                        _join_scaled, dense_right_index=num_pages)
+    sums = contrib.ReduceToIndex(
+        _contrib_dst, _SUM_V, num_pages, neutral={"d": 0, "v": 0.0})
+    return sums.Map(Bind(_dampen, base))
+
+
 def page_rank(ctx: Context, edges: np.ndarray, num_pages: int,
               iterations: int = 10):
     """edges: [m, 2] int64 (src, dst). Returns np.ndarray of ranks."""
@@ -93,33 +126,18 @@ def page_rank(ctx: Context, edges: np.ndarray, num_pages: int,
     base = np.array([(1.0 - DAMPENING) / num_pages])
     ranks = ctx.Generate(num_pages).Map(Bind(_fill, inv_n)).Cache()
 
-    # One iteration = three dense-table steps, no sort and no exchange
-    # at any worker count:
-    #   1. Zip ranks with the degree table and pre-divide — each page's
-    #      outgoing contribution, one elementwise pass over [n] rows
-    #      (the reference divides per EDGE, m/n times more divisions);
-    #   2. a DENSE INDEX join: the right side is the dense per-page
-    #      contribution table (row at global position p has key p by
-    #      construction), so dense_right_index turns the join into a
-    #      pure device gather — no sort, no hash exchange, no size sync
-    #      (the generic sort-merge join pays two XLA argsorts per call);
-    #   3. scatter-add by destination (sort-free FieldReduce engine) and
-    #      dampen — the O(n+m) shape of the numpy proxy's np.add.at.
-    def body(ranks):
-        scaled = Zip(ranks, deg_dia, zip_fn=_scale_rank)
-        contrib = InnerJoin(edges_dia, scaled, _edge_src, None,
-                            _join_scaled, dense_right_index=num_pages)
-        sums = contrib.ReduceToIndex(
-            _contrib_dst, _SUM_V, num_pages, neutral={"d": 0, "v": 0.0})
-        return sums.Map(Bind(_dampen, base))
-
     # the Collapse-loop idiom, loop-layer spelling (api/loop.py):
     # iteration 1 runs the body through the pull recursion + fusion
     # planner and CAPTURES the resulting dispatch tape as a LoopPlan;
     # iterations 2..N replay the tape device-resident — zero Python
     # graph construction, zero re-planning, zero host round trips
-    # (THRILL_TPU_LOOP_REPLAY=0 restores the plain per-iteration loop)
-    ranks = Iterate(ctx, body, ranks, iterations, name="page_rank")
+    # (THRILL_TPU_LOOP_REPLAY=0 restores the plain per-iteration loop).
+    # What the body reads unchanged in every iteration goes in as
+    # ``invariants``: the body is a module-level function that carries
+    # nothing of this call, so the next page_rank() of the same sizes
+    # REBINDS the kept tape to its own tables and captures nothing.
+    ranks = Iterate(ctx, _iteration, ranks, iterations, name="page_rank",
+                    invariants=(edges_dia, deg_dia, num_pages, base))
 
     return np.asarray(ranks.AllGather(), dtype=np.float64)
 

@@ -989,6 +989,7 @@ class Context:
             # loop fori_loop iterations, loud replay fallbacks, and
             # HBM bytes donated back to XLA on replayed dispatches
             "loop_plan_builds": mex.stats_loop_plan_builds,
+            "loop_plan_rebinds": mex.stats_loop_plan_rebinds,
             "loop_replays": mex.stats_loop_replays,
             "loop_fori_iters": mex.stats_loop_fori_iters,
             "loop_replay_fallbacks": mex.stats_loop_fallbacks,

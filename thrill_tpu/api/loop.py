@@ -84,6 +84,29 @@ corrupt it. ``Iterate(..., checkpoint_every=k)`` seals the carry into
 a durable epoch every k iterations via api/checkpoint.py; a resumed
 run restores the newest loop epoch and continues from the next
 iteration.
+
+One tape for every call of a loop: a job that runs the same loop again
+(the next batch of a service, the next job of a benchmark window) used
+to capture again, because a tape's constants are the buffers of ITS
+call's upstream tables. ``Iterate(..., invariants=...)`` names what the
+body reads unchanged in every iteration and hands it to the body after
+the carry. Buffers of invariant DIAs are recorded as ("inv", ...) refs
+and not as constants; needed calls that depend on them and not on the
+carry form the plan's PROLOGUE. A body that is a plain function carrying
+nothing of its own (no closure cells, no default arguments, no ``self``)
+and whose tape read nothing else of its call (every other constant a
+content-cached plan array or a host operand; no host read of an
+invariant or of a value computed from one) is kept on the mesh under
+the loop's token: the body OBJECT (by identity and held, as ``jax.jit``
+keys a function), name, carry signature and host counts, invariant
+signatures and host counts, host invariants by content. The next call
+with that token REBINDS it (``loop`` span ``rebind``): it materializes its own
+invariants, runs the prologue once on them, and replays from its first
+iteration, the whole loop in the one ``fori`` program (its trip count
+is an operand). Anything else keeps the old behaviour, a capture per
+call; a rebind or a replay that fails forgets the kept tape, counts in
+``loop_replay_fallbacks`` and captures afresh. Between calls a kept
+tape holds no call's buffers (``unbind``).
 """
 
 from __future__ import annotations
@@ -91,6 +114,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
+import types
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -100,11 +124,16 @@ import numpy as np
 from jax import lax
 
 from ..common import faults
+from ..common.trace import span_of
 from ..data.shards import DeviceShards, HostShards
 from .dia import DIA
 from .dia_base import DIABase
 
 _F_REPLAY = faults.declare("api.loop.replay")
+
+# argument refs that reach a whole-loop program as runtime operands:
+# plan constants and the current call's invariant / prologue buffers
+_OPERAND_KINDS = ("const", "inv", "pro")
 
 
 # ----------------------------------------------------------------------
@@ -193,9 +222,12 @@ def fori_enabled() -> bool:
 class _Call:
     """One recorded dispatch: the counted-jit callable plus classified
     argument references.  ``arg_refs``: ("carry", slot) | ("val",
-    (call_idx, out_idx)) | ("const", buffer) | ("tree", treedef,
-    [leaf refs]) for pytree arguments that MIX loop-owned leaves with
-    constants (a jit_cached body called on the carry dict).  Filled
+    (call_idx, out_idx)) | ("const", buffer) | ("inv", (k, leaf)): a
+    buffer of the k-th invariant DIA's shards | ("pro", (call_idx,
+    out_idx)): an output of the plan's prologue (analysis only) |
+    ("tree", treedef, [leaf refs]) for pytree arguments that MIX
+    loop-owned leaves with constants (a jit_cached body called on the
+    carry dict).  Filled
     during analysis: ``donate_pos`` — argument positions whose buffers
     are loop-owned and dead after this call.  ``leaf_kinds`` (flatten
     order across all arguments = jaxpr invar order) and ``avals``
@@ -227,8 +259,22 @@ class _Recorder:
     body run; sees every ``_CountedJit`` dispatch."""
 
     def __init__(self, carry_ids: Dict[int, int],
-                 known: Optional[list] = None) -> None:
+                 known: Optional[list] = None,
+                 inv_ids: Optional[Dict[int, Tuple[int, int]]] = None,
+                 small_cache: Optional[dict] = None) -> None:
         self.carry_ids = carry_ids
+        # buffers of the declared invariant DIAs' shards -> (k, leaf):
+        # what a later Iterate call of the same loop rebinds
+        self.inv_ids = inv_ids or {}
+        # the constants every call of this loop would see: plan arrays
+        # of the content-keyed put_small cache and host operands
+        # converted right before a dispatch (asarray_blessed). A
+        # constant from anywhere else (an upstream table, a source
+        # that uploaded its data during the capture) may belong to
+        # this call alone, and then the tape is not kept for the next
+        self._small_cache = small_cache if small_cache is not None else {}
+        self._operands: set = set()
+        self.unshared: Optional[str] = None
         self.calls: List[_Call] = []
         self.produced: Dict[int, Tuple[int, int]] = {}
         self.plan_reads: set = set()   # (call, out) leaves fetched to host
@@ -251,11 +297,18 @@ class _Recorder:
             except TypeError:
                 self._known[id(a)] = (lambda a=a: a)
 
-    def bless(self, buf) -> None:
-        """mesh.put uploaded ``buf`` during this capture. Blessed
-        buffers are held strongly: the tape's bound args reference
-        them anyway, and a blessing must not silently expire."""
+    def bless(self, buf, operand: bool = False) -> None:
+        """mesh.put uploaded ``buf`` during this capture, or (``operand``)
+        a host operand was converted for a dispatch. Blessed buffers
+        are held strongly: the tape's bound args reference them anyway,
+        and a blessing must not silently expire."""
         self._known[id(buf)] = (lambda buf=buf: buf)
+        if operand:
+            self._operands.add(id(buf))
+
+    def _is_shared(self, a) -> bool:
+        return id(a) in self._operands \
+            or any(v is a for v in self._small_cache.values())
 
     def _is_known(self, a) -> bool:
         r = self._known.get(id(a))
@@ -275,6 +328,8 @@ class _Recorder:
             self.dirty = ("host plan logic fetched a carry leaf "
                           "during capture (carry-dependent plan)")
             return
+        if id(arr) in self.inv_ids:
+            self.unshared = "host plan logic read an invariant's buffer"
         src = self.produced.get(id(arr))
         if src is not None:
             self.plan_reads.add(src)
@@ -285,6 +340,9 @@ class _Recorder:
             return ("carry", slot)
         if id(a) in self.produced:
             return ("val", self.produced[id(a)])
+        inv = self.inv_ids.get(id(a))
+        if inv is not None:
+            return ("inv", inv)
         if isinstance(a, np.ndarray):
             # a host array feeding a dispatch may be a fetched copy
             # of loop-VARIANT data (multi-controller egress); a
@@ -301,6 +359,11 @@ class _Recorder:
                           "during capture (unrecorded jax op in the "
                           "body?)")
             return None
+        if isinstance(a, jax.Array) and self.unshared is None \
+                and not self._is_shared(a):
+            self.unshared = ("a device array that is neither an "
+                             "invariant nor a plan constant entered a "
+                             "dispatch")
         return ("const", a)
 
     def on_call(self, fn, args, kwargs, out) -> None:
@@ -538,13 +601,27 @@ class LoopPlan:
     counts thread through the tape as a device leaf. ``seed``: the
     plan store's remembered tape metadata for this loop — a digest
     match skips the taint re-traces (trusted tape), a mismatch is
-    STALE and runs the full fresh analysis."""
+    STALE and runs the full fresh analysis.
+
+    Invariants: a needed call that depends on an invariant DIA's
+    buffers but not on the carry is the same in every iteration and
+    another in every ``Iterate`` call, so it goes to ``prologue``, run
+    once per call by :meth:`bind`; ("inv", ...) and ("pro", ...) refs
+    of the live calls read the CURRENT call's buffers (``_inv``,
+    ``_pro``). ``unshared`` says why the tape serves its own call
+    alone, or is None: then ``Iterate`` keeps it on the mesh."""
 
     def __init__(self, mex, calls: List[_Call], carry_out: List[Tuple],
                  n_carry: int, plan_reads: Optional[set] = None,
                  name: Optional[str] = None,
-                 seed: Optional[dict] = None) -> None:
+                 seed: Optional[dict] = None,
+                 inv_leaves: Optional[List[List[Any]]] = None,
+                 unshared: Optional[str] = None) -> None:
         self.mex = mex
+        self.prologue: List[_Call] = []
+        self._inv = inv_leaves
+        self._pro: Optional[Dict[Tuple[int, int], Any]] = None
+        self.unshared = unshared
         self.calls = calls
         self.carry_out = carry_out
         self.n_carry = n_carry
@@ -570,14 +647,17 @@ class LoopPlan:
     def _analyze(self) -> None:
         calls = self.calls
         n = len(calls)
-        # carry dependence (forward)
+        # carry dependence and invariant dependence (forward)
         dep = [False] * n
+        idep = [False] * n
         for i, c in enumerate(calls):
             for ref in _leaf_refs(c.arg_refs):
                 if ref[0] == "carry" or (ref[0] == "val"
                                          and dep[ref[1][0]]):
                     dep[i] = True
-                    break
+                if ref[0] == "inv" or (ref[0] == "val"
+                                       and idep[ref[1][0]]):
+                    idep[i] = True
         # tape identity (plan-store loop_tape metadata): computed over
         # the ORIGINAL calls/wiring/plan-reads — exactly the inputs
         # the taint verification below is a pure function of
@@ -628,16 +708,23 @@ class LoopPlan:
                 if ref[0] == "val":
                     stack.append(ref[1][0])
         live_idx = [i for i in range(n) if needed[i] and dep[i]]
+        pro_idx = [i for i in range(n)
+                   if needed[i] and not dep[i] and idep[i]]
         self.pruned_invariant = sum(1 for i in range(n)
                                     if needed[i] and not dep[i])
         self.pruned_dead = n - sum(needed)
         remap = {old: new for new, old in enumerate(live_idx)}
+        pro_remap = {old: new for new, old in enumerate(pro_idx)}
 
         def rewrite(ref):
             if ref[0] == "val":
                 src, j = ref[1]
                 if src in remap:
                     return ("val", (remap[src], j))
+                if src in pro_remap:
+                    # the same in every iteration of THIS call: read
+                    # from the call's prologue outputs
+                    return ("pro", (pro_remap[src], j))
                 # invariant producer: its captured output IS the
                 # value for every future iteration
                 return ("const", calls[src].out_buffers[j])
@@ -645,23 +732,23 @@ class LoopPlan:
                 return ("tree", ref[1], [rewrite(s) for s in ref[2]])
             return ref
 
-        live: List[_Call] = []
-        for i in live_idx:
-            c = calls[i]
-            live.append(_Call(c.fn, [rewrite(r) for r in c.arg_refs],
-                              c.out_buffers))
-        out: List[Tuple] = []
-        for ref in self.carry_out:
-            if ref[0] == "val":
-                src, j = ref[1]
-                if src in remap:
-                    out.append(("val", (remap[src], j)))
-                else:
-                    # invariant producer: this carry leaf is the SAME
-                    # value every iteration — fold it, like rewrite()
-                    out.append(("const", calls[src].out_buffers[j]))
-            else:
-                out.append(ref)
+        def rewritten(idx):
+            return [_Call(calls[i].fn,
+                          [rewrite(r) for r in calls[i].arg_refs],
+                          calls[i].out_buffers) for i in idx]
+
+        live = rewritten(live_idx)
+        self.prologue = rewritten(pro_idx)
+        # this call's prologue outputs are the captured ones
+        self._pro = {(p, j): o for p, c in enumerate(self.prologue)
+                     for j, o in enumerate(c.out_buffers)}
+        out = [rewrite(ref) for ref in self.carry_out]
+        if self.unshared is None:
+            if any(idep[i] for i, _ in self.plan_reads):
+                self.unshared = ("host plan logic read a value computed "
+                                 "from an invariant")
+            elif any(ref[0] not in ("val", "carry") for ref in out):
+                self.unshared = "a carry leaf is constant across iterations"
         self.calls = live
         self.carry_out = out
         # donation positions are recomputed per capture (cheap, pure
@@ -672,7 +759,7 @@ class LoopPlan:
         # live calls must not pin the capture iteration's HBM: their
         # recorded outputs are never read again (invariant producers'
         # outputs were just folded into ("const", ...) refs above)
-        for c in self.calls:
+        for c in self.calls + self.prologue:
             c.out_buffers = None
         # which (call, out) pairs later steps / the carry actually read
         used: set = set()
@@ -742,6 +829,40 @@ class LoopPlan:
                 and last_use.get((ref[0], ref[1])) == (i, p)))
             c.donate_pos = pos
 
+    # -- this call's buffers --------------------------------------------
+    def _resolve(self, ref, carry=None, vals=None):
+        """The buffer behind an argument ref: a constant, the current
+        call's invariant or prologue output, or (inside an iteration)
+        a ``carry`` leaf or an earlier call's output in ``vals``."""
+        kind = ref[0]
+        if kind == "const":
+            return ref[1]
+        if kind == "inv":
+            return self._inv[ref[1][0]][ref[1][1]]
+        if kind == "pro":
+            return self._pro[ref[1]]
+        if kind == "carry":
+            return carry[ref[1]]
+        if kind == "val":
+            return vals[ref[1]]
+        return jax.tree.unflatten(
+            ref[1], [self._resolve(s, carry, vals) for s in ref[2]])
+
+    def bind(self, inv_leaves: List[List[Any]]) -> None:
+        """Take over another ``Iterate`` call's invariant buffers and
+        run the prologue on them, once."""
+        self._inv = inv_leaves
+        self._pro = pro = {}
+        for p, call in enumerate(self.prologue):
+            out = call.fn(*[self._resolve(ref) for ref in call.arg_refs])
+            for j, o in enumerate(jax.tree.leaves(out)):
+                pro[(p, j)] = o
+
+    def unbind(self) -> None:
+        """Let go of the call's buffers: a kept plan must not pin one
+        job's tables in HBM until the next one comes."""
+        self._inv = self._pro = None
+
     # -- execution ------------------------------------------------------
     def replay(self, carry: List[Any], donate: bool,
                donate_carry: bool = True) -> List[Any]:
@@ -752,19 +873,9 @@ class LoopPlan:
         mex = self.mex
         vals: Dict[Tuple[int, int], Any] = {}
 
-        def resolve(ref):
-            kind = ref[0]
-            if kind == "const":
-                return ref[1]
-            if kind == "carry":
-                return carry[ref[1]]
-            if kind == "val":
-                return vals[ref[1]]
-            return jax.tree.unflatten(ref[1],
-                                      [resolve(s) for s in ref[2]])
-
         for i, call in enumerate(self.calls):
-            args = [resolve(ref) for ref in call.arg_refs]
+            args = [self._resolve(ref, carry, vals)
+                    for ref in call.arg_refs]
             fn = call.fn
             if donate and call.donate_pos:
                 pos = call.donate_pos
@@ -779,9 +890,7 @@ class LoopPlan:
             for j, o in enumerate(jax.tree.leaves(out)):
                 if (i, j) in self.used_outputs:
                     vals[(i, j)] = o
-        return [carry[ref[1]] if ref[0] == "carry"
-                else ref[1] if ref[0] == "const"
-                else vals[ref[1]] for ref in self.carry_out]
+        return [self._resolve(ref, carry, vals) for ref in self.carry_out]
 
     # -- whole-loop fori_loop lowering ---------------------------------
     def fori_eligible(self) -> bool:
@@ -796,14 +905,9 @@ class LoopPlan:
         """Constant operands in tape order (tree args contribute their
         const LEAVES, in flatten order — the fori body consumes them
         from the same traversal)."""
-        out = []
-        for c in self.calls:
-            for ref in c.arg_refs:
-                if ref[0] == "const":
-                    out.append(ref[1])
-                elif ref[0] == "tree":
-                    out.extend(s[1] for s in ref[2] if s[0] == "const")
-        return tuple(out)
+        return tuple(self._resolve(ref) for c in self.calls
+                     for ref in _leaf_refs(c.arg_refs)
+                     if ref[0] in _OPERAND_KINDS)
 
     def run_fori(self, carry: List[Any], k: int) -> Optional[List[Any]]:
         """Lower the remaining ``k`` iterations into ONE jitted
@@ -812,20 +916,25 @@ class LoopPlan:
 
         The incoming carry is never donated here: fori only ever runs
         as the FIRST replay after a (re)capture, whose carry buffers
-        the capture graph still references."""
+        the capture graph still references. ``k`` is an operand and
+        not part of the program: the call that captured runs k = n - 1
+        through it and a call that rebinds the tape k = n."""
         if self._fori_failed or not self.fori_eligible():
             return None
         calls = self.calls
-        out_slots: List[Tuple] = list(self.carry_out)
+        # a carry leaf that is the same buffer in every iteration is
+        # closed over by the program, whatever it was computed from
+        out_slots: List[Tuple] = [
+            ("const", self._resolve(r)) if r[0] in ("inv", "pro") else r
+            for r in self.carry_out]
         used = self.used_outputs
-        cached = self._fori
-        if cached is None or cached[1] != k:
+        if self._fori is None:
             # two plans with the same per-call programs and wiring are
             # the SAME loop — share one compiled fori program through
             # the mesh cache (a fresh capture per driver call must not
             # recompile the whole-loop dispatch)
             def ref_sig(r):
-                if r[0] == "const":
+                if r[0] in _OPERAND_KINDS:
                     return ("const",)
                 if r[0] == "tree":
                     return ("tree", r[1],
@@ -843,7 +952,7 @@ class LoopPlan:
                          or ("rawid", id(c.fn.raw)) for c in calls),
                    tuple(tuple(ref_sig(r) for r in c.arg_refs)
                          for c in calls),
-                   tuple(sorted(used)), out_sig, k)
+                   tuple(sorted(used)), out_sig)
 
             built = []
 
@@ -856,7 +965,7 @@ class LoopPlan:
                 # intentionally closed over, that's what the id-keying
                 # above is for)
                 def strip(r):
-                    if r[0] == "const":
+                    if r[0] in _OPERAND_KINDS:
                         return ("const", None)
                     if r[0] == "tree":
                         return ("tree", r[1], [strip(s) for s in r[2]])
@@ -864,7 +973,7 @@ class LoopPlan:
                 call_plan = [(c.fn.raw, [strip(r) for r in c.arg_refs])
                              for c in calls]
 
-                def loop_fn(carry_t, consts):
+                def loop_fn(carry_t, consts, k):
                     def body(_, c):
                         ci = iter(consts)
                         vals: Dict[Tuple[int, int], Any] = {}
@@ -905,7 +1014,8 @@ class LoopPlan:
             try:
                 fn = self.mex.cached(key, build)
                 if built:                        # fresh program: probe
-                    fn.lower(tuple(carry), self._fori_consts())
+                    fn.lower(tuple(carry), self._fori_consts(),
+                             np.int32(k))
             except Exception as e:               # version/topology limits
                 self._fori_failed = True
                 log = getattr(self.mex, "logger", None)
@@ -913,10 +1023,9 @@ class LoopPlan:
                     log.line(event="loop_fori_unavailable",
                              loop=self.name, error=repr(e)[:200])
                 return None
-            self._fori = (fn, k)
-        fn = self._fori[0]
+            self._fori = fn
         # the dispatch counter ticks inside _CountedJit.__call__ now
-        out = fn(tuple(carry), self._fori_consts())
+        out = self._fori(tuple(carry), self._fori_consts(), np.int32(k))
         return list(out)
 
 
@@ -925,18 +1034,23 @@ class LoopPlan:
 # ----------------------------------------------------------------------
 
 class _LoopCarryNode(DIABase):
-    """Source node wrapping the loop-carried shards of one iteration."""
+    """Source node wrapping the loop-carried shards of one iteration.
+    It has no parents, yet starts no pipeline: it joins ``pipe``, the
+    pipeline of the loop's input, so that a job that loops stays one
+    pipeline in the span records (common/trace.py)."""
 
-    def __init__(self, ctx, shards) -> None:
+    def __init__(self, ctx, shards, pipe: Optional[int] = None) -> None:
         super().__init__(ctx, "LoopCarry")
+        if pipe is not None:
+            self.pipe = pipe
         self._carry = shards
 
     def compute(self):
         return self._carry
 
 
-def _carry_dia(ctx, shards) -> DIA:
-    return DIA(_LoopCarryNode(ctx, shards))
+def _carry_dia(ctx, shards, pipe: Optional[int] = None) -> DIA:
+    return DIA(_LoopCarryNode(ctx, shards, pipe))
 
 
 def _shards_carry_ids(shards: DeviceShards) -> Tuple[Dict[int, int], int]:
@@ -958,7 +1072,8 @@ def _leaf_sig(leaves: Sequence[Any]) -> Tuple:
 # ----------------------------------------------------------------------
 
 def Iterate(ctx, body: Callable, carry, n: int, *, name: str = "loop",
-            checkpoint_every: Optional[int] = None):
+            checkpoint_every: Optional[int] = None,
+            invariants: Sequence[Any] = ()):
     """Run ``body`` ``n`` times with ``carry`` threaded through,
     replaying a captured LoopPlan for iterations 2..N.
 
@@ -979,9 +1094,43 @@ def Iterate(ctx, body: Callable, carry, n: int, *, name: str = "loop",
     iterations when the Context has a CheckpointManager
     (THRILL_TPU_CKPT_DIR); a resumed run restores the newest loop epoch
     for ``name`` and continues after it. Returns the final carry in
-    the same form it was given (DIA in, DIA out)."""
+    the same form it was given (DIA in, DIA out).
+
+    ``invariants`` are what the body reads unchanged in every
+    iteration, handed to it after the carry (``body(carry,
+    *invariants)``): DIAs (an edge list, a degree table) and host
+    values (sizes, small numpy arrays). They are what makes a loop the
+    SAME loop in the next call: a body that is a plain function with
+    no closure cells, no default arguments and no ``self`` (a
+    module-level ``def``) whose tape took everything that differs
+    between calls from the carry and the invariant DIAs is kept on the
+    mesh, and a later ``Iterate`` of the same body OBJECT, name, carry
+    signature, invariant signatures and host values REBINDS it to its
+    own invariants and replays from its first iteration: no capture.
+    Like ``jax.jit``, the body is keyed by identity and whatever else
+    it reads (module globals) is taken as it was at capture. A bound
+    method, a ``functools.partial``, a callable object or a function
+    made by a factory captures in every call, as before.
+
+    The whole loop runs under one ``stage`` span named ``Iterate`` of
+    the carry's pipeline, the root of its ``loop`` spans."""
     if n <= 0:
         return carry
+    node = carry.node if isinstance(carry, DIA) \
+        else carry if isinstance(carry, DIABase) else None
+    if node is not None:
+        span = node.stage_span("Iterate")
+        pipe = node.pipe
+    else:
+        span = span_of(getattr(ctx, "tracer", None), "stage", "Iterate")
+        pipe = None
+    with span:
+        return _iterate(ctx, body, carry, n, name, checkpoint_every,
+                        tuple(invariants), pipe)
+
+
+def _iterate(ctx, body, carry, n, name, checkpoint_every, invariants,
+             pipe):
     mex = ctx.mesh_exec
     log = ctx.logger
     mgr = getattr(ctx, "checkpoint", None)
@@ -1022,11 +1171,11 @@ def Iterate(ctx, body: Callable, carry, n: int, *, name: str = "loop",
         """One plain iteration: st -> next st, through the full pull
         recursion + fusion planner."""
         if dia_mode:
-            out = body(_carry_dia(ctx, st))
+            out = body(_carry_dia(ctx, st, pipe), *invariants)
             if isinstance(out, DIABase):
                 out = DIA(out)
             return out._link().pull(consume=True)
-        return body(st)
+        return body(st, *invariants)
 
     def seal(st, i):
         if mgr is not None and checkpoint_every and dia_mode \
@@ -1076,148 +1225,196 @@ def Iterate(ctx, body: Callable, carry, n: int, *, name: str = "loop",
               "donated_bytes0": mex.stats_loop_donated_bytes}
     tracer = getattr(ctx, "tracer", None)
     tr_on = tracer is not None and tracer.enabled
-    i = start
-    while i < n:
-        if plan is None:
-            # ---- capture (or plain) iteration ------------------------
-            t0 = time.perf_counter()
-            d0 = mex.stats_dispatches
-            sp = (tracer.begin("loop", "capture", loop=name, iter=i)
-                  if tr_on else None)
-            try:
-                if can_replay and miss_streak < 2:
-                    state, plan = _capture(ctx, run_body, state,
-                                           name=name, it=i,
-                                           seed=tape_seed,
-                                           info=last_miss)
-                    if plan is not None:
-                        miss_streak = 0
-                        mex.stats_loop_plan_builds += 1
-                        report["captures"] += 1
-                        report["calls"] = len(plan.calls)
-                        report["pruned"] = (plan.pruned_invariant
-                                            + plan.pruned_dead)
-                        if plan.seeded:
-                            seed_mode = "tape"
-                        elif plan.seed_stale:
-                            seed_mode = "stale"
-                        if tape_token is not None:
-                            _note_tape(mex, tape_token, plan.meta)
-                    else:
-                        miss_streak += 1
-                        if miss_streak >= 2 and tape_token is not None:
-                            # deterministic reject: remember it so a
-                            # warm restart skips the capture probes
-                            _note_tape(mex, tape_token, {
-                                "capture": False,
-                                "reason": last_miss.get("reason",
-                                                        "?")[:200]})
-                else:
-                    state = run_body(state)
-            finally:
-                if sp is not None:
-                    tracer.end(sp, mode=("capture" if plan is not None
-                                         else "plain"))
-            dt = time.perf_counter() - t0
-            report["capture_s"] += dt
-            if log.enabled:
-                log.line(event="iteration", loop=name, iter=i,
-                         mode="capture" if plan is not None else "plain",
-                         seconds=round(dt, 6),
-                         dispatches=mex.stats_dispatches - d0,
-                         plan_calls=(len(plan.calls)
-                                     if plan is not None else None))
-            ckpt = seal(state, i)
-            i += 1
-            fresh_plan = True
-            continue
-
-        # ---- replayed iterations -------------------------------------
-        leaves, treedef = _carry_leaves(state, dia_mode, plan)
-        if leaves is None:
-            plan = None                      # carry shape drifted
-            continue
-        remaining = n - i
-        # whole-loop lowering: only when no checkpoint epoch is due
-        # inside the window (an epoch needs the carry on the host) —
-        # checkpoint_every without a CheckpointManager seals nothing,
-        # so it must not cost the fori lowering either
-        fori_ok = fori_enabled() \
+    fresh_plan, ckpt = True, False
+    # the tape of an earlier call of this very loop, rebound to this
+    # call's invariants: no capture at all
+    inv_leaves = share_token = None
+    if tape_token is not None and miss_streak < 2 \
             and not (checkpoint_every and mgr is not None) \
-            and plan.fori_eligible() and remaining > 1
-        t0 = time.perf_counter()
-        d0 = mex.stats_dispatches
-        sp = (tracer.begin("loop", "replay", loop=name, iter=i)
+            and _carries_nothing(body):
+        inv_leaves = _invariant_leaves(invariants)
+        share_token = _share_token(body, tape_token, state, invariants,
+                                   inv_leaves)
+    kept = mex.loop_plans.get(share_token) \
+        if share_token is not None else None
+    if kept is not None:
+        sp = (tracer.begin("loop", "rebind", loop=name)
               if tr_on else None)
         try:
-            try:
-                if faults.REGISTRY.active():
-                    faults.check(_F_REPLAY, loop=name, iter=i)
-                if fori_ok:
-                    out = plan.run_fori(leaves, remaining)
-                    if out is not None:
-                        mex.stats_loop_fori_iters += remaining
-                        report["fori_iters"] += remaining
-                        state = _rebuild_carry(out, treedef, dia_mode,
-                                               mex, plan)
-                        dt = time.perf_counter() - t0
-                        report["replay_s"] += dt
-                        if sp is not None:
-                            sp.attrs["fori_iters"] = remaining
-                        if log.enabled:
-                            log.line(event="loop_replay", loop=name,
-                                     iter=i, iters=remaining, fori=True,
-                                     seconds=round(dt, 6))
-                        i = n
-                        continue
-                out = plan.replay(
-                    leaves,
-                    donate and not faults.REGISTRY.active(),
-                    donate_carry=not fresh_plan and not ckpt)
-            except Exception as e:
-                # LOUD degradation: a failed replayed dispatch falls
-                # back to full re-planning for this iteration (the body
-                # path, which re-captures); the loop slows down, it
-                # never lies. Unless donation already consumed part of
-                # the carry mid-iteration — then there is nothing to
-                # re-plan FROM, and the only honest outcome is a clear
-                # error, not a deleted-array crash deep inside the pull
-                # recursion.
-                if sp is not None:
-                    sp.attrs["error"] = repr(e)[:200]
-                if any(getattr(l, "is_deleted", lambda: False)()
-                       for l in leaves):
-                    raise RuntimeError(
-                        f"loop '{name}' iteration {i}: a replayed "
-                        f"dispatch failed after part of the loop carry "
-                        f"was donated; cannot degrade to re-planning. "
-                        f"Re-run with THRILL_TPU_LOOP_DONATE=0 (or "
-                        f"from the last checkpoint epoch).") from e
-                mex.stats_loop_fallbacks += 1
-                report["fallbacks"] += 1
-                faults.note("recovery", what="loop_replay", loop=name,
-                            iter=i, error=repr(e)[:200])
-                if log.enabled:
-                    log.line(event="loop_replay_fallback", loop=name,
-                             iter=i, error=repr(e)[:200])
-                plan = None
-                continue
-            mex.stats_loop_replays += 1
-            report["replays"] += 1
-            state = _rebuild_carry(out, treedef, dia_mode, mex, plan)
-            dt = time.perf_counter() - t0
-            report["replay_s"] += dt
+            kept.bind([leaves for leaves, _ in inv_leaves])
+            plan = kept
+            mex.stats_loop_plan_rebinds += 1
+            report["rebound"] = True
+        except Exception as e:
+            # LOUD: the kept tape's prologue failed on this call's
+            # buffers; forget it and capture afresh
+            kept.unbind()
+            del mex.loop_plans[share_token]
+            mex.stats_loop_fallbacks += 1
+            report["fallbacks"] += 1
+            faults.note("recovery", what="loop_rebind", loop=name,
+                        error=repr(e)[:200])
             if log.enabled:
-                log.line(event="loop_replay", loop=name, iter=i,
-                         dispatches=mex.stats_dispatches - d0,
-                         seconds=round(dt, 6))
-            ckpt = seal(state, i)
-            fresh_plan = False
-            i += 1
+                log.line(event="loop_rebind_fallback", loop=name,
+                         error=repr(e)[:200])
         finally:
             if sp is not None:
-                tracer.end(sp)
+                tracer.end(sp, calls=len(kept.prologue))
+    bound = [kept] if kept is not None else []
+    i = start
+    try:
+        while i < n:
+            if plan is None:
+                # ---- capture (or plain) iteration ------------------------
+                t0 = time.perf_counter()
+                d0 = mex.stats_dispatches
+                sp = (tracer.begin("loop", "capture", loop=name, iter=i)
+                      if tr_on else None)
+                try:
+                    if can_replay and miss_streak < 2:
+                        state, plan = _capture(
+                            ctx, run_body, state, name=name, it=i,
+                            seed=tape_seed, info=last_miss,
+                            inv_leaves=inv_leaves
+                            if share_token is not None else None)
+                        if plan is not None:
+                            bound.append(plan)
+                            if share_token is not None:
+                                _keep_plan(mex, share_token, plan, log)
+                            miss_streak = 0
+                            mex.stats_loop_plan_builds += 1
+                            report["captures"] += 1
+                            report["calls"] = len(plan.calls)
+                            report["pruned"] = (plan.pruned_invariant
+                                                + plan.pruned_dead)
+                            if plan.seeded:
+                                seed_mode = "tape"
+                            elif plan.seed_stale:
+                                seed_mode = "stale"
+                            if tape_token is not None:
+                                _note_tape(mex, tape_token, plan.meta)
+                        else:
+                            miss_streak += 1
+                            if miss_streak >= 2 and tape_token is not None:
+                                # deterministic reject: remember it so a
+                                # warm restart skips the capture probes
+                                _note_tape(mex, tape_token, {
+                                    "capture": False,
+                                    "reason": last_miss.get("reason",
+                                                            "?")[:200]})
+                    else:
+                        state = run_body(state)
+                finally:
+                    if sp is not None:
+                        tracer.end(sp, mode=("capture" if plan is not None
+                                             else "plain"))
+                dt = time.perf_counter() - t0
+                report["capture_s"] += dt
+                if log.enabled:
+                    log.line(event="iteration", loop=name, iter=i,
+                             mode="capture" if plan is not None else "plain",
+                             seconds=round(dt, 6),
+                             dispatches=mex.stats_dispatches - d0,
+                             plan_calls=(len(plan.calls)
+                                         if plan is not None else None))
+                ckpt = seal(state, i)
+                i += 1
+                fresh_plan = True
+                continue
 
+            # ---- replayed iterations -------------------------------------
+            leaves, treedef = _carry_leaves(state, dia_mode, plan)
+            if leaves is None:
+                plan = None                      # carry shape drifted
+                continue
+            remaining = n - i
+            # whole-loop lowering: only when no checkpoint epoch is due
+            # inside the window (an epoch needs the carry on the host) —
+            # checkpoint_every without a CheckpointManager seals nothing,
+            # so it must not cost the fori lowering either
+            fori_ok = fori_enabled() \
+                and not (checkpoint_every and mgr is not None) \
+                and plan.fori_eligible() and remaining > 1
+            t0 = time.perf_counter()
+            d0 = mex.stats_dispatches
+            sp = (tracer.begin("loop", "replay", loop=name, iter=i)
+                  if tr_on else None)
+            try:
+                try:
+                    if faults.REGISTRY.active():
+                        faults.check(_F_REPLAY, loop=name, iter=i)
+                    if fori_ok:
+                        out = plan.run_fori(leaves, remaining)
+                        if out is not None:
+                            mex.stats_loop_fori_iters += remaining
+                            report["fori_iters"] += remaining
+                            state = _rebuild_carry(out, treedef, dia_mode,
+                                                   mex, plan)
+                            dt = time.perf_counter() - t0
+                            report["replay_s"] += dt
+                            if sp is not None:
+                                sp.attrs["fori_iters"] = remaining
+                            if log.enabled:
+                                log.line(event="loop_replay", loop=name,
+                                         iter=i, iters=remaining, fori=True,
+                                         seconds=round(dt, 6))
+                            i = n
+                            continue
+                    out = plan.replay(
+                        leaves,
+                        donate and not faults.REGISTRY.active(),
+                        donate_carry=not fresh_plan and not ckpt)
+                except Exception as e:
+                    # LOUD degradation: a failed replayed dispatch falls
+                    # back to full re-planning for this iteration (the body
+                    # path, which re-captures); the loop slows down, it
+                    # never lies. Unless donation already consumed part of
+                    # the carry mid-iteration — then there is nothing to
+                    # re-plan FROM, and the only honest outcome is a clear
+                    # error, not a deleted-array crash deep inside the pull
+                    # recursion.
+                    if sp is not None:
+                        sp.attrs["error"] = repr(e)[:200]
+                    if any(getattr(l, "is_deleted", lambda: False)()
+                           for l in leaves):
+                        raise RuntimeError(
+                            f"loop '{name}' iteration {i}: a replayed "
+                            f"dispatch failed after part of the loop carry "
+                            f"was donated; cannot degrade to re-planning. "
+                            f"Re-run with THRILL_TPU_LOOP_DONATE=0 (or "
+                            f"from the last checkpoint epoch).") from e
+                    mex.stats_loop_fallbacks += 1
+                    report["fallbacks"] += 1
+                    faults.note("recovery", what="loop_replay", loop=name,
+                                iter=i, error=repr(e)[:200])
+                    if log.enabled:
+                        log.line(event="loop_replay_fallback", loop=name,
+                                 iter=i, error=repr(e)[:200])
+                    if share_token is not None:
+                        # a tape that failed serves no later call either
+                        mex.loop_plans.pop(share_token, None)
+                    plan = None
+                    continue
+                mex.stats_loop_replays += 1
+                report["replays"] += 1
+                state = _rebuild_carry(out, treedef, dia_mode, mex, plan)
+                dt = time.perf_counter() - t0
+                report["replay_s"] += dt
+                if log.enabled:
+                    log.line(event="loop_replay", loop=name, iter=i,
+                             dispatches=mex.stats_dispatches - d0,
+                             seconds=round(dt, 6))
+                ckpt = seal(state, i)
+                fresh_plan = False
+                i += 1
+            finally:
+                if sp is not None:
+                    tracer.end(sp)
+
+    finally:
+        # a kept plan must not pin this call's tables until the next
+        for p in bound:
+            p.unbind()
     report["donated_bytes"] = (mex.stats_loop_donated_bytes
                                - report.pop("donated_bytes0"))
     if seed_mode is not None:
@@ -1231,7 +1428,7 @@ def Iterate(ctx, body: Callable, carry, n: int, *, name: str = "loop",
                                            if isinstance(v, float) else v)
                                        for k, v in report.items()})
     if dia_mode:
-        return _carry_dia(ctx, state)
+        return _carry_dia(ctx, state, pipe)
     return state
 
 
@@ -1259,13 +1456,97 @@ def _tape_token(name: str, dia_mode: bool, state,
     return ("loop_tape", name, bool(dia_mode), body_id, sig)
 
 
+def _invariant_leaves(invariants) -> Optional[List[Tuple[List[Any], Tuple]]]:
+    """Each invariant DIA's materialized buffers, in the order the
+    tape numbers them, with their signature; None where one is not
+    device-resident. A rebound tape never pulls the invariants, so a
+    deferred validation they owe runs here."""
+    out = []
+    for x in invariants:
+        node = x.node if isinstance(x, DIA) else x
+        if not isinstance(node, DIABase):
+            continue
+        shards = node.materialize(consume=False)
+        if not isinstance(shards, DeviceShards):
+            return None
+        shards.validate_pending()
+        leaves = list(jax.tree.leaves(shards.tree))
+        counts = shards._counts_host
+        if counts is None:
+            leaves.append(shards._counts_dev)
+        out.append((leaves, (
+            _leaf_sig(leaves), str(jax.tree.structure(shards.tree)),
+            shards.cap,
+            None if counts is None else tuple(int(c) for c in counts))))
+    return out
+
+
+def _carries_nothing(body) -> bool:
+    """May a tape of ``body`` serve another call? Only where the body
+    object IS the whole of the body: a plain function with no closure
+    cells, no default arguments and no ``self``. A bound method
+    forwards ``__code__`` and ``__closure__`` to its function and
+    differs only in ``__self__``; a factory-made ``def body(c, x, a=a)``
+    differs only in ``__defaults__``: two such bodies have one
+    bytecode and would replay each other's constants."""
+    return (type(body) is types.FunctionType
+            and not body.__closure__
+            and not body.__defaults__
+            and not body.__kwdefaults__)
+
+
+def _share_token(body, tape_token, state, invariants, inv_leaves):
+    """What makes two ``Iterate`` calls the same loop: the body OBJECT
+    (a function hashes and compares by identity, and the key holds it,
+    so no other function can take its place: what ``jax.jit`` does; the
+    bytecode hash in ``tape_token`` merges plan-store records and is no
+    identity), the name and the carry's signature, the invariant DIAs'
+    signatures (host-known counts by value: the tape bakes them), the
+    host invariants by content, the carry's host counts. None where
+    one of them cannot be signed: the tape then serves its own call
+    alone."""
+    if inv_leaves is None:
+        return None
+    host = []
+    for x in invariants:
+        if isinstance(x, (DIA, DIABase)):
+            continue
+        if isinstance(x, np.ndarray) and x.nbytes <= 4096:
+            host.append((x.dtype.str, x.shape, x.tobytes()))
+        elif isinstance(x, (bool, int, float, str, bytes, type(None),
+                            np.generic)):
+            host.append((type(x).__name__, repr(x)))
+        else:
+            return None
+    counts = getattr(state, "_counts_host", None)
+    return (body, tape_token, tuple(sig for _, sig in inv_leaves),
+            tuple(host),
+            None if counts is None else tuple(int(c) for c in counts))
+
+
+def _keep_plan(mex, token, plan: "LoopPlan", log) -> None:
+    """Keep a freshly captured tape on the mesh for the next call of
+    the same loop, or say why not."""
+    if plan.unshared is not None:
+        if log.enabled:
+            log.line(event="loop_tape_unshared", loop=plan.name,
+                     reason=plan.unshared)
+        return
+    kept = mex.loop_plans
+    while len(kept) >= 32:
+        del kept[next(iter(kept))]
+    kept[token] = plan
+
+
 def _capture(ctx, run_body, state, name="loop", it=0, seed=None,
-             info=None):
+             info=None, inv_leaves=None):
     """Run one body iteration with the tape recorder installed.
     Returns (next_state, LoopPlan or None). ``seed`` is the plan
     store's remembered tape metadata (LoopPlan trusts a digest match);
     ``info`` (dict) receives the miss reason for the caller's own
-    metadata bookkeeping."""
+    metadata bookkeeping; ``inv_leaves`` are the invariant DIAs'
+    buffers (:func:`_invariant_leaves`) where the loop may be kept for
+    later calls."""
     mex = ctx.mesh_exec
     log = ctx.logger
 
@@ -1303,7 +1584,12 @@ def _capture(ctx, run_body, state, name="loop", it=0, seed=None,
         leaves = jax.tree.leaves(state)
         carry_ids = {id(l): s for s, l in enumerate(leaves)}
         n_carry = len(leaves)
-    rec = _Recorder(carry_ids, known=list(jax.live_arrays()))
+    bufs = [leaves for leaves, _ in inv_leaves or ()]
+    rec = _Recorder(
+        carry_ids, known=list(jax.live_arrays()),
+        inv_ids={id(l): (k, j) for k, leaves in enumerate(bufs)
+                 for j, l in enumerate(leaves)},
+        small_cache=mex._put_small_cache)
     prev = mex.loop_recorder
     if prev is not None:
         # nested Iterate inside a capturing body: the inner loop's
@@ -1376,7 +1662,8 @@ def _capture(ctx, run_body, state, name="loop", it=0, seed=None,
                         "dispatch stream (eager host math in the "
                         "body?)", out_state)
     plan = LoopPlan(mex, rec.calls, carry_out, n_carry, name=name,
-                    plan_reads=rec.plan_reads, seed=seed)
+                    plan_reads=rec.plan_reads, seed=seed, inv_leaves=bufs,
+                    unshared=rec.unshared)
     if plan.seed_stale and log.enabled:
         # stale plan-store metadata: LOUD, and the full fresh
         # analysis just ran — the seed cost nothing but this line

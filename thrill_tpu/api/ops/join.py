@@ -32,6 +32,11 @@ from ...common.config import round_up_pow2
 from ..dia import DIA
 from ..dia_base import DIABase
 
+# the name the dense-index join's gather carries in a device profile
+# (jax.named_scope: HLO metadata, no operation added), beside
+# core/rowmove.py's and core/device_sort.py's
+DENSE_SCOPE = "join_gather"
+
 
 class InnerJoinNode(DIABase):
     def __init__(self, ctx, llink, rlink, lkey, rkey, join_fn,
@@ -324,8 +329,9 @@ class InnerJoinNode(DIABase):
                 rall = jax.tree.map(
                     lambda x: lax.all_gather(x, AXIS).reshape(
                         (W * rcap,) + x.shape[1:]), rtree)
-            rsel = jax.tree.map(lambda x: jnp.take(x, gidx, axis=0),
-                                rall)
+            with jax.named_scope(DENSE_SCOPE):
+                rsel = jax.tree.map(lambda x: jnp.take(x, gidx, axis=0),
+                                    rall)
             out = jfn(ltree, rsel)
             return out, lmask & (key >= 0) & (key < n)
 

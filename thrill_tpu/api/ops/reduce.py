@@ -31,6 +31,10 @@ from ..dia import DIA
 from ..dia_base import DIABase
 from ...parallel.mesh import AXIS
 
+# the name the sort-free ReduceToIndex scatters carry in a device
+# profile (jax.named_scope: HLO metadata, no operation added)
+SCATTER_SCOPE = "reduce_to_index"
+
 
 # device DuplicateDetection registers are sized per site by
 # core/preshuffle.register_width (collisions only cause unnecessary
@@ -862,6 +866,7 @@ def _scatter_fold_specs(reduce_fn, treedef, leaves):
     return specs
 
 
+@jax.named_scope(SCATTER_SCOPE)
 def _scatter_reduce_apply(tree, valid, local_idx, range_size, out_cap,
                           specs, neutral):
     """The dense ReduceToIndex phase as pure scatters — NO sort.

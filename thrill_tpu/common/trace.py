@@ -58,7 +58,22 @@ Category / name; site; read by:
   tests/common/test_doctor.py.
 * ``mem`` / ladder rung (instants); ``mem/pressure.py``,
   ``api/fusion.py`` degradations; tests/common/test_trace.py (lane).
-* ``loop`` / ``capture``, ``replay``; ``api/loop.py``;
+* ``stage`` / ``Iterate``; ``api/loop.py Iterate``, the root of a
+  loop: of the carry DIA's pipeline (``pipe``, ``dia_id``; a pytree
+  carry has neither), around the carry's first pull and every
+  iteration; ``_LoopCarryNode`` and the carry rebuilt after a replay
+  join that pipeline instead of starting one, so a job that loops is
+  ONE pipeline; ``host_plan_s_per_job`` (self time) and the window
+  rule, tests/api/test_loop_spans.py.
+* ``loop`` / ``capture`` (one iteration through the pull recursion,
+  captured or plain, ``mode``), ``replay`` (one iteration off the
+  tape, or ``fori_iters`` of them in one whole-loop dispatch;
+  ``error`` where it fell back), ``rebind`` (a call that took over a
+  kept tape: its prologue, ``calls``); ``api/loop.py``, children of
+  ``stage`` / ``Iterate``, parents of an iteration's dispatches, waits,
+  fetches and stages; ``loop_host_s_per_job`` (self time),
+  ``loop_captures_in_window``, ``iterations_replayed_share``
+  (``chipbench/loop_window.py``), tests/api/test_loop_spans.py,
   tests/common/test_trace.py (lane).
 * ``service`` / ``queue_wait`` (``emit_span``), ``job:<name>``;
   ``service/scheduler.py``; tests/common/test_trace.py.

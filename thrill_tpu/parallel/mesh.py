@@ -378,6 +378,7 @@ class MeshExec:
         # fallbacks to full re-planning, and HBM bytes donated back to
         # XLA on replayed dispatches
         self.stats_loop_plan_builds = 0
+        self.stats_loop_plan_rebinds = 0
         self.stats_loop_replays = 0
         self.stats_loop_fori_iters = 0
         self.stats_loop_fallbacks = 0
@@ -406,6 +407,9 @@ class MeshExec:
         # per-Iterate reports (phase timings, replay hit rate) for
         # bench.py / tools/loop_report.py
         self.loop_reports: list = []
+        # tapes kept for the next Iterate call of the same loop
+        # (api/loop.py _share_token -> LoopPlan)
+        self.loop_plans: Dict[Tuple, Any] = {}
         self._put_small_cache: Dict[Any, jax.Array] = {}
         # deferred device-side validations (e.g. InnerJoin
         # out_size_hint overflow): ops that skip a blocking host sync
@@ -609,7 +613,7 @@ class MeshExec:
             if not isinstance(l, jax.Array):
                 l = jnp.asarray(l)
                 if rec is not None:
-                    rec.bless(l)
+                    rec.bless(l, operand=True)
             out.append(l)
         return out
 
