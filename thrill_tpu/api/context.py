@@ -990,6 +990,7 @@ class Context:
             # HBM bytes donated back to XLA on replayed dispatches
             "loop_plan_builds": mex.stats_loop_plan_builds,
             "loop_plan_rebinds": mex.stats_loop_plan_rebinds,
+            "r2i_index_plans": mex.stats_r2i_index_plans,
             "loop_replays": mex.stats_loop_replays,
             "loop_fori_iters": mex.stats_loop_fori_iters,
             "loop_replay_fallbacks": mex.stats_loop_fallbacks,

@@ -51,6 +51,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import re
+import weakref
 from typing import Any, Callable, List, Optional, Tuple
 
 import jax
@@ -63,6 +64,7 @@ from ..common import decisions as _decisions
 from ..common import faults
 from ..common import trace as _trace
 from ..common.retry import default_policy
+from ..core.jaxpr_deps import output_deps
 from ..data.shards import DeviceShards, HostShards, compact_valid
 from ..parallel.mesh import AXIS
 from .stack import (Stack, apply_stack_host_list, apply_stack_traced,
@@ -81,6 +83,9 @@ class TraceCtx:
     def __init__(self, W: int) -> None:
         self.W = W
         self.aux: dict = {}          # name -> per-worker scalar output
+        # the index plan of the segment being traced
+        # (Segment.index_plan), or None
+        self.index_plan: Optional[Tuple] = None
 
     @staticmethod
     def count(mask: jnp.ndarray) -> jnp.ndarray:
@@ -149,6 +154,45 @@ class Segment:
     # ladder's LAST rung runs the chain through these when even split
     # chunks exhaust HBM
     host_apply: Optional[Callable] = None
+    # ``index_plan(fctx, tree, mask, bound)`` -> a tuple of per-worker
+    # arrays, or None: what this segment derives from the index column
+    # and the mask of the state it is handed, reading no value
+    # (ReduceToIndex's fold over sorted runs). ``trace`` finds it in
+    # ``fctx.index_plan``. The stitched program computes it in place;
+    # :func:`index_plans` has it also as a program of its own, for a
+    # whole-loop program (api/loop.py) whose carry it does not read
+    index_plan: Optional[Callable] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexPlans:
+    """The index plans (Segment.index_plan) that one run of a compiled
+    program computes in place."""
+    count: int
+    # each plan as a program of its own: (the positions of the
+    # program's arguments that it reads, the shard_map program over
+    # just those)
+    plans: Tuple[Tuple[Tuple[int, ...], Callable], ...] = ()
+    # the program in the form that takes the plans' outputs as further
+    # arguments behind its own, in place of computing them; None where
+    # there is no such form (the per-op path)
+    body: Optional[Callable] = None
+
+
+# raw program -> IndexPlans, for the programs that compute any
+_INDEX_PLANS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def note_index_plans(fn, info: IndexPlans) -> None:
+    _INDEX_PLANS[fn.raw] = info
+
+
+def index_plans(fn) -> Optional[IndexPlans]:
+    """What the compiled program ``fn`` computes of index plans, or
+    None. Keyed by the raw program, which a tape records (api/loop.py
+    asks with the recorded call, whatever twin of it a replay runs)."""
+    raw = getattr(fn, "raw", None)
+    return None if raw is None else _INDEX_PLANS.get(raw)
 
 
 def _src_sig(shards: DeviceShards, flat) -> Tuple:
@@ -268,35 +312,74 @@ class FusionPlan:
         holder: dict = {}
         W = mex.num_workers
         caps = [s[0] for s in sigs]
-        head, tail, last = self.head, self.segments, segs[-1]
+        head, tail = self.head, self.segments
+        # a source's valid rows are a prefix (its mask is made from its
+        # count), so are those behind a segment that says so, and a
+        # map-only stack keeps them where they are: no compaction
+        # scatter then (72 ns per row and 8-byte leaf on a v5e). Rows
+        # past the count hold whatever the maps made of the padding
+        compact = head is None
+        for seg in segs:
+            compact = seg.already_compact or (
+                compact and seg.preserves_counts and seg.row_local)
+        nd = len(srcs) + sum(len(f_[0]) for f_ in src_flat)
+        nb = sum(len(bf[0]) for bf in bound_flat)
+        in_specs = (P(AXIS),) * nd + (P(),) * nb
 
-        def build():
-            def f(*args):
-                nsrc = len(srcs)
-                counts = args[:nsrc]
-                pos = nsrc
-                states = []
-                for k, (leaves_, td_) in enumerate(src_flat):
-                    ls = args[pos:pos + len(leaves_)]
-                    pos += len(leaves_)
-                    tree = jax.tree.unflatten(td_, [l[0] for l in ls])
-                    mask = jnp.arange(caps[k]) < counts[k][0, 0]
-                    states.append((tree, mask))
-                bounds_t = []
-                for bl, bt in bound_flat:
-                    bs = args[pos:pos + len(bl)]
-                    pos += len(bl)
-                    bounds_t.append(jax.tree.unflatten(bt, list(bs)))
-                fctx = TraceCtx(W)
-                si = 0
-                if head is not None:
-                    tree, mask = head.trace(fctx, states, bounds_t[0])
-                    si = 1
+        def chain(fctx, args, stop, pre):
+            """Trace the chain from the flat arguments up to tail
+            segment ``stop`` (exclusive): the ``(tree, mask)`` there and
+            the traced bounds of the tail. ``pre[k]`` is tail segment
+            ``k``'s index plan where that ran before this program; any
+            other is computed here."""
+            nsrc = len(srcs)
+            counts = args[:nsrc]
+            pos = nsrc
+            states = []
+            for k, (leaves_, td_) in enumerate(src_flat):
+                ls = args[pos:pos + len(leaves_)]
+                pos += len(leaves_)
+                tree = jax.tree.unflatten(td_, [l[0] for l in ls])
+                mask = jnp.arange(caps[k]) < counts[k][0, 0]
+                states.append((tree, mask))
+            bounds_t = []
+            for bl, bt in bound_flat:
+                bs = args[pos:pos + len(bl)]
+                pos += len(bl)
+                bounds_t.append(jax.tree.unflatten(bt, list(bs)))
+            if head is not None:
+                tree, mask = head.trace(fctx, states, bounds_t[0])
+                bounds_t = bounds_t[1:]
+            else:
+                tree, mask = states[0]
+            for k, (seg, bound_t) in enumerate(zip(tail[:stop],
+                                                   bounds_t)):
+                if k in pre:
+                    fctx.index_plan = pre[k]
                 else:
-                    tree, mask = states[0]
-                for seg, bound_t in zip(tail, bounds_t[si:]):
-                    tree, mask = seg.trace(fctx, tree, mask, bound_t)
-                if last.already_compact:
+                    fctx.index_plan = seg.index_plan and seg.index_plan(
+                        fctx, tree, mask, bound_t)
+                tree, mask = seg.trace(fctx, tree, mask, bound_t)
+            return tree, mask, bounds_t
+
+        args = ([s.counts_device() for s in srcs]
+                + [l for f_ in src_flat for l in f_[0]]
+                + [l for bf in bound_flat for l in bf[0]])
+
+        def program(split):
+            """The stitched program. ``split`` lists (tail index, number
+            of arrays) of the index plans that arrive as sharded
+            arguments behind the bounds, in place of being computed
+            here: the form a loop runs with the plans hoisted."""
+            def f(*args):
+                pos = nd + nb
+                pre_t = {}
+                for k, n_k in split:
+                    pre_t[k] = tuple(a[0] for a in args[pos:pos + n_k])
+                    pos += n_k
+                fctx = TraceCtx(W)
+                tree, mask, _ = chain(fctx, args, len(tail), pre_t)
+                if compact:
                     out_tree = tree
                     new_count = jnp.sum(mask.astype(jnp.int32))
                 else:
@@ -310,23 +393,30 @@ class FusionPlan:
                         *[fctx.aux[n][None, None]
                           for n in holder["aux_names"]])
 
-            nd = len(srcs) + sum(len(f_[0]) for f_ in src_flat)
-            nb = sum(len(bf[0]) for bf in bound_flat)
-            in_specs = (P(AXIS),) * nd + (P(),) * nb
+            n_pre = sum(n_k for _, n_k in split)
             # the program's name on the device plane says which ops it
             # carries: jit_fused_Sort, jit_fused_ReduceByKey.pre_...
             name = re.sub(r"[^A-Za-z0-9_.]+", "_", "fused_" + "_".join(
                 s.label for s in segs))[:64]
-            return mex.smap(f, nd + nb, in_specs=in_specs,
-                            name=name), holder
+            return mex.smap(f, nd + nb + n_pre,
+                            in_specs=in_specs + (P(AXIS),) * n_pre,
+                            name=name)
+
+        def build():
+            fn = program(())
+            plans = self._index_plan_programs(args, nd, in_specs, chain)
+            if plans:
+                note_index_plans(fn, IndexPlans(
+                    count=len(plans),
+                    plans=tuple((used, raw) for _, _, used, raw in plans),
+                    body=program([(k, n_k)
+                                  for k, n_k, _, _ in plans]).raw))
+            return fn, holder
 
         fn, h = mex.cached(key, build)
         split = self._proactive_split(fn, srcs, segs)
         if split is not None:
             return split
-        args = ([s.counts_device() for s in srcs]
-                + [l for f_ in src_flat for l in f_[0]]
-                + [l for bf in bound_flat for l in bf[0]])
         if faults.REGISTRY.active():
             # per-op fault sites survive fusion: each constituent op
             # keeps a named site, and a transient fire at the stage
@@ -380,6 +470,9 @@ class FusionPlan:
                              predicted=pred, reason=why,
                              ops=ops_label, n_ops=len(segs),
                              dia_ids=[s.dia_id for s in segs])
+        plans = index_plans(fn)
+        if plans is not None:
+            mex.stats_r2i_index_plans += plans.count
         try:
             out = fn(*args)
         except Exception as e:
@@ -418,6 +511,53 @@ class FusionPlan:
                 if seg.finalize is not None:
                     seg.finalize(self, shards)
         return shards
+
+    def _index_plan_programs(self, args, nd, in_specs, chain) -> list:
+        """The index plans of this chain's tail segments
+        (Segment.index_plan) as programs of their own: ``(tail index,
+        number of outputs, the positions of the arguments it reads, the
+        shard_map program over just those)`` for each that yields one.
+
+        A plan is traced behind the chain up to its segment, and the
+        arguments it reads are those its outputs can depend on
+        (core/jaxpr_deps.py)."""
+        mex = self.mex
+        avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
+        out = []
+        for k, seg in enumerate(self.segments):
+            if seg.index_plan is None:
+                continue
+            n_out = []
+
+            def g(*a, k=k, seg=seg, n_out=n_out):
+                fctx = TraceCtx(mex.num_workers)
+                tree, mask, bounds_t = chain(fctx, a, k, {})
+                res = seg.index_plan(fctx, tree, mask, bounds_t[k]) or ()
+                n_out[:] = [len(res)]
+                return tuple(r[None] for r in res)
+
+            closed = jax.make_jaxpr(
+                mex.smap(g, len(args), in_specs=in_specs).raw)(*avals)
+            if not n_out[0]:
+                continue
+            used = tuple(sorted(frozenset().union(
+                *output_deps(closed.jaxpr))))
+
+            def pruned(*sub, g=g, used=used):
+                # inside shard_map a sharded argument is its worker's
+                # [1, ...] block; what the outputs cannot depend on is
+                # dead code whatever stands in for it
+                a = [jnp.zeros(((1,) + v.shape[1:]) if i < nd
+                               else v.shape, v.dtype)
+                     for i, v in enumerate(avals)]
+                for i, x in zip(used, sub):
+                    a[i] = x
+                return g(*a)
+
+            out.append((k, n_out[0], used, mex.smap(
+                pruned, len(used),
+                in_specs=tuple(in_specs[i] for i in used)).raw))
+        return out
 
     def reexecute(self, new_cap: int) -> DeviceShards:
         """Recovery re-dispatch with the head refit to ``new_cap``

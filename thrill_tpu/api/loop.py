@@ -125,7 +125,9 @@ from jax import lax
 
 from ..common import faults
 from ..common.trace import span_of
+from ..core.jaxpr_deps import output_deps as _jaxpr_output_deps
 from ..data.shards import DeviceShards, HostShards
+from . import fusion
 from .dia import DIA
 from .dia_base import DIABase
 
@@ -415,53 +417,6 @@ class _Recorder:
 # ----------------------------------------------------------------------
 # per-output-leaf taint refinement (jaxpr input->output reachability)
 # ----------------------------------------------------------------------
-
-# call-like primitives whose sub-jaxpr maps eqn invars to outvars
-# one-to-one, so reachability may recurse instead of union-ing all
-# inputs into all outputs. Loops/conds (scan, while, cond) are NOT
-# here on purpose: their iteration semantics mix operands across
-# rounds, so they keep the conservative union.
-_CALL_PRIMS = frozenset({"pjit", "closed_call", "core_call", "xla_call",
-                         "custom_jvp_call", "custom_vjp_call",
-                         "remat", "checkpoint", "shard_map"})
-
-
-def _jaxpr_output_deps(jaxpr) -> List[frozenset]:
-    """For each jaxpr output, the set of INVAR indices it may depend
-    on — a conservative over-approximation (per-equation union, with
-    recursion into call-like sub-jaxprs so a ``pjit``/``shard_map``
-    wrapper does not collapse the whole program into one equation)."""
-    deps = {v: frozenset([i]) for i, v in enumerate(jaxpr.invars)}
-
-    def get(atom):
-        if hasattr(atom, "val"):           # Literal
-            return frozenset()
-        return deps.get(atom, frozenset())  # constvars -> empty
-
-    for eqn in jaxpr.eqns:
-        sub = None
-        if eqn.primitive.name in _CALL_PRIMS:
-            p = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-            if p is not None:
-                inner = getattr(p, "jaxpr", p)   # ClosedJaxpr -> Jaxpr
-                if len(inner.invars) == len(eqn.invars):
-                    sub = inner
-        if sub is not None:
-            inner_out = _jaxpr_output_deps(sub)
-            in_sets = [get(a) for a in eqn.invars]
-            for ov, od in zip(eqn.outvars, inner_out):
-                s = frozenset()
-                for k in od:
-                    s |= in_sets[k]
-                deps[ov] = s
-            continue
-        u = frozenset()
-        for a in eqn.invars:
-            u |= get(a)
-        for ov in eqn.outvars:
-            deps[ov] = u
-    return [get(o) for o in jaxpr.outvars]
-
 
 def _call_output_deps(c: "_Call") -> Optional[List[frozenset]]:
     """Per-output-leaf invar dependence of one recorded call, from a
@@ -855,6 +810,7 @@ class LoopPlan:
         self._pro = pro = {}
         for p, call in enumerate(self.prologue):
             out = call.fn(*[self._resolve(ref) for ref in call.arg_refs])
+            self._count_index_plans(call)
             for j, o in enumerate(jax.tree.leaves(out)):
                 pro[(p, j)] = o
 
@@ -887,10 +843,18 @@ class LoopPlan:
                     mex.stats_loop_donated_bytes += sum(
                         getattr(args[p], "nbytes", 0) for p in pos)
             out = fn(*args)
+            self._count_index_plans(call)
             for j, o in enumerate(jax.tree.leaves(out)):
                 if (i, j) in self.used_outputs:
                     vals[(i, j)] = o
         return [self._resolve(ref, carry, vals) for ref in self.carry_out]
+
+    def _count_index_plans(self, call: "_Call", runs: int = 1) -> None:
+        """``r2i_index_plans``: the index plans that ``runs`` runs of a
+        recorded call compute in place (api/fusion.py)."""
+        plans = fusion.index_plans(call.fn)
+        if plans is not None:
+            self.mex.stats_r2i_index_plans += plans.count * runs
 
     # -- whole-loop fori_loop lowering ---------------------------------
     def fori_eligible(self) -> bool:
@@ -922,6 +886,17 @@ class LoopPlan:
         if self._fori_failed or not self.fori_eligible():
             return None
         calls = self.calls
+        # a call that computes index plans in place (api/fusion.py
+        # Segment.index_plan: ReduceToIndex's fold over sorted runs),
+        # all from operands of the loop, derives them from nothing that
+        # changes with the iteration: the plans run once, before the
+        # loop, and the loop runs the call's form that takes them
+        plans = [fusion.index_plans(c.fn) for c in calls]
+        hoisted = frozenset(
+            i for i, (c, ip) in enumerate(zip(calls, plans))
+            if ip is not None and ip.body is not None and all(
+                c.arg_refs[p][0] in _OPERAND_KINDS
+                for reads, _ in ip.plans for p in reads))
         # a carry leaf that is the same buffer in every iteration is
         # closed over by the program, whatever it was computed from
         out_slots: List[Tuple] = [
@@ -952,7 +927,7 @@ class LoopPlan:
                          or ("rawid", id(c.fn.raw)) for c in calls),
                    tuple(tuple(ref_sig(r) for r in c.arg_refs)
                          for c in calls),
-                   tuple(sorted(used)), out_sig)
+                   tuple(sorted(used)), out_sig, tuple(sorted(hoisted)))
 
             built = []
 
@@ -970,28 +945,50 @@ class LoopPlan:
                     if r[0] == "tree":
                         return ("tree", r[1], [strip(s) for s in r[2]])
                     return r
-                call_plan = [(c.fn.raw, [strip(r) for r in c.arg_refs])
-                             for c in calls]
+                call_plan = [(c.fn.raw, [strip(r) for r in c.arg_refs],
+                              plans[i] if i in hoisted else None)
+                             for i, c in enumerate(calls)]
 
                 def loop_fn(carry_t, consts, k):
+                    ci = iter(consts)
+
+                    def operand(ref):
+                        """The loop's operand behind a ref, taken in
+                        tape order; None for what an iteration makes."""
+                        if ref[0] == "const":
+                            return next(ci)
+                        if ref[0] == "tree":
+                            return [operand(s) for s in ref[2]]
+                        return None
+
+                    operands = [[operand(r) for r in refs]
+                                for _, refs, _ in call_plan]
+                    before = {
+                        i: [o for reads, plan in ip.plans
+                            for o in plan(*[operands[i][p] for p in reads])]
+                        for i, (_, _, ip) in enumerate(call_plan)
+                        if ip is not None}
+
                     def body(_, c):
-                        ci = iter(consts)
                         vals: Dict[Tuple[int, int], Any] = {}
 
-                        def resolve(ref):
+                        def resolve(ref, op):
                             if ref[0] == "carry":
                                 return c[ref[1]]
                             if ref[0] == "val":
                                 return vals[ref[1]]
                             if ref[0] == "const":
-                                return next(ci)
+                                return op
                             return jax.tree.unflatten(
-                                ref[1], [resolve(s) for s in ref[2]])
+                                ref[1], [resolve(s, o)
+                                         for s, o in zip(ref[2], op)])
 
-                        for i, (raw, refs) in enumerate(call_plan):
-                            args = [resolve(r) for r in refs]
-                            leaves = jax.tree.leaves(raw(*args))
-                            for j, o in enumerate(leaves):
+                        for i, (raw, refs, ip) in enumerate(call_plan):
+                            args = [resolve(r, o)
+                                    for r, o in zip(refs, operands[i])]
+                            out = (raw(*args) if ip is None
+                                   else ip.body(*args, *before[i]))
+                            for j, o in enumerate(jax.tree.leaves(out)):
                                 if (i, j) in used:
                                     vals[(i, j)] = o
                         return tuple(
@@ -1026,6 +1023,9 @@ class LoopPlan:
             self._fori = fn
         # the dispatch counter ticks inside _CountedJit.__call__ now
         out = self._fori(tuple(carry), self._fori_consts(), np.int32(k))
+        for i, c in enumerate(calls):
+            # in every iteration, or once where they are hoisted
+            self._count_index_plans(c, 1 if i in hoisted else k)
         return list(out)
 
 

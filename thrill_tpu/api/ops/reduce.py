@@ -10,6 +10,21 @@ exchange — pre-reduction cuts shuffle volume exactly like the reference's
 pre-phase table, and the whole pipeline is three jitted SPMD programs.
 Host storage falls back to dict-based aggregation per worker (the same
 algorithm the reference runs, in Python).
+
+ReduceToIndex's dense local phase has three engines, chosen from what
+the code can see of the reduce function and the leaves:
+
+- declarative ``FieldReduce`` specs over leaves of 4 bytes or fewer:
+  one ``.at[row].add/min/max`` per field, no sort (6.8 ns per update on
+  a v5e; ``_scatter_reduce_apply``);
+- a ``"sum"`` of 8-byte leaves in such a tree: the fold over runs sorted
+  by row (core/segmented.py ``sorted_fold_*``), because XLA:TPU scatters
+  8-byte values as pairs at 122-126 ns per update; the index plan reads
+  no value, so a loop with an invariant index sorts once
+  (api/fusion.py ``Segment.index_plan``);
+- any other reduce function: sort the items by index and reduce the
+  runs (``sort_by_key_words`` + ``reduce_runs``), then scatter the
+  representatives.
 """
 
 from __future__ import annotations
@@ -31,8 +46,9 @@ from ..dia import DIA
 from ..dia_base import DIABase
 from ...parallel.mesh import AXIS
 
-# the name the sort-free ReduceToIndex scatters carry in a device
-# profile (jax.named_scope: HLO metadata, no operation added)
+# the name ReduceToIndex's FieldReduce engines carry in a device profile
+# (jax.named_scope: HLO metadata, no operation added); the fold of
+# 8-byte sums nests ``index_plan`` and ``sorted_fold`` under it
 SCATTER_SCOPE = "reduce_to_index"
 
 
@@ -866,40 +882,69 @@ def _scatter_fold_specs(reduce_fn, treedef, leaves):
     return specs
 
 
+def _dense_pos(valid, local_idx, range_size, out_cap):
+    """Target row of every item, [cap] int32: its range-relative index,
+    or the dump row ``out_cap`` for masked and out-of-range items."""
+    ok = valid & (local_idx >= 0) & (local_idx < range_size)
+    return jnp.where(ok, local_idx, out_cap).astype(jnp.int32)
+
+
+def _wants_sorted_fold(specs, leaves) -> bool:
+    """Does any leaf take the fold over sorted runs? A "sum" of 8-byte
+    values does: XLA:TPU scatters those as pairs of 32-bit values at
+    122-126 ns per update (6.8 ns for a 32-bit scatter on the same
+    indices). Decided from spec and dtype width alone, so every backend
+    runs the path the chip runs."""
+    return any(s == "sum" and l.dtype.itemsize == 8
+               for s, l in zip(specs, leaves))
+
+
 @jax.named_scope(SCATTER_SCOPE)
-def _scatter_reduce_apply(tree, valid, local_idx, range_size, out_cap,
-                          specs, neutral):
-    """The dense ReduceToIndex phase as pure scatters — NO sort.
+def _scatter_reduce_apply(tree, pos, out_cap, specs, neutral, plan=None):
+    """The dense ReduceToIndex phase without a sort of the items.
 
-    The sorted engine pays an XLA argsort (~43 ms at 64 k rows on
-    XLA:CPU — the dominant cost of iterative PageRank/k-means bodies);
-    with declarative FieldReduce specs the same result is a direct
-    ``.at[idx].add/min/max`` (deterministic: XLA applies duplicate
+    With declarative FieldReduce specs a field's result is a direct
+    ``.at[pos].add/min/max`` (deterministic: XLA applies duplicate
     updates in operand order) plus, for "first" fields, a scatter-min
-    over arrival positions and one gather. Out-of-range indices are
-    DROPPED (routed to the dump slot) rather than clamped like the
-    sorted engine's clip — they cannot occur through the public op
-    (the exchange routes every item into its worker's range).
+    over arrival positions and one gather: 6.8 ns per update for values
+    of 4 bytes or fewer on a v5e, which no sort of the rows beats. A
+    "sum" of 8-byte values would cost 122-126 ns per update there
+    (XLA:TPU's two-operand scatter), so it folds over the runs of an
+    index plan instead (core/segmented.py ``sorted_fold_sum``: one
+    gather by the plan's permutation, 16 ns per row, a segmented scan
+    and a gather at the run ends); where a tree has such a leaf, its
+    "first" fields and presence masks read the same plan and the
+    scatter-min is not run. The plan sorts the 32-bit target rows, never
+    the items. Out-of-range indices are DROPPED (routed to the dump
+    row) rather than clamped like the sorted engine's clip: they cannot
+    occur through the public op (the exchange routes every item into
+    its worker's range).
 
-    ``local_idx``: range-start-relative indices [cap]; ``valid``: item
-    mask [cap]; ``range_size``: traced scalar (this worker's dense
-    rows); ``out_cap``: static padded output rows. Returns the dense
-    output tree ([out_cap, ...] leaves, neutral at untouched rows).
+    ``pos``: target rows [cap] (:func:`_dense_pos`); ``out_cap``:
+    static padded output rows; ``plan``: the index plan of ``pos``
+    where the caller has it (the fused segment's own), else it is
+    computed here when a leaf needs it. Returns the dense output
+    tree ([out_cap, ...] leaves, neutral at untouched rows).
     """
     leaves, td = jax.tree.flatten(tree)
-    cap = valid.shape[0]
-    ok = valid & (local_idx >= 0) & (local_idx < range_size)
-    pos = jnp.where(ok, local_idx, out_cap).astype(jnp.int32)
+    cap = pos.shape[0]
+    if plan is None and _wants_sorted_fold(specs, leaves):
+        plan = segmented.sorted_fold_plan(pos, out_cap)
     win = None          # first-arrival winner per bin, computed lazily
 
     def winners():
         nonlocal win
         if win is None:
-            arrival = jnp.where(ok, jnp.arange(cap, dtype=jnp.int32),
-                                cap)
+            arrival = jnp.where(pos < out_cap,
+                                jnp.arange(cap, dtype=jnp.int32), cap)
             win = jnp.full(out_cap + 1, cap,
                            jnp.int32).at[pos].min(arrival)[:out_cap]
         return win
+
+    def present_rows():
+        if plan is not None:
+            return plan[1][1:] > plan[1][:-1]
+        return winners() < cap
 
     nleaves = (jax.tree.leaves(neutral) if neutral is not None
                else [None] * len(leaves))
@@ -907,30 +952,35 @@ def _scatter_reduce_apply(tree, valid, local_idx, range_size, out_cap,
     for s, leaf, nv in zip(specs, leaves, nleaves):
         trail = leaf.shape[1:]
         if s == "first":
-            w = winners()
-            col = jnp.take(leaf, jnp.clip(w, 0, cap - 1), axis=0)
-            present = w < cap
+            if plan is not None:
+                col, present = segmented.sorted_fold_first(leaf, plan)
+            else:
+                w = winners()
+                col = jnp.take(leaf, jnp.clip(w, 0, cap - 1), axis=0)
+                present = w < cap
         elif s == "sum":
             from ...core import pallas_kernels as _pk
-            if (leaf.dtype == jnp.float32 and not trail
+            if leaf.dtype.itemsize == 8:
+                col = segmented.sorted_fold_sum(leaf, plan)
+            elif (leaf.dtype == jnp.float32 and not trail
                     and _pk.pallas_enabled()
                     and _pk.segment_sum_ok(out_cap, cap)):
                 # additive f32 fold through the Pallas segment-sum
-                # kernel (the PageRank/k-means hot shape). Sum order
-                # differs from the scatter (per-block partials), which
-                # the unordered-reduce contract permits; the scatter
-                # below stays THE path whenever the knob is off, so
-                # THRILL_TPU_PALLAS=0 is bit-identical by construction.
+                # kernel. Sum order differs from the scatter (per-block
+                # partials), which the unordered-reduce contract
+                # permits; the scatter below stays THE path whenever the
+                # knob is off, so THRILL_TPU_PALLAS=0 is bit-identical
+                # by construction.
                 col = _pk.segment_sum_pallas(pos, leaf, out_cap)
             else:
                 col = jnp.zeros((out_cap + 1,) + trail,
                                 leaf.dtype).at[pos].add(leaf)[:out_cap]
             if nv is None or not np.any(np.asarray(nv)):
-                # zero neutral == the scatter base: skip the presence
-                # arbitration entirely (the PageRank/k-means hot shape)
+                # zero neutral == what an untouched row sums to: skip
+                # the presence mask (the PageRank/k-means hot shape)
                 outs.append(col)
                 continue
-            present = winners() < cap
+            present = present_rows()
         else:
             big = jnp.asarray(_type_max(np.dtype(leaf.dtype))
                               if s == "min"
@@ -939,7 +989,7 @@ def _scatter_reduce_apply(tree, valid, local_idx, range_size, out_cap,
             base = jnp.full((out_cap + 1,) + trail, big, leaf.dtype)
             col = (base.at[pos].min(leaf) if s == "min"
                    else base.at[pos].max(leaf))[:out_cap]
-            present = winners() < cap
+            present = present_rows()
         fill = (jnp.zeros((), leaf.dtype) if nv is None
                 else jnp.asarray(nv, leaf.dtype))
         pb = present.reshape(present.shape + (1,) * len(trail))
@@ -996,20 +1046,37 @@ class ReduceToIndexNode(DIABase):
         bound = (bounds[:W].astype(np.int64),
                  local_sizes.astype(np.int64))
 
-        def trace(fctx, tree, mask, bound_t):
+        def local_index(tree, bound_t):
             starts, sizes = bound_t            # replicated [W] plans
             widx = lax.axis_index(AXIS)
-            range_start = starts[widx]
-            range_size = sizes[widx]
-            leaves, td = jax.tree.flatten(tree)
             idx = jnp.asarray(index_fn(tree)).astype(jnp.int64)
+            return idx, starts[widx], sizes[widx]
+
+        def index_plan(fctx, tree, mask, bound_t):
+            """The index plan where a leaf folds over sorted runs: it
+            reads the index and the mask, no value, so a whole-loop
+            program whose index is invariant runs it once, before the
+            loop (api/fusion.py Segment.index_plan, api/loop.py)."""
+            leaves, td = jax.tree.flatten(tree)
+            sc = _scatter_fold_specs(reduce_fn, td, leaves)
+            if sc is None or not _wants_sorted_fold(sc, leaves):
+                return None
+            idx, range_start, range_size = local_index(tree, bound_t)
+            with jax.named_scope(SCATTER_SCOPE):
+                return segmented.sorted_fold_plan(
+                    _dense_pos(mask, idx - range_start, range_size,
+                               out_cap), out_cap)
+
+        def trace(fctx, tree, mask, bound_t):
+            idx, range_start, range_size = local_index(tree, bound_t)
+            leaves, td = jax.tree.flatten(tree)
             sc = _scatter_fold_specs(reduce_fn, td, leaves)
             if sc is not None:
-                # declarative specs: sort-free scatter engine (the
-                # iterative hot path — no XLA argsort per iteration)
+                # declarative specs: no sort of the items
                 out_tree = _scatter_reduce_apply(
-                    tree, mask, idx - range_start, range_size, out_cap,
-                    sc, neutral)
+                    tree, _dense_pos(mask, idx - range_start, range_size,
+                                     out_cap),
+                    out_cap, sc, neutral, plan=fctx.index_plan)
                 return out_tree, jnp.arange(out_cap) < range_size
             specs = _device_fold_specs(reduce_fn, td, leaves)
             words = [idx.astype(jnp.uint64)]
@@ -1041,7 +1108,8 @@ class ReduceToIndexNode(DIABase):
             token=("r2i_post_fused", (index_fn, reduce_fn, self.size),
                    out_cap, ntok),
             trace=trace, bound=bound, already_compact=True,
-            sets_counts=local_sizes, dia_id=self.id)
+            sets_counts=local_sizes, dia_id=self.id,
+            index_plan=index_plan)
 
     def compute_plan(self):
         from .. import fusion
@@ -1049,17 +1117,18 @@ class ReduceToIndexNode(DIABase):
         from ...core import host_radix
         plan = fusion.pull_plan(self.parents[0])
         bounds = self._bounds()
-        # declarative FieldReduce specs unlock the sort-free scatter
-        # engine, which beats the native host engine EVEN on the CPU
-        # backend (no device->host demotion, no blocking column fetch,
-        # stays in jax's async dispatch stream — load-bearing for
-        # iterative loop replay, api/loop.py); everything else keeps
-        # the host-radix preference on CPU (XLA's single-core sort is
-        # the wrong engine there). Leaf dtypes are unknown until the
-        # plan materializes, so this gate trusts the FieldReduce shape
-        # alone: a spec the scatter engine later rejects (bool or
-        # non-numeric sum/min/max leaf) still runs correctly through the
-        # fused segment's sorted fallback, just on the slower engine
+        # declarative FieldReduce specs take the fused segment on every
+        # backend: its engines (the scatter, and the fold over sorted
+        # runs for 8-byte sums) sort no items, need no device->host
+        # demotion and no blocking column fetch, and stay in jax's async
+        # dispatch stream, which loop replay (api/loop.py) rests on.
+        # Everything else keeps the host-radix preference on the CPU
+        # backend (XLA's single-core sort of the items is the wrong
+        # engine there). Leaf dtypes are unknown until the plan
+        # materializes, so this gate trusts the FieldReduce shape alone:
+        # a spec the scatter engine later rejects (bool or non-numeric
+        # sum/min/max leaf) still runs correctly through the fused
+        # segment's sorted fallback, just on the slower engine
         if not plan.stitchable or (
                 host_radix.eligible(self.context.mesh_exec)
                 and not isinstance(self.reduce_fn, FieldReduce)):
@@ -1125,11 +1194,12 @@ class ReduceToIndexNode(DIABase):
                 tree = jax.tree.unflatten(treedef, [l[0] for l in ls])
                 idx = jnp.asarray(index_fn(tree)).astype(jnp.int64)
                 if sc is not None:
-                    # sort-free scatter engine (same math as the fused
-                    # segment — FUSE=0 runs produce identical results)
+                    # the fused segment's engines, the same math:
+                    # FUSE=0 runs produce identical results
                     out_tree = _scatter_reduce_apply(
-                        tree, valid, idx - range_start[0, 0],
-                        range_size[0, 0], out_cap, sc, neutral)
+                        tree, _dense_pos(valid, idx - range_start[0, 0],
+                                         range_size[0, 0], out_cap),
+                        out_cap, sc, neutral)
                     out_leaves = jax.tree.leaves(out_tree)
                     return (range_size[0].astype(jnp.int32)[None],
                             *[l[None] for l in out_leaves])
@@ -1162,6 +1232,11 @@ class ReduceToIndexNode(DIABase):
             return mex.smap(f, 3 + len(leaves))
 
         fn = mex.cached(key, build)
+        if sc is not None and _wants_sorted_fold(sc, leaves):
+            # the plan in place; a tape that replays this call counts it
+            from .. import fusion
+            fusion.note_index_plans(fn, fusion.IndexPlans(count=1))
+            mex.stats_r2i_index_plans += 1
         rs = mex.put_small(bounds[:W].astype(np.int64)[:, None])
         rsz = mex.put_small(local_sizes[:, None])
         out = fn(shards.counts_device(), rs, rsz, *leaves)

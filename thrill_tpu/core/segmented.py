@@ -214,3 +214,97 @@ def segmented_reduce_fields(words: List[jnp.ndarray], tree: Any,
         out_leaves.append(jnp.take(res, seg, axis=0))
     return (words, jax.tree.unflatten(td, out_leaves),
             _rep_mask(starts, valid))
+
+
+# ----------------------------------------------------------------------
+# fold over runs sorted by a dense index (ReduceToIndex's 8-byte sums)
+# ----------------------------------------------------------------------
+# XLA:TPU carries a 64-bit value as a pair of 32-bit ones, so a scatter
+# of 8-byte values is a two-operand scatter: 122-126 ns per update on a
+# v5e against 6.8 ns for a 32-bit one on the same indices, sorted or not
+# (PERF.md section 5). The fold below has no scatter of a value in it:
+# an index plan made of 32-bit operations alone (a stable argsort of the
+# target rows and the run boundaries), then per 8-byte column a gather
+# by the permutation, a segmented inclusive scan and one gather at the
+# run ends. The plan reads no value, so a loop whose index does not
+# change computes it once (api/fusion.py Segment.index_plan, api/loop.py).
+
+def sorted_fold_plan(pos: jnp.ndarray, num_rows: int):
+    """Index plan of a fold over runs sorted by target row.
+
+    ``pos``: [n] int32 target rows in [0, num_rows]; ``num_rows`` itself
+    is the dump row of dropped items, which sort last and are never
+    read. Returns ``(perm, offsets, starts)``: ``perm`` [n + 1] int32,
+    the stable argsort of ``pos`` (the first arrival of a row is the
+    first of its run) and behind it ``n``, the place of no item, which
+    gathers as a zero; ``offsets`` [num_rows + 1] int32, run ``i`` being
+    the sorted positions ``offsets[i] .. offsets[i + 1] - 1``;
+    ``starts`` [n + 1] bool, set at the first position of every run,
+    the zero behind the items being a run of its own."""
+    from .device_sort import argsort_words
+    n = pos.shape[0]
+    with jax.named_scope("index_plan"):
+        perm = jnp.concatenate([argsort_words([pos.astype(jnp.uint32)]),
+                                jnp.full(1, n, jnp.int32)])
+        counts = jnp.zeros(num_rows + 1, jnp.int32).at[pos].add(1)
+        offsets = jnp.concatenate([
+            jnp.zeros(1, jnp.int32),
+            jnp.cumsum(counts[:num_rows], dtype=jnp.int32)])
+        starts = jnp.zeros(n + 1, jnp.bool_).at[jnp.concatenate(
+            [offsets, jnp.full(1, n, jnp.int32)])].set(True)
+    return perm, offsets, starts
+
+
+def _segmented_cumsum(x: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive sums that begin anew at every set flag, in log2(n)
+    shifted passes (Hillis-Steele: 1.5 ms for 2^21 binary64 values on a
+    v5e, where ``lax.associative_scan`` takes 7.7 ms and four minutes to
+    compile). Every partial sum adds terms of one run only: no prefix
+    is subtracted anywhere, so a small run beside a large one keeps its
+    precision."""
+    n = x.shape[0]
+    flags = starts
+    d = 1
+    while d < n:
+        shifted = jnp.concatenate(
+            [jnp.zeros((d,) + x.shape[1:], x.dtype), x[:-d]])
+        x = jnp.where(_bshape(flags, x), x, shifted + x)
+        flags = flags | jnp.concatenate(
+            [jnp.ones(d, jnp.bool_), flags[:-d]])
+        d *= 2
+    return x
+
+
+def sorted_fold_sum(leaf: jnp.ndarray, plan) -> jnp.ndarray:
+    """Per-row sums of ``leaf`` [n, ...] over the runs of ``plan``
+    (:func:`sorted_fold_plan`): [num_rows, ...], zero where a row has
+    no item. Integer sums are exact; float sums add each run's terms in
+    arrival order, pairwise.
+
+    A row with no item reads the zero that the plan keeps behind the
+    items as a run of its own, so the column is handed on as a plain
+    gather of sums, as the scatter handed on a column in memory. With a
+    ``where(..., 0)`` as the last operation the consumer's arithmetic
+    compiled otherwise than behind a column: XLA:CPU moves a multiply
+    that follows into the select, can then no longer contract it with
+    the add behind it, and a stitched chain rounded otherwise than the
+    same operations dispatched one by one."""
+    perm, offsets, starts = plan
+    n = perm.shape[0] - 1
+    with jax.named_scope("sorted_fold"):
+        sums = _segmented_cumsum(
+            jnp.take(leaf, perm, axis=0, mode="fill", fill_value=0),
+            starts)
+        last = jnp.where(offsets[1:] > offsets[:-1], offsets[1:] - 1, n)
+        return jnp.take(sums, last, axis=0, mode="clip")
+
+
+def sorted_fold_first(leaf: jnp.ndarray, plan):
+    """The first arrival of every row off the same plan: ``(values
+    [num_rows, ...], present [num_rows])``. The sort is stable, so the
+    first of a run is the item that arrived first."""
+    perm, offsets, _ = plan
+    with jax.named_scope("sorted_fold"):
+        first = jnp.take(perm, offsets[:-1], mode="clip")
+        return (jnp.take(leaf, first, axis=0),
+                offsets[1:] > offsets[:-1])

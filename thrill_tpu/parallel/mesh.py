@@ -379,6 +379,12 @@ class MeshExec:
         # XLA on replayed dispatches
         self.stats_loop_plan_builds = 0
         self.stats_loop_plan_rebinds = 0
+        # index plans of ReduceToIndex (api/ops/reduce.py) computed by
+        # the programs dispatched, counted where those are dispatched
+        # (api/fusion.py, api/loop.py): one per fold of 8-byte sums; in
+        # a whole-loop program one per iteration where the index
+        # changes with the carry, and one per dispatch where it does not
+        self.stats_r2i_index_plans = 0
         self.stats_loop_replays = 0
         self.stats_loop_fori_iters = 0
         self.stats_loop_fallbacks = 0
