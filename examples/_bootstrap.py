@@ -1,5 +1,5 @@
 """Repo-root on sys.path for direct CLI runs
-(`python examples/x.py`, `python benchmarks/x.py`)."""
+(`python examples/x.py`)."""
 
 import os
 import sys

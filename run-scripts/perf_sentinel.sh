@@ -27,7 +27,7 @@ for v in THRILL_TPU_FUSE THRILL_TPU_OVERLAP THRILL_TPU_XCHG_CHUNKS \
          THRILL_TPU_WIRE_COMPRESS THRILL_TPU_PLANNER \
          THRILL_TPU_PLAN_STORE THRILL_TPU_EXCHANGE \
          THRILL_TPU_LOCATION_DETECT THRILL_TPU_DUP_DETECT \
-         THRILL_TPU_LOOP_REPLAY THRILL_TPU_FORI THRILL_TPU_FAULTS; do
+         THRILL_TPU_LOOP_REPLAY THRILL_TPU_FAULTS; do
     unset "$v" || true
 done
 

@@ -258,8 +258,8 @@ def test_reduce_to_index_min_sentinels_never_leak(monkeypatch):
 
 
 def test_field_reduce_wordcount_matches_counter():
-    """End-to-end WordCount (the bench.py configuration, small n) is
-    EXACTLY collections.Counter."""
+    """End-to-end WordCount (Zipf ids, small n) is EXACTLY
+    collections.Counter."""
     import collections
     n = 20000
     rng = np.random.default_rng(1)

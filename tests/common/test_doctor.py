@@ -321,6 +321,16 @@ def test_sentinel_serve_row_pins_elastic_machinery_idle():
                    fresh) == []
 
 
+def test_sentinel_default_path_needs_no_bench_py(tmp_path, monkeypatch):
+    """From anywhere, the sentinel finds the checkout's contract by the
+    contract file itself, not by a benchmark script beside it."""
+    from thrill_tpu.tools import perf_sentinel as ps
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                        "..", ".."))
+    monkeypatch.chdir(tmp_path)
+    assert ps.default_path() == os.path.join(root, "PERF_CONTRACT.json")
+
+
 @pytest.mark.slow
 def test_repo_perf_contract_matches_fresh_run():
     """The checked-in PERF_CONTRACT.json must describe THIS tree: a

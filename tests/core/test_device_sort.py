@@ -147,32 +147,91 @@ def test_radix_matches_xla(monkeypatch, n, nwords):
     assert np.array_equal(perm_xla, perm_rad)
 
 
-def test_sort_engine_policy_pins(monkeypatch):
-    """The cost model's load-bearing regions (edge (e)): xla below the
-    compile cliff / on CPU, radix past the cliff when eligible, chunked
-    when the Pallas kernel cannot engage."""
-    monkeypatch.setattr(device_sort.jax, "default_backend",
-                        lambda: "cpu")
-    eng, costs, _ = device_sort.sort_engine_policy(1 << 20, 64, True)
-    assert eng == "xla"                      # CPU: lowering healthy
+_CLIFF = device_sort.XLA_SORT_MAX_N
 
-    monkeypatch.setattr(device_sort.jax, "default_backend",
-                        lambda: "tpu")
-    small = device_sort.XLA_SORT_MAX_N
-    eng, _, _ = device_sort.sort_engine_policy(small, 64, True)
-    assert eng == "xla"                      # below the compile cliff
-    eng, costs, reason = device_sort.sort_engine_policy(
-        1 << 22, 64, True)
-    assert eng == "radix" and "radix" in costs and "chunked" in costs
-    assert costs["radix"] < costs["chunked"]
-    eng, costs, reason = device_sort.sort_engine_policy(
-        1 << 22, 64, False)
-    assert eng == "chunked" and "radix" not in costs
-    assert "ineligible" in reason
+# (backend, n, uint64 key words, radix_ok, THRILL_TPU_SORT_IMPL) ->
+# (engine, a fragment of the reason; None where the engine is pinned),
+# written from the parent of the PR that made the choice one function
+# (sort_engine_policy + _impl + argsort_words' own read of the pin)
+_PARENT_TABLE = [
+    ("cpu", 1 << 20, 1, True, None, "xla", "healthy"),
+    ("cpu", 1 << 24, 40, True, None, "xla", "healthy"),
+    ("tpu", _CLIFF, 1, True, None, "xla", "healthy"),
+    ("tpu", _CLIFF + 1, 1, False, None, "chunked", "radix ineligible"),
+    ("tpu", 1 << 22, 1, False, None, "chunked", "radix ineligible"),
+    ("tpu", 1 << 22, 1, True, None, "radix", "radix eligible"),
     # many wide words: enough passes to price radix past chunked
-    eng, costs, _ = device_sort.sort_engine_policy(1 << 22, 64 * 40,
-                                                  True)
-    assert eng == "chunked" and costs["chunked"] < costs["radix"]
+    ("tpu", 1 << 22, 40, True, None, "chunked", "radix eligible"),
+    ("cpu", 1 << 22, 1, True, "xla", "xla", None),
+    ("cpu", 1 << 22, 1, True, "bitonic", "bitonic", None),
+    ("cpu", 1 << 22, 1, False, "chunked", "chunked", None),
+    ("tpu", 1 << 10, 1, False, "radix", "radix", None),
+]
+
+
+@pytest.mark.parametrize(
+    "backend,n,nwords,radix_ok,pin,engine,why", _PARENT_TABLE)
+def test_choose_engine_matches_parent_table(
+        monkeypatch, backend, n, nwords, radix_ok, pin, engine, why):
+    """The one engine choice is the parent's for every input it looks
+    at, and writes its ``sort_engine`` record only where it chose."""
+    from types import SimpleNamespace
+    from thrill_tpu.common.decisions import DecisionLedger
+    from thrill_tpu.parallel import mesh
+    monkeypatch.setattr(device_sort.jax, "default_backend",
+                        lambda: backend)
+    if pin is None:
+        monkeypatch.delenv("THRILL_TPU_SORT_IMPL", raising=False)
+    else:
+        monkeypatch.setenv("THRILL_TPU_SORT_IMPL", pin)
+    led = DecisionLedger(enabled=True)
+    monkeypatch.setattr(
+        mesh, "current_mex", lambda: SimpleNamespace(decisions=led))
+    words = [jax.ShapeDtypeStruct((n,), jnp.uint64)] * nwords
+    assert device_sort.choose_engine(n, words, radix_ok=radix_ok) \
+        == engine
+    if pin is not None:
+        assert not led.snapshot()  # a pinned engine is no choice
+        return
+    (rec,) = led.snapshot()
+    assert rec["kind"] == "sort_engine" and rec["chosen"] == engine
+    assert rec["site"] == f"sort:n{n}:w{nwords}" and why in rec["reason"]
+    assert rec["inputs"]["n"] == n
+    assert rec["inputs"]["total_bits"] == 64 * nwords
+    costs = dict(rec.get("rejected", ()), **{engine: rec["predicted"]})
+    assert set(costs) == ({"xla"} if engine == "xla" else
+                          {"chunked", "radix"} if radix_ok
+                          else {"chunked"})
+    assert min(costs, key=costs.get) == engine
+    # the merge of presorted runs asks without a record of its own
+    assert device_sort.choose_engine(n, words, radix_ok=radix_ok,
+                                     record=False) == engine
+    assert len(led.snapshot()) == 1
+
+
+def test_sort_and_run_merge_ask_the_one_choice(monkeypatch):
+    """``argsort_words`` and Sort's fused exchange-merge both take
+    their engine from ``choose_engine``."""
+    from thrill_tpu.api import Context
+    from thrill_tpu.parallel.mesh import MeshExec
+    monkeypatch.setenv("THRILL_TPU_HOST_RADIX", "0")
+    asked = []
+    real = device_sort.choose_engine
+
+    def spy(n, words, **kw):
+        asked.append(kw.get("record", True))
+        return real(n, words, **kw)
+
+    monkeypatch.setattr(device_sort, "choose_engine", spy)
+    ctx = Context(MeshExec(devices=jax.devices("cpu")[:2]))
+    vals = np.random.default_rng(3).permutation(5000).astype(np.int64)
+    assert [int(x) for x in ctx.Distribute(vals).Sort().AllGather()] \
+        == list(range(5000))
+    recorded = [d for d in ctx.decisions.snapshot()
+                if d["kind"] == "sort_engine"]
+    ctx.close()
+    assert True in asked and False in asked, asked
+    assert len(recorded) == asked.count(True)
 
 
 @pytest.mark.parametrize("w", [

@@ -198,3 +198,50 @@ def test_sticky_capacities_stop_recompile_churn(monkeypatch):
     # sticky, so count wiggles reuse the same compiled programs
     assert sizes[-1] == sizes[2], sizes
     ctx.close()
+
+
+@pytest.mark.parametrize("W", [
+    2,
+    # W sweep tails ride the unfiltered sweep only (tier-1 wall-clock
+    # budget; W=2 is the in-tier representative — PR-9 precedent)
+    pytest.param(5, marks=pytest.mark.slow),
+    pytest.param(8, marks=pytest.mark.slow)])
+def test_reduce_shuffle_matches_dictionary(W):
+    rng = np.random.default_rng(W)
+    vals = rng.integers(0, 40, 6000).astype(np.int64)
+    want = {}
+    for v in vals.tolist():
+        want[v % 17] = want.get(v % 17, 0) + v
+    ctx = _ctx(W)
+    out = ctx.Distribute(vals).Map(lambda x: (x % 17, x)).ReducePair(
+        lambda a, b: a + b)
+    assert dict((int(k), int(v)) for k, v in out.AllGather()) == want
+    ctx.close()
+
+
+def test_reduce_shuffle_cap_stays_linear():
+    """The post phase's capacity stays linear in the rows actually
+    received: ~1000 distinct keys over W=8 are ~125 a worker, and a
+    capacity fed back through round_up_pow2 once per source would
+    exceed 2^15."""
+    ctx = _ctx(8)
+    vals = np.arange(20000, dtype=np.int64)
+    out = ctx.Distribute(vals).Map(lambda x: (x % 1000, 1)).ReducePair(
+        lambda a, b: a + b)
+    sh = out.node.materialize(consume=False)
+    assert sh.cap <= 8192, f"post-phase cap blew up: {sh.cap}"
+    got = dict((int(k), int(v)) for k, v in out.AllGather())
+    assert len(got) == 1000 and all(v == 20 for v in got.values())
+    ctx.close()
+
+
+def test_reduce_shuffle_on_sliced_mesh(monkeypatch):
+    monkeypatch.setenv("THRILL_TPU_SLICES", "2")
+    ctx = _ctx(8)
+    assert ctx.mesh_exec.num_slices == 2
+    vals = np.arange(5000, dtype=np.int64)
+    out = ctx.Distribute(vals).Map(lambda x: (x % 9, 1)).ReducePair(
+        lambda a, b: a + b)
+    got = dict((int(k), int(v)) for k, v in out.AllGather())
+    assert sum(got.values()) == 5000 and len(got) == 9
+    ctx.close()

@@ -292,66 +292,6 @@ def _strided_run_fold(tree, lens: np.ndarray, reduce_fn: Callable,
         td, [host_radix.gather_rows(a, starts) for a in leaves])
 
 
-def _fold_reduce_device(acc: DeviceShards, block: DeviceShards,
-                        key_fn: Callable, reduce_fn: Callable,
-                        token) -> DeviceShards:
-    """One jitted program folding two reduced shards into one: concat
-    both valid prefixes, sort by key words, segmented-reduce, compact.
-    Counts stay device-resident end to end — the whole streamed post
-    phase runs with zero host syncs.
-
-    The output capacity is round_up_pow2(capA + capB). Callers must NOT
-    fold a long stream linearly through one accumulator — feeding the
-    rounded cap back makes the accumulator double every fold
-    (exponential padding). The streamed post phase folds blocks as a
-    binary counter instead (see ``_compute_device_stream``): caps stay
-    on a power-of-two ladder, only O(log W) distinct shapes compile,
-    and worst-case padded rows stay within ~2x the bulk path."""
-    from ...common.config import round_up_pow2
-    mex = acc.mesh_exec
-    leaves_a, td = jax.tree.flatten(acc.tree)
-    leaves_b, td_b = jax.tree.flatten(block.tree)
-    assert td == td_b, "fold requires matching schemas"
-    capA, capB = acc.cap, block.cap
-    out_cap = round_up_pow2(capA + capB)
-    nA = len(leaves_a)
-    specs = _device_fold_specs(reduce_fn, td, leaves_a)
-    key = ("reduce_fold", token, capA, capB, out_cap, td,
-           tuple((l.dtype, l.shape[2:]) for l in leaves_a))
-
-    def build():
-        def f(ca, cb, *ls):
-            validA = jnp.arange(capA) < ca[0, 0]
-            validB = jnp.arange(capB) < cb[0, 0]
-            treeA = jax.tree.unflatten(td, [l[0] for l in ls[:nA]])
-            treeB = jax.tree.unflatten(td, [l[0] for l in ls[nA:]])
-            tree = jax.tree.map(
-                lambda a, b: jnp.concatenate([a, b], axis=0),
-                treeA, treeB)
-            valid = jnp.concatenate([validA, validB])
-            words = keymod.encode_key_words(key_fn(tree))
-            words, tree, valid, _ = segmented.sort_by_key_words(
-                words, tree, valid)
-            words, tree, rep = segmented.reduce_runs(
-                words, tree, valid, reduce_fn, specs)
-            tree, new_count = compact_valid(tree, rep)
-            pad = out_cap - (capA + capB)
-            tree = jax.tree.map(
-                lambda l: jnp.pad(l, [(0, pad)] + [(0, 0)] * (l.ndim - 1))
-                if pad else l, tree)
-            out_leaves = jax.tree.leaves(tree)
-            return (new_count[None, None].astype(jnp.int32),
-                    *[l[None] for l in out_leaves])
-
-        return mex.smap(f, 2 + 2 * nA)
-
-    fn = mex.cached(key, build)
-    out = fn(acc.counts_device(), block.counts_device(),
-             *leaves_a, *leaves_b)
-    tree = jax.tree.unflatten(td, list(out[1:]))
-    return DeviceShards(mex, tree, out[0])
-
-
 class ReduceNode(DIABase):
     # both phase tables want workspace (reference: ReduceByKey registers
     # DIAMemUse::Max for its pre/post tables, api/reduce_by_key.hpp);
@@ -504,15 +444,6 @@ class ReduceNode(DIABase):
                 return jnp.where(mine_only, widx.astype(jnp.int32),
                                  hash_dest)
 
-            import os
-            if os.environ.get("THRILL_TPU_REDUCE_STREAM") == "1":
-                # MixStream-analog post phase: fold each received round
-                # into the accumulator while later rounds' collectives
-                # are still in flight (reference: use_post_thread_
-                # overlap, api/reduce_by_key.hpp:142-168, over
-                # MixStream's arbitrary-order delivery)
-                return fusion.wrap(
-                    self._compute_device_stream(pre, dest, token, dup))
             pre = exchange.exchange(pre, dest,
                                     ("reduce_dest", token, W, dup))
         # post-phase: final combine (reference: ReduceByHashPostPhase);
@@ -525,51 +456,6 @@ class ReduceNode(DIABase):
                 return plan
         return fusion.wrap(
             _local_reduce_device(pre, key_fn, reduce_fn, "post", token))
-
-    def _compute_device_stream(self, pre: DeviceShards, dest, token,
-                               dup: bool = False):
-        """Streamed post-phase: per-round receive + incremental fold.
-
-        Every yielded round block is folded by ONE jitted program
-        (concat + sort + segmented reduce, counts staying
-        device-resident throughout — a host counts sync per round would
-        serialize the rounds); jax async dispatch overlaps round r's
-        fold with round r+1's ppermute.
-
-        Blocks combine as a BINARY COUNTER (bottom-up merge-sort
-        shape): ``levels[i]`` holds the reduction of 2^i round blocks;
-        a new block folds up through full levels. A single linear
-        accumulator would double its padded cap on every fold (the fold
-        rounds capA+capB up to a power of two and feeds it back —
-        exponential growth); the counter keeps every fold between
-        same-magnitude shards, so caps walk a pow2 ladder with O(log W)
-        distinct compiled shapes and ~2x the bulk path's padded rows.
-        """
-        key_fn, reduce_fn = self.key_fn, self.reduce_fn
-        W = self.context.num_workers
-        levels: List[Optional[DeviceShards]] = []
-        for block in exchange.exchange_stream(
-                pre, dest, ("reduce_dest", token, W, dup)):
-            # round blocks carry pre-reduced (unique-key) rows, so any
-            # block IS a valid partial accumulator
-            cur = block
-            i = 0
-            while i < len(levels) and levels[i] is not None:
-                cur = _fold_reduce_device(levels[i], cur, key_fn,
-                                          reduce_fn, token)
-                levels[i] = None
-                i += 1
-            if i == len(levels):
-                levels.append(cur)
-            else:
-                levels[i] = cur
-        acc: Optional[DeviceShards] = None
-        for lv in levels:                  # fold up the leftovers
-            if lv is None:
-                continue
-            acc = lv if acc is None else _fold_reduce_device(
-                lv, acc, key_fn, reduce_fn, token)
-        return acc
 
     def _compute_host(self, shards: HostShards):
         W = shards.num_workers

@@ -574,8 +574,8 @@ class SortNode(DIABase):
         # (encode+sort+spill) phase is engine-independent machinery;
         # the merge phase is where the native k-way engine replaces
         # heapq + per-item Python key calls (ref hot loop:
-        # api/sort.hpp:216-271) — bench.py reports the phase times so
-        # the engine win is pinned, not inferred from noisy totals
+        # api/sort.hpp:216-271); ``_em_stats`` keeps the two phase
+        # times apart
         import time as _time
         t_phase0 = _time.perf_counter()
         ra = None
@@ -1002,7 +1002,7 @@ def _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
     generic exchange + full sort for ragged/one-factor modes (those
     compact receives at dynamic boundaries).
     """
-    from ...core.device_sort import (_impl, merge_sorted_runs,
+    from ...core.device_sort import (choose_engine, merge_sorted_runs,
                                      prepare_sort_words)
     W = mex.num_workers
     cap = sorted_dest.shape[1]
@@ -1076,7 +1076,8 @@ def _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
                                  a.dtype)])
 
             arrs = [pad_rows(w) for w in sort_words] + [iota]
-            if _impl(Np) == "xla":
+            # the merge sorts nothing itself: no record of its own
+            if choose_engine(Np, sort_words, record=False) == "xla":
                 res = lax.sort(tuple(arrs), dimension=0,
                                num_keys=len(arrs), is_stable=False)
                 perm = res[-1][:out_cap].astype(jnp.int32)

@@ -18,8 +18,8 @@ pattern: one attribute read plus one predicate on the off path) owns:
 
 * **The cost model.** Three terms, shared by every choice:
   ``fabric_bytes`` (padded rows / serialized frames a candidate plan
-  ships), ``dispatches * bytes_eq`` (the measured per-launch overhead
-  expressed in equivalent bytes — benchmarks/exchange_crossover.py,
+  ships), ``dispatches * bytes_eq`` (the per-launch overhead
+  expressed in equivalent bytes — data/exchange.py ``_bytes_eq``,
   the same calibration ``_skewed`` always used) and an HBM-admission
   term (a candidate whose estimate cannot fit under the watermark even
   with every cold shard spilled is inadmissible). Inputs come from the
@@ -49,8 +49,8 @@ pattern: one attribute read plus one predicate on the off path) owns:
   site on the next exchange instead of waiting out the periodic
   resync window. Every re-choice lands in the ledger as a ``replan``
   record carrying both plans' costs, so ``ctx.explain()`` names what
-  switched and why, and the ``cost_model_mae`` bench lane doubles as
-  the planner's own accuracy gauge.
+  switched and why; ``decision_accuracy`` in ``ctx.overall_stats()``
+  doubles as the planner's own accuracy gauge.
 
 ``THRILL_TPU_PLANNER=0`` restores today's per-site heuristics exactly:
 no Planner is constructed, every guarded call site takes its legacy
@@ -154,18 +154,6 @@ class Planner:
         if hbm_bytes is not None and self.hbm_inadmissible(hbm_bytes):
             return math.inf
         return c
-
-    def sort_engine(self, n: int, total_bits: int, radix_ok: bool,
-                    site: Optional[str] = None):
-        """Device sort engine choice (edge (e)): delegates to the one
-        shared cost model in core/device_sort.py so the planner and the
-        legacy auto path can never disagree; a pending replan mark on
-        the sort site is consumed here (the decision is re-recorded by
-        the caller either way)."""
-        from ..core.device_sort import sort_engine_policy
-        if site is not None:
-            self.take_replan(site)
-        return sort_engine_policy(n, total_bits, radix_ok)
 
     def hbm_inadmissible(self, est_bytes: int) -> bool:
         """True when ``est_bytes`` cannot be admitted at any spill

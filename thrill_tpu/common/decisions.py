@@ -25,9 +25,9 @@ record:
   exchange's deferred capacity check, the dispatch choke point's
   measured output bytes, observed prune fractions (record_prune).
   Per-kind ``|log2(pred/actual)|`` aggregates feed the accuracy
-  ledger in ``ctx.overall_stats()`` (``decision_accuracy``), the
-  ``cost_model_mae`` bench lane, and ``PlanStore.save_ledger`` — the
-  on-disk audit trail next to plans.json.
+  ledger in ``ctx.overall_stats()`` (``decision_accuracy``) and
+  ``PlanStore.save_ledger`` — the on-disk audit trail next to
+  plans.json.
 * :func:`render_plan` — the shared explain() renderer: an annotated
   physical-plan tree (ops, fused segments, exchange strategy per
   edge, every decision with its reason and audit verdict). Consumed

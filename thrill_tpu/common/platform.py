@@ -3,8 +3,7 @@
 JAX takes the accelerator it finds unless told otherwise; the program
 never falls back to the CPU by itself. ``force_cpu_platform()`` is "use
 the CPU because I was asked to" (tests, the multi-process CPU children
-and the CPU-only tools call it before their first jax use);
-``require_accelerator()`` is its opposite for the measurement scripts.
+and the CPU-only tools call it before their first jax use).
 """
 
 from __future__ import annotations
@@ -14,23 +13,6 @@ def force_cpu_platform() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-
-
-def require_accelerator() -> None:
-    """Measurement entry points (bench.py, benchmarks/) call this before
-    timing anything: a run that finds no accelerator exits non-zero
-    instead of reporting CPU times as if they were the device's —
-    unless ``JAX_PLATFORMS=cpu`` asked for the CPU by name."""
-    import os
-
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        return
-    if jax.default_backend() == "cpu":
-        raise SystemExit(
-            "thrill_tpu: JAX found no accelerator; set JAX_PLATFORMS=cpu "
-            "to run on the CPU on purpose")
 
 
 def enable_cpu_multiprocess_collectives() -> bool:

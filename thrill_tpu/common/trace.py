@@ -12,7 +12,7 @@ the critical path.
 it. Every record also reaches three readers that take all of them: the
 flight recorder's dump (below), ``tools/trace2perfetto.py`` (one lane per
 category) and ``Tracer.lane_counts`` (``trace_spans{lane=...}`` on the
-metrics endpoint, ``bench.py``); every span (not instant) reaches
+metrics endpoint); every span (not instant) reaches
 ``common/doctor.py critical_path`` (``ctx.doctor_report()``,
 ``tools/doctor_report.py``). The last column names who reads THAT site
 besides: a chip-benchmark metric (``chipbench/layer_metrics/``, through
@@ -306,7 +306,8 @@ class Tracer:
         self.gen_fn = None
         self.tenant_fn = None
         self.current_job: Optional[str] = None
-        # finished spans per category lane (bench.py trace lane counts)
+        # finished spans per category lane (the metrics endpoint's
+        # trace_spans{lane=...})
         self.lane_counts: Dict[str, int] = {}
         # records ever written to the ring, against its capacity
         self.records_written = 0

@@ -16,12 +16,14 @@ implementations:
 * ``bitonic`` — the full bitonic network through the same loop
                 (O(log² n) stages; no base-case sort to compile).
 
-Selection: THRILL_TPU_SORT_IMPL = auto (default) | xla | chunked |
-bitonic. ``auto`` uses xla on CPU backends and for small n, chunked on
-accelerators above the threshold: the TPU compiler's time for a
-multi-operand ``lax.sort`` grows steeply with rows (round 1: stalls
-beyond ~64K rows; JAX 0.9.0 for v5e: minutes from 2^16 rows up, see
-``CHUNK_ROWS`` and CHANGES.md PR 22).
+Selection, in :func:`choose_engine` and nowhere else:
+THRILL_TPU_SORT_IMPL = auto (default) | xla | chunked | bitonic |
+radix (core/pallas_sort.py). ``auto`` uses xla on CPU backends and for
+small n, chunked (or, with the Pallas tier on, the cheaper of chunked
+and radix) on accelerators above the threshold: the TPU compiler's time
+for a multi-operand ``lax.sort`` grows steeply with rows (round 1:
+stalls beyond ~64K rows; JAX 0.9.0 for v5e: minutes from 2^16 rows up,
+see ``CHUNK_ROWS`` and CHANGES.md PR 22).
 """
 
 from __future__ import annotations
@@ -35,12 +37,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-# above this row count, accelerator backends switch engines in auto
 # HLO metadata only (jax.named_scope adds, moves and fuses nothing): a
 # device profile tells the sort engine's operations from the row
 # movement's and the exchange's by this name in their op_name
 SCOPE = "sort_engine"
 
+# above this row count, accelerator backends switch engines in auto
 XLA_SORT_MAX_N = 1 << 16
 # rows per tile of the chunked engine's base-case sort. The TPU
 # compiler's time for a 7-operand lax.sort grows steeply with rows
@@ -49,95 +51,65 @@ XLA_SORT_MAX_N = 1 << 16
 # tiles are one rolled loop whose compile time does not depend on them
 CHUNK_ROWS = 1 << 12
 
-# coarse per-platform element-op throughput, converting modeled engine
-# costs into µs — the unit the dispatch-latency audit joins back in
-# (parallel/mesh.py resolves sort_engine records with the program's
-# measured post-compile dispatch wall time). Deliberately order-of-
-# magnitude: the audit checks magnitude, not percent. "tpu" is NOT
-# MEASURED (a guess from round 1); a platform with no entry raises.
-_OPS_PER_US = {"cpu": 2e2, "tpu": 2e4}
+ENGINES = ("xla", "bitonic", "chunked", "radix")
 
 
-def _impl(n: int) -> str:
-    mode = os.environ.get("THRILL_TPU_SORT_IMPL", "auto")
-    if mode in ("xla", "bitonic", "chunked", "radix"):
-        return mode
-    if jax.default_backend() == "cpu" or n <= XLA_SORT_MAX_N:
-        return "xla"
-    return "chunked"
+def choose_engine(n: int, words, *, radix_ok=None,
+                  record: bool = True) -> str:
+    """THE device sort engine choice: every site that sorts or merges
+    ``n`` rows by ``words`` asks here, nowhere else.
 
+    ``THRILL_TPU_SORT_IMPL`` pins one of :data:`ENGINES`. Otherwise:
 
-def sort_engine_policy(n: int, total_bits: int, radix_ok: bool):
-    """THE cost model for the device sort engine choice (ROADMAP
-    planner edge (e)) — shared verbatim by the auto path here and by
-    ``Planner.sort_engine`` so both always agree.
-
-    Returns ``(engine, costs_us, reason)`` where ``costs_us`` maps each
-    candidate engine to its modeled cost in µs:
-
-    * xla     — one ``lax.sort``: ~n·log n work, but only where the
-                lowering is healthy (CPU, or n below the TPU compile
-                cliff at ``XLA_SORT_MAX_N``);
+    * xla     — one ``lax.sort``, where its lowering is healthy: on the
+                CPU, or at ``n <= XLA_SORT_MAX_N`` (the TPU compile
+                cliff);
     * chunked — batched tile sorts + bitonic merge stages:
                 n·(log²(tile)/2 + log C·log n) compare-exchanges;
     * radix   — LSD 8-bit passes over the key words (pallas_sort):
                 ~3n per pass (histogram + offsets + scatter),
-                ``total_bits/8`` passes, eligible only when the Pallas
-                stable-partition kernel engages (``radix_ok``).
-    """
-    plat = jax.default_backend()
-    ops = _OPS_PER_US[plat]
-    lg = max(1.0, math.log2(max(n, 2)))
-    if plat == "cpu" or n <= XLA_SORT_MAX_N:
-        return ("xla", {"xla": n * lg / ops},
-                "xla sort lowering healthy at this size")
-    costs = {}
-    lgc = math.log2(CHUNK_ROWS)
-    c_tiles = max(1.0, n / CHUNK_ROWS)
-    costs["chunked"] = n * (lgc * lgc / 2.0
-                            + math.log2(c_tiles) * lg) / ops
-    if radix_ok:
-        passes = max(1, (total_bits + 7) // 8)
-        costs["radix"] = 3.0 * n * passes / ops
-        reason = "past the xla compile cliff; radix eligible"
-    else:
-        reason = ("past the xla compile cliff; radix ineligible "
-                  "(Pallas off or too many rows)")
-    engine = min(costs, key=costs.get)
-    return engine, costs, reason
+                ``total_bits/8`` passes; a candidate only where the
+                Pallas stable-partition kernel engages (``radix_ok``;
+                None asks the dispatching mesh).
 
-
-def _auto_engine(words: List[jnp.ndarray], n: int) -> str:
-    """Resolve auto mode to an engine, routing through the planner's
-    cost model when one is attached and recording the choice in the
-    decision ledger (audited later with the program's measured dispatch
-    latency — see _CountedJit._dispatch)."""
+    Past the cliff the cheaper of chunked and radix wins, both priced
+    in modelled element operations. An unpinned choice is written to
+    the dispatching mesh's decision ledger as a ``sort_engine`` record
+    (``record=False``: a caller that sorts nothing itself, the merge of
+    presorted runs)."""
+    mode = os.environ.get("THRILL_TPU_SORT_IMPL", "auto")
+    if mode in ENGINES:
+        return mode
     from ..parallel import mesh as _mesh
-    from .pallas_kernels import MAX_ROWS, pallas_enabled
-
     mex = _mesh.current_mex()
-    radix_ok = pallas_enabled(mex) and n < MAX_ROWS
     total_bits = sum(32 if w.dtype == jnp.uint32 else 64 for w in words)
-    site = f"sort:n{n}:w{len(words)}"
-    pl = getattr(mex, "planner", None) if mex is not None else None
-    if pl is not None and pl.enabled:
-        engine, costs, reason = pl.sort_engine(n, total_bits, radix_ok,
-                                               site=site)
+    lg = max(1.0, math.log2(max(n, 2)))
+    if jax.default_backend() == "cpu" or n <= XLA_SORT_MAX_N:
+        costs = {"xla": n * lg}
+        reason = "xla sort lowering healthy at this size"
     else:
-        engine, costs, reason = sort_engine_policy(n, total_bits,
-                                                   radix_ok)
-    if mex is not None:
-        led = getattr(mex, "decisions", None)
-        if led is not None and led.enabled:
-            rec = led.record(
-                "sort_engine", site=site,
-                chosen=engine, predicted=costs.get(engine),
-                rejected=[(e, c) for e, c in sorted(costs.items())
-                          if e != engine],
-                reason=reason, n=n, total_bits=total_bits)
-            prog = _mesh.current_program()
-            if prog is not None and not prog._engine_armed:
-                prog._engine_recs.append(rec)
+        lgc = math.log2(CHUNK_ROWS)
+        c_tiles = max(1.0, n / CHUNK_ROWS)
+        costs = {"chunked": n * (lgc * lgc / 2.0
+                                 + math.log2(c_tiles) * lg)}
+        if radix_ok is None:
+            from .pallas_kernels import MAX_ROWS, pallas_enabled
+            radix_ok = pallas_enabled(mex) and n < MAX_ROWS
+        if radix_ok:
+            costs["radix"] = 3.0 * n * max(1, (total_bits + 7) // 8)
+            reason = "past the xla compile cliff; radix eligible"
+        else:
+            reason = ("past the xla compile cliff; radix ineligible "
+                      "(Pallas off or too many rows)")
+    engine = min(costs, key=costs.get)
+    led = getattr(mex, "decisions", None) if record else None
+    if led is not None and led.enabled:
+        led.record(
+            "sort_engine", site=f"sort:n{n}:w{len(words)}",
+            chosen=engine, predicted=costs[engine],
+            rejected=[(e, c) for e, c in sorted(costs.items())
+                      if e != engine],
+            reason=reason, n=n, total_bits=total_bits, unit="ops")
     return engine
 
 
@@ -189,9 +161,7 @@ def prepare_sort_words(words: List[jnp.ndarray], n: int):
 def argsort_words(words: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable argsort by uint64 key words (lexicographic). [n] int32."""
     n = words[0].shape[0]
-    mode = os.environ.get("THRILL_TPU_SORT_IMPL", "auto")
-    impl = mode if mode in ("xla", "bitonic", "chunked", "radix") \
-        else _auto_engine(words, n)
+    impl = choose_engine(n, words)
     if impl == "radix":
         # LSD radix over 8-bit digits (O(n * passes), no comparison
         # network, no XLA-sort compile cliff): Pallas stable-partition

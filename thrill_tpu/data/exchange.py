@@ -550,9 +550,9 @@ def _phase_a(shards: DeviceShards, dest_builder: Callable,
     phase-B row narrowing feeds on. Computing it here costs two
     reductions per leaf inside a program that already sorts the shard;
     whether anything READS it (the synced plan step, or an optimistic
-    miss heal) is again the caller's decision. Callers whose phase B
-    never narrows (the streamed rounds) pass ``want_ranges=False`` and
-    skip the analysis entirely."""
+    miss heal) is again the caller's decision. A caller whose phase B
+    never narrows passes ``want_ranges=False`` and skips the analysis
+    entirely."""
     mex = shards.mesh_exec
     # an upstream optimistic exchange may still owe its overflow check:
     # heal it before this program bakes the (possibly truncated)
@@ -667,116 +667,6 @@ def exchange(shards: DeviceShards, dest_builder: Callable, cache_key: Tuple,
     return _exchange_planned(mex, treedef, sorted_dest, sorted_leaves, S,
                              min_cap=min_cap, ident=cache_key,
                              smat_dev=send_mat, ranges=ranges)
-
-
-def exchange_stream(shards: DeviceShards, dest_builder: Callable,
-                    cache_key: Tuple):
-    """MixStream analog: yield received blocks round by round, in
-    arbitrary (schedule) order, instead of one compacted shard.
-
-    The reference's MixStream (thrill/data/mix_stream.hpp:126) delivers
-    blocks as they arrive so the consumer overlaps processing with the
-    shuffle. The TPU-native equivalent: each 1-factor round is its own
-    small jitted program whose result the consumer folds while jax's
-    async dispatch keeps later rounds' collectives in flight — no
-    global receive buffer, no compaction scatter, no rank-order
-    guarantee. Yields one DeviceShards per source (identity round
-    first, then the 1-factor schedule — tier-pure on sliced meshes).
-    """
-    mex = shards.mesh_exec
-    W = mex.num_workers
-    # streamed rounds ship full-width by design — skip range analysis
-    treedef, sorted_dest, sorted_leaves, send_mat, _ = _phase_a(
-        shards, dest_builder, cache_key, want_ranges=False)
-    # per-round caps genuinely need the host S — the same exchange
-    # barrier as the planned path, charged to the same wait lane
-    doc = getattr(mex, "doctor", None)
-    t0 = time.perf_counter() if doc is not None else 0.0
-    S = mex.fetch(send_mat)
-    if doc is not None:
-        doc.record_wait("xchg.plan_sync", None,
-                        time.perf_counter() - t0, lane="exchange")
-    account_traffic(mex, S, leaf_item_bytes(sorted_leaves),
-                    site="xchg:" + _ident_digest(cache_key)[:10])
-    cap = sorted_leaves[0].shape[1] if sorted_leaves else 0
-    if W > 1:
-        count_plan_build(mex)
-        led = _decisions.ledger_of(mex)
-        if led is not None:
-            rec = led.record(
-                "xchg_strategy", "xchg:" + _ident_digest(cache_key)[:10],
-                "stream", reason="MixStream delivery requested",
-                items=int(S.sum()))
-            led.resolve(rec, (int(S.sum()) - int(np.trace(S)))
-                        * leaf_item_bytes(sorted_leaves))
-
-    if W == 1:
-        yield DeviceShards(mex, jax.tree.unflatten(treedef, sorted_leaves),
-                           np.diag(S).astype(np.int64).copy())
-        return
-
-    rounds = one_factor_rounds(mex)
-    cap_ident = ("xchg_stream_caps", cache_key, cap, treedef,
-                 tuple((l.dtype, l.shape[2:]) for l in sorted_leaves))
-    needed = (max(int(np.diag(S).max()), 1),) + tuple(
-        max(int(S[np.arange(W), to].max()), 1) for to in rounds)
-    caps = _sticky_caps(mex, cap_ident, needed)
-    mex.stats_padded_rows += sum(caps)
-    # identity round is a local scatter; rounds 1.. cross the fabric
-    # (streamed rounds ship full-width: no narrowing on this path)
-    stream_bytes = W * sum(caps[1:]) * leaf_item_bytes(sorted_leaves)
-    mex.stats_bytes_wire_device += stream_bytes
-    mex.stats_bytes_wire_device_raw += stream_bytes
-
-    srow = mex.put_small(S.astype(np.int32))
-
-    def round_program(r: int, to, M_r: int):
-        key = ("xchg_stream_round", cap, M_r, W,
-               None if to is None else tuple(int(x) for x in to),
-               treedef,
-               tuple((l.dtype, l.shape[2:]) for l in sorted_leaves))
-
-        def build():
-            def f(sdest, srow_a, *ls):
-                d = sdest[0]
-                off = _ex_cumsum(srow_a[0])
-                i = jnp.arange(cap)
-                widx = lax.axis_index(AXIS)
-                d_r = widx if to is None else jnp.take(
-                    jnp.asarray(to), widx)
-                sel = d == d_r
-                slot = i - jnp.take(off, d_r)
-                send_idx = jnp.where(sel, slot, M_r)
-                outs = []
-                for l in ls:
-                    x = l[0]
-                    buf = jnp.zeros((M_r + 1,) + x.shape[1:], x.dtype)
-                    buf = buf.at[send_idx].set(x)[:M_r]
-                    if to is not None:
-                        with jax.named_scope(SCOPE):
-                            buf = lax.ppermute(
-                                buf, AXIS,
-                                perm=[(w, int(to[w])) for w in range(W)])
-                    outs.append(buf[None])
-                return tuple(outs)
-
-            return mex.smap(f, 2 + len(sorted_leaves))
-
-        return mex.cached(key, build)
-
-    # identity round: the diagonal blocks, no communication
-    f0 = round_program(0, None, caps[0])
-    out0 = f0(sorted_dest, srow, *sorted_leaves)
-    yield DeviceShards(mex, jax.tree.unflatten(treedef, list(out0)),
-                       np.diag(S).astype(np.int64).copy())
-    for r, to in enumerate(rounds):
-        inv = np.empty(W, dtype=np.int64)
-        inv[to] = np.arange(W)
-        fr = round_program(r + 1, to, caps[r + 1])
-        outr = fr(sorted_dest, srow, *sorted_leaves)
-        counts_r = S[inv, np.arange(W)].astype(np.int64)
-        yield DeviceShards(mex, jax.tree.unflatten(treedef, list(outr)),
-                           counts_r.copy())
 
 
 def _sticky_caps(mex: MeshExec, ident: Tuple, needed: Tuple[int, ...]
@@ -929,13 +819,14 @@ def leaf_item_bytes(leaves) -> int:
 #   saved_padded_bytes > extra_launches * BYTES_EQ
 #
 # where BYTES_EQ = round_overhead * exchange_bandwidth, both measured
-# on the actual mesh by benchmarks/exchange_crossover.py:
+# on the actual mesh by a sweep of payload sizes through both plans:
 #   * virtual 8-device CPU mesh (this image, 2026-07-30, plan pinned
 #     during calibration): round_overhead 119 us, dense bw 378 MB/s
 #     -> BYTES_EQ ~45 KiB
 #   * "tpu": NOT MEASURED. The value is the guess earlier rounds fell
 #     through to (~10-30 us launch overhead at multi-GB/s effective
-#     -> O(1 MiB)); measure it with the same script on a four-chip host.
+#     -> O(1 MiB)); a four-chip cell that takes both plans would
+#     measure it (ROADMAP S10).
 # A platform with no entry raises: a device nobody priced is an error,
 # not a default. Override with THRILL_TPU_XCHG_BYTES_EQ.
 _BYTES_EQ_MEASURED = {"cpu": 45_000, "tpu": 1 << 20}
@@ -943,7 +834,7 @@ _BYTES_EQ_MEASURED = {"cpu": 45_000, "tpu": 1 << 20}
 # other factor of BYTES_EQ. The launch-overhead factor is measured on
 # this very mesh (the dispatch-latency spine); bandwidth stays a
 # baked platform constant because measuring it needs a sized payload
-# sweep (benchmarks/exchange_crossover.py), not a passive observer.
+# sweep, not a passive observer.
 _BYTES_EQ_BANDWIDTH = {"cpu": 378e6,
                        "tpu": 4e9}      # not measured (see above)
 _BYTES_EQ_MIN_SAMPLES = 256
@@ -1390,7 +1281,6 @@ def _dispatch_chunked(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
                     call = fn.donating(acc_pos)
                     # aliasing is real here (non-CPU, no capture): count
                     # the chunk handoffs whose accumulators were donated
-                    # so benchmarks can report measured donation traffic
                     mex.stats_xchg_donated += len(acc_pos)
                 else:
                     call = fn

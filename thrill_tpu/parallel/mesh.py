@@ -46,8 +46,9 @@ _F_DISPATCH = faults.declare("api.mesh.dispatch")
 # FIRST call, when jax traces the python builder), the owning mesh and
 # the _CountedJit being run are visible here. Plan choke points that
 # live INSIDE traced builders (core/device_sort.py's engine choice)
-# use this to reach the decision ledger / planner without threading a
-# mex handle through every functional signature.
+# use this to reach the decision ledger without threading a mex handle
+# through every functional signature; the compile listener names its
+# span by the program.
 _TL = threading.local()
 
 
@@ -155,12 +156,6 @@ class _CountedJit:
         # the name the jitted callable carries (module ``jit_<label>``
         # on the device plane) and every host span of this program
         self._trace_label: Optional[str] = label
-        # sort-engine decisions recorded while THIS program traced
-        # (core/device_sort.py via current_program()); resolved with
-        # the first post-compile dispatch latency (the tracing call's
-        # wall time is compile, not dispatch)
-        self._engine_recs: list = []
-        self._engine_armed = False
         functools.update_wrapper(self, jitted, updated=())
 
     def _label(self) -> str:
@@ -228,17 +223,6 @@ class _CountedJit:
         if dt < mex._disp_lat_min:
             mex._disp_lat_min = dt
         mex._disp_lat_n += 1
-        if self._engine_recs:
-            if not self._engine_armed:
-                # this call traced the program (and recorded the
-                # engine decision); its wall time is compile time
-                self._engine_armed = True
-            else:
-                led = mex.decisions
-                if led is not None and led.enabled:
-                    for erec in self._engine_recs:
-                        led.resolve(erec, dt * 1e6)
-                self._engine_recs = []
         if pres is not None and pres.enabled and self._out_bytes is None:
             self._out_bytes = sum(
                 int(getattr(l, "nbytes", 0) or 0)
@@ -411,7 +395,7 @@ class MeshExec:
         # choice takes its legacy per-site heuristic branch exactly
         self.planner = None
         # per-Iterate reports (phase timings, replay hit rate) for
-        # bench.py / tools/loop_report.py
+        # tools/loop_report.py
         self.loop_reports: list = []
         # tapes kept for the next Iterate call of the same loop
         # (api/loop.py _share_token -> LoopPlan)

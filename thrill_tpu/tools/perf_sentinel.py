@@ -112,7 +112,7 @@ ENV_NOTE = (
     "THRILL_TPU_WIRE_COMPRESS", "THRILL_TPU_PLANNER",
     "THRILL_TPU_EXCHANGE",
     "THRILL_TPU_LOCATION_DETECT", "THRILL_TPU_DUP_DETECT",
-    "THRILL_TPU_LOOP_REPLAY", "THRILL_TPU_FORI",
+    "THRILL_TPU_LOOP_REPLAY",
     "THRILL_TPU_NATIVE_RECORDS", "THRILL_TPU_PREFETCH",
     "THRILL_TPU_WRITEBACK",
     "THRILL_TPU_PALLAS", "THRILL_TPU_SORT_IMPL",
@@ -526,15 +526,16 @@ def diff(contract: dict, fresh: dict) -> List[str]:
 
 
 def default_path() -> str:
-    """PERF_CONTRACT.json at the repo root (next to bench.py) when run
-    from a checkout, else the current directory. The checkout test is
-    the bench.py marker — the package grandparent always EXISTS (the
-    module was imported from it), so a mere isdir check would route a
+    """PERF_CONTRACT.json at the repo root when run from a checkout,
+    else the current directory. The checkout test is the contract file
+    itself — the package grandparent always EXISTS (the module was
+    imported from it), so a mere isdir check would route a
     pip-installed run's contract next to site-packages."""
     here = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    if os.path.isfile(os.path.join(here, "bench.py")):
-        return os.path.join(here, "PERF_CONTRACT.json")
+    path = os.path.join(here, "PERF_CONTRACT.json")
+    if os.path.isfile(path):
+        return path
     return os.path.abspath("PERF_CONTRACT.json")
 
 
