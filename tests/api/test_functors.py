@@ -144,8 +144,8 @@ def test_malformed_reduce_fn_structure_raises():
 
 
 def test_field_reduce_bool_first_leaf_device_engine(monkeypatch):
-    """bool 'first' leaves must work on the segment-op device engine
-    (segment_sum rejects bool; the engine casts through int32)."""
+    """bool 'first' leaves must work on the per-field device engine
+    (a gather at the run starts, any dtype)."""
     monkeypatch.setenv("THRILL_TPU_HOST_RADIX", "0")
     n = 2000
     rng = np.random.default_rng(3)
@@ -163,9 +163,9 @@ def test_field_reduce_bool_first_leaf_device_engine(monkeypatch):
 
 
 def test_field_reduce_first_preserves_negative_zero(monkeypatch):
-    """float 'first' on the segment-op engine must be bit-exact: a
-    -0.0 first value keeps its sign bit (the engine bitcasts through
-    uints; a float sum would canonicalize -0.0 + 0.0 -> +0.0)."""
+    """float 'first' on the per-field engine must be bit-exact: a
+    -0.0 first value keeps its sign bit (the engine gathers the row;
+    a float sum would canonicalize -0.0 + 0.0 -> +0.0)."""
     monkeypatch.setenv("THRILL_TPU_HOST_RADIX", "0")
     data = {"k": np.array([1, 1, 2, 2], np.int64),
             "f": np.array([-0.0, 5.0, 3.0, -0.0], np.float64),

@@ -376,19 +376,19 @@ def test_named_scopes_are_in_the_programs_metadata():
         .as_text(debug_info=True)
     assert f"/{device_sort.SCOPE}/" in text and f"/{rowmove.SCOPE}/" in text
 
-    def g(x):       # ReduceByKey's W=1 program: sort, gathers, compaction
+    def g(x):       # ReduceByKey's W=1 program: sort, gathers, the fold
         from thrill_tpu.core import segmented
-        from thrill_tpu.data.shards import compact_valid
         w, tree, valid, _ = segmented.sort_by_key_words(
             [x], {"v": x}, x > 3)
-        w, tree, rep = segmented.reduce_runs(w, tree, valid, None,
-                                             {"v": "sum"})
-        return compact_valid(tree, rep)
+        return segmented.reduce_runs(w, tree, valid, None, ["sum"])
 
     text = jax.jit(g).lower(jnp.arange(64, dtype=jnp.uint32)) \
         .as_text(debug_info=True)
-    assert f"/{rowmove.SCOPE}/" in text and "/compact/" in text
-    assert "/segmented_reduce/" in text
+    assert f"/{rowmove.SCOPE}/" in text
+    # the fold's rows come compact: positions, then scan and gathers
+    assert "/segmented_reduce/run_bounds/" in text
+    assert "/segmented_reduce/run_fold/" in text
+    assert "/compact/" not in text
     ctx = Context(MeshExec(num_workers=2))
     try:
         mex = ctx.mesh_exec

@@ -129,8 +129,9 @@ class Segment:
     # lets the plan hand host-known counts through, like the legacy
     # apply_stack_device counts passthrough
     preserves_counts: bool = False
-    # output already has all valid rows in a prefix (sorts): the final
-    # compaction scatter is skipped
+    # output already has all valid rows in a prefix (sorts, a dense
+    # ReduceToIndex range, ReduceByKey's fold, which gathers one row
+    # per run): the final compaction scatter is skipped
     already_compact: bool = False
     # host-known output counts this segment imposes (ReduceToIndex's
     # dense range sizes); replaces the plan's known counts

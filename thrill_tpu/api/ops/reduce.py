@@ -22,9 +22,10 @@ the code can see of the reduce function and the leaves:
   8-byte values as pairs at 122-126 ns per update; the index plan reads
   no value, so a loop with an invariant index sorts once
   (api/fusion.py ``Segment.index_plan``);
-- any other reduce function: sort the items by index and reduce the
-  runs (``sort_by_key_words`` + ``reduce_runs``), then scatter the
-  representatives.
+- any other reduce function: sort the items by index and fold the
+  runs (``sort_by_key_words`` + ``reduce_runs``, which hands back one
+  row per run, compact and in index order), then scatter those rows to
+  their dense places by the gathered index word.
 """
 
 from __future__ import annotations
@@ -41,7 +42,7 @@ from ...common.partition import dense_range_bounds
 from ...core import keys as keymod
 from ...core import segmented
 from ...data import exchange
-from ...data.shards import DeviceShards, HostShards, compact_valid
+from ...data.shards import DeviceShards, HostShards
 from ..dia import DIA
 from ..dia_base import DIABase
 from ...parallel.mesh import AXIS
@@ -58,7 +59,7 @@ SCATTER_SCOPE = "reduce_to_index"
 
 
 def _device_fold_specs(reduce_fn, treedef, leaves):
-    """Flat FieldReduce specs when the DEVICE segment-op specialization
+    """Flat FieldReduce specs when the DEVICE per-field specialization
     applies (core/segmented.py segmented_reduce_fields), else None."""
     from ..functors import FieldReduce
     if not isinstance(reduce_fn, FieldReduce):
@@ -73,7 +74,8 @@ def _device_fold_specs(reduce_fn, treedef, leaves):
 def _local_reduce_device(shards: DeviceShards, key_fn: Callable,
                          reduce_fn: Callable, phase: str,
                          token) -> DeviceShards:
-    """One jitted program: encode keys, sort, segmented-reduce, compact."""
+    """One jitted program: encode keys, sort, fold the runs (one row per
+    run, compact as it comes: core/segmented.py ``reduce_runs``)."""
     mex = shards.mesh_exec
     # an optimistic post-exchange input may owe its capacity check —
     # heal before reading the columns (data/exchange.py)
@@ -94,11 +96,10 @@ def _local_reduce_device(shards: DeviceShards, key_fn: Callable,
             words = keymod.encode_key_words(key_fn(tree))
             words, tree, valid, _ = segmented.sort_by_key_words(
                 words, tree, valid)
-            words, tree, rep = segmented.reduce_runs(
+            _, tree, n_runs = segmented.reduce_runs(
                 words, tree, valid, reduce_fn, specs)
-            tree, new_count = compact_valid(tree, rep)
             out_leaves = jax.tree.leaves(tree)
-            return (new_count[None, None].astype(jnp.int32),
+            return (n_runs[None, None].astype(jnp.int32),
                     *[l[None] for l in out_leaves])
 
         return mex.smap(f, 1 + len(leaves))
@@ -317,9 +318,10 @@ class ReduceNode(DIABase):
 
     def _fuse_segment(self, phase: str):
         """This node's local combine phase as a fused segment
-        (api/fusion.py): the same encode + sort + segmented-reduce
-        trace as :func:`_local_reduce_device`, stitched into a larger
-        program instead of paying its own dispatch. The FieldReduce
+        (api/fusion.py): the same encode + sort + fold trace as
+        :func:`_local_reduce_device`, stitched into a larger program
+        instead of paying its own dispatch. The fold's rows are a
+        prefix already, so no compaction follows it. The FieldReduce
         specs are derived at trace time from the actual traced tree
         (the composite plan key pins treedef/dtypes, so the choice is
         deterministic per executable)."""
@@ -335,13 +337,14 @@ class ReduceNode(DIABase):
             words = keymod.encode_key_words(key_fn(tree))
             words, tree_s, valid, _ = segmented.sort_by_key_words(
                 words, tree, mask)
-            words, tree_s, rep = segmented.reduce_runs(
+            _, tree_k, n_runs = segmented.reduce_runs(
                 words, tree_s, valid, reduce_fn, specs)
-            return tree_s, rep
+            return tree_k, jnp.arange(mask.shape[0]) < n_runs
 
         return fusion.Segment(label="ReduceLocal",
                               token=("reduce_local", phase, self.token),
-                              trace=trace, dia_id=self.id)
+                              trace=trace, already_compact=True,
+                              dia_id=self.id)
 
     def compute_plan(self):
         from .. import fusion
@@ -775,6 +778,16 @@ def _dense_pos(valid, local_idx, range_size, out_cap):
     return jnp.where(ok, local_idx, out_cap).astype(jnp.int32)
 
 
+def _run_pos(index_word, n_runs, range_start, out_cap):
+    """Target row of every folded run (the generic engine's compact
+    rows, core/segmented.py ``reduce_runs``), [cap] int: the run's
+    index relative to the range, clipped into it, or the dump row
+    ``out_cap`` for the rows behind the last run."""
+    local_idx = index_word.astype(jnp.int64) - range_start
+    live = jnp.arange(index_word.shape[0]) < n_runs
+    return jnp.clip(jnp.where(live, local_idx, out_cap), 0, out_cap)
+
+
 def _wants_sorted_fold(specs, leaves) -> bool:
     """Does any leaf take the fold over sorted runs? A "sum" of 8-byte
     values does: XLA:TPU scatters those as pairs of 32-bit values at
@@ -968,11 +981,9 @@ class ReduceToIndexNode(DIABase):
             words = [idx.astype(jnp.uint64)]
             words, tree_s, valid, _ = segmented.sort_by_key_words(
                 words, tree, mask)
-            words, tree_s, rep = segmented.reduce_runs(
+            words, tree_s, n_runs = segmented.reduce_runs(
                 words, tree_s, valid, reduce_fn, specs)
-            local_idx = words[0].astype(jnp.int64) - range_start
-            pos = jnp.where(rep, local_idx, out_cap)
-            pos = jnp.clip(pos, 0, out_cap)
+            pos = _run_pos(words[0], n_runs, range_start, out_cap)
 
             def scatter(leaf):
                 base = jnp.zeros((out_cap + 1,) + leaf.shape[1:],
@@ -1092,11 +1103,10 @@ class ReduceToIndexNode(DIABase):
                 words = [idx.astype(jnp.uint64)]
                 words, tree, valid, _ = segmented.sort_by_key_words(
                     words, tree, valid)
-                words, tree, rep = segmented.reduce_runs(
+                words, tree, n_runs = segmented.reduce_runs(
                     words, tree, valid, reduce_fn, specs)
-                local_idx = (words[0].astype(jnp.int64) - range_start[0, 0])
-                pos = jnp.where(rep, local_idx, out_cap)
-                pos = jnp.clip(pos, 0, out_cap)
+                pos = _run_pos(words[0], n_runs, range_start[0, 0],
+                               out_cap)
 
                 def scatter(leaf):
                     base = jnp.zeros((out_cap + 1,) + leaf.shape[1:],

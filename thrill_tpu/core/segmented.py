@@ -5,9 +5,11 @@ The reference aggregates with linear-probing hash tables
 reduce_by_hash_post_phase.hpp:44, reduce_probing_hash_table.hpp:77).
 Hash tables are a pointer-chasing CPU idiom; the TPU-native equivalent
 is sort + segmented reduction: XLA's bitonic sort groups equal keys into
-runs, a segmented associative scan combines each run with the user's
-reduce function, and run representatives are compacted out. Everything
-is static-shaped, branch-free and VPU/MXU friendly.
+runs, a segmented scan combines each run with the user's reduce
+function, and one row per run is gathered from the run boundaries:
+the output is born compact and in key order, and no value is scattered
+on the way. Everything is static-shaped, branch-free and VPU/MXU
+friendly.
 """
 
 from __future__ import annotations
@@ -60,18 +62,63 @@ def segment_boundaries(words: List[jnp.ndarray], valid: jnp.ndarray
     return diff & valid
 
 
+def run_bounds(starts: jnp.ndarray, valid: jnp.ndarray
+               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Where the runs lie, by output row: ``(start_of, end_of, n_runs)``.
+
+    ``starts`` from :func:`segment_boundaries` over rows that are
+    key-sorted with the invalid ones last. Output row ``k < n_runs`` is
+    run ``k`` in key order: its first row is the sorted position
+    ``start_of[k]``, its last ``end_of[k]`` (the position before the
+    next start; the last run ends at the last VALID row, so the invalid
+    tail behind it is in no run). Rows at and past ``n_runs`` point at
+    ``n``, out of range, and gather as zeros. All [n] int32 and 32-bit
+    work, and nothing is scattered: the positions of the set flags come
+    out of one single-operand sort of ``where(starts, i, n)`` (7.4 ms
+    for 2^22 rows on a v5e, where the 32-bit scatter of positions takes
+    25.1; PERF.md section 6, PR 31)."""
+    n = valid.shape[0]
+    with jax.named_scope("run_bounds"):
+        idx = jnp.arange(n, dtype=jnp.int32)
+        n_runs = jnp.sum(starts.astype(jnp.int32))
+        start_of = jax.lax.sort(jnp.where(starts, idx, n))
+        next_start = jnp.concatenate([start_of[1:],
+                                      jnp.full(1, n, jnp.int32)])
+        count = jnp.sum(valid.astype(jnp.int32))
+        end_of = jnp.where(idx < n_runs,
+                           jnp.minimum(next_start, count) - 1, n)
+    return start_of, end_of, n_runs
+
+
+def _rows_at(leaf: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
+    """``leaf[at]`` by rows; a position out of range reads zeros.
+
+    XLA:TPU holds a 64-bit integer as two 32-bit halves and gathers
+    each by itself; as ``u32[n, ..., 2]`` rows one gather moves both
+    (19.3 ms against 61.1 for 2^22 int64, bit for bit; PERF.md section
+    6, PR 31). Not for binary64: its halves are two binary32, which a
+    bit-cast has to convert."""
+    if leaf.dtype.itemsize == 8 and jnp.issubdtype(leaf.dtype, jnp.integer):
+        halves = jax.lax.bitcast_convert_type(leaf, jnp.uint32)
+        return jax.lax.bitcast_convert_type(_rows_at(halves, at), leaf.dtype)
+    return jnp.take(leaf, at, axis=0, mode="fill", fill_value=0)
+
+
 def segmented_reduce(words: List[jnp.ndarray], tree: Any,
                      valid: jnp.ndarray, reduce_fn: Callable
                      ) -> Tuple[List[jnp.ndarray], Any, jnp.ndarray]:
     """Combine each equal-key run into one item.
 
     Inputs must be key-sorted with invalid items last. Returns
-    (words, tree, rep_mask): ``rep_mask`` marks one surviving item per
-    run, whose tree value is the fold of the whole run. The fold uses a
-    segmented inclusive scan, so ``reduce_fn`` must be associative
-    (same contract as the reference's reduce function).
+    ``(words, tree, n_runs)``: row ``k < n_runs`` of the leaves is the
+    fold of run ``k`` and of the words its key, compact and in key
+    order; rows at and past ``n_runs`` are zero. The fold is a
+    segmented inclusive scan read at every run's last row, so
+    ``reduce_fn`` must be associative (same contract as the reference's
+    reduce function).
     """
     starts = segment_boundaries(words, valid)
+    start_of, end_of, n_runs = run_bounds(starts, valid)
 
     def combine(a, b):
         tree_a, flag_a = a
@@ -82,19 +129,12 @@ def segmented_reduce(words: List[jnp.ndarray], tree: Any,
             merged, tree_b)
         return keep_b, flag_a | flag_b
 
-    scanned, _ = jax.lax.associative_scan(combine, (tree, starts), axis=0)
-    return words, scanned, _rep_mask(starts, valid)
-
-
-def _rep_mask(starts: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
-    """Representative = last item of each run (position before the next
-    start), or the last valid item overall. Shared by both segmented
-    reduce engines so the contract cannot diverge."""
-    n = valid.shape[0]
-    next_start = jnp.roll(starts, -1).at[-1].set(True)
-    count = jnp.sum(valid.astype(jnp.int32))
-    is_last_valid = jnp.arange(n) == count - 1
-    return valid & (next_start | is_last_valid)
+    with jax.named_scope("run_fold"):
+        scanned, _ = jax.lax.associative_scan(combine, (tree, starts),
+                                              axis=0)
+        return ([_rows_at(w, start_of) for w in words],
+                jax.tree.map(lambda l: _rows_at(l, end_of), scanned),
+                n_runs)
 
 
 def _bshape(flag, leaf):
@@ -105,9 +145,12 @@ def _bshape(flag, leaf):
 @jax.named_scope("segmented_reduce")
 def reduce_runs(words, tree, valid, reduce_fn, specs):
     """One dispatch point for every device reduce program: the
-    segment-op engine when ``specs`` (from FieldReduce, pre-gated by
+    per-field engine when ``specs`` (from FieldReduce, pre-gated by
     :func:`fields_specializable`) is available, else the generic
-    associative scan. Same (words, tree, rep) contract either way."""
+    associative scan. Same ``(words, tree, n_runs)`` contract either
+    way: one row per run, compact and in key order, made by gathers at
+    the run boundaries (:func:`run_bounds`), so nothing behind it has
+    rows left to compact."""
     if specs is not None:
         return segmented_reduce_fields(words, tree, valid, specs)
     return segmented_reduce(words, tree, valid, reduce_fn)
@@ -115,19 +158,13 @@ def reduce_runs(words, tree, valid, reduce_fn, specs):
 
 def fields_specializable(flat_specs, leaf_dtypes) -> bool:
     """Can :func:`segmented_reduce_fields` handle this FieldReduce
-    spec? "first" takes any dtype; "sum" needs numeric (bool addition
-    differs between numpy and the scan's `+`); "min"/"max" need
-    INTEGER dtypes — float segment-min/max via scatter does not
-    guarantee the NaN-propagation order jnp.minimum gives the generic
-    scan, so floats keep the scan."""
+    spec? "first" is a gather and takes any dtype; "sum" needs numeric
+    (bool addition differs between numpy and the scan's `+`);
+    "min"/"max" need INTEGER dtypes: the order in which NaNs and signed
+    zeros meet is the generic scan's to decide, so floats keep it."""
     import numpy as np
     for s, dt in zip(flat_specs, leaf_dtypes):
         if s == "first":
-            # bool/int/uint/float all route through an exact integer
-            # segment_sum (floats via bitcast); complex has no clean
-            # bitcast target — keep the scan for it
-            if np.issubdtype(dt, np.complexfloating):
-                return False
             continue
         if s == "sum":
             if not (np.issubdtype(dt, np.integer)
@@ -141,79 +178,39 @@ def fields_specializable(flat_specs, leaf_dtypes) -> bool:
     return True
 
 
+_FIELD_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
 def segmented_reduce_fields(words: List[jnp.ndarray], tree: Any,
                             valid: jnp.ndarray, flat_specs
                             ) -> Tuple[List[jnp.ndarray], Any,
                                        jnp.ndarray]:
-    """FieldReduce specialization of :func:`segmented_reduce` — same
-    inputs and (words, tree, rep_mask) contract, different engine: each
-    field folds with ONE sorted segment reduction plus one gather
-    instead of the O(log n)-round associative scan over the whole tree.
-    On TPU that is a single scatter pass per field through HBM rather
-    than log2(n) combine rounds; the reference reaches the same shape
-    by accumulating std::plus directly in its probing table.
+    """FieldReduce specialization of :func:`segmented_reduce`: same
+    inputs and ``(words, tree, n_runs)`` contract, each field folded by
+    itself. "first" is one gather at the run starts. "sum" / "min" /
+    "max" are a segmented inclusive scan of the sorted leaf in log2(n)
+    shifted passes (:func:`_segmented_scan`) and one gather at the run
+    ends; the reference reaches the same shape by accumulating
+    std::plus directly in its probing table. No value is scattered:
+    XLA:TPU scatters 8-byte and ``u8[n, 16]`` rows at 67-89 ns a row,
+    sorted indices or not (PERF.md section 6, PR 31).
 
-    "first" is computed as segment_sum of a start-row-masked
-    contribution (each segment receives exactly one addend — its first
-    row — so the sum IS the first value, exactly). Caller gates with
-    :func:`fields_specializable`.
+    A scan adds the terms of one run only, the invalid tail behind the
+    last run never reaches a row that is read, and a float sum adds
+    each run's terms in sorted order, pairwise: the unordered-reduce
+    contract. A run that sums to -0.0 keeps its sign, as in the generic
+    engine. Caller gates with :func:`fields_specializable`.
     """
-    import jax.ops as jops
-
-    n = valid.shape[0]
     starts = segment_boundaries(words, valid)
-    seg = jnp.clip(jnp.cumsum(starts.astype(jnp.int32)) - 1, 0, n - 1)
+    start_of, end_of, n_runs = run_bounds(starts, valid)
     leaves, td = jax.tree.flatten(tree)
-    out_leaves = []
-    for s, leaf in zip(flat_specs, leaves):
-        v = _bshape(valid, leaf)
-        if s == "first":
-            st = _bshape(starts, leaf)
-            # exactly one addend lands in each segment, so segment_sum
-            # IS a select — but only over INTEGERS: bools cast through
-            # int32, and floats BITCAST to same-width uints (a float
-            # sum would canonicalize -0.0 + 0.0 to +0.0, silently
-            # diverging from the scan engine on sign-bit-sensitive
-            # consumers) and bitcast back
-            fdt = leaf.dtype
-            if fdt == jnp.bool_:
-                src = leaf.astype(jnp.int32)
-            elif jnp.issubdtype(fdt, jnp.floating):
-                src = jax.lax.bitcast_convert_type(
-                    leaf, jnp.dtype(f"uint{fdt.itemsize * 8}"))
-            else:
-                src = leaf
-            contrib = jnp.where(st, src, jnp.zeros_like(src))
-            res = jops.segment_sum(contrib, seg, num_segments=n,
-                                   indices_are_sorted=True)
-            if fdt == jnp.bool_:
-                res = res.astype(jnp.bool_)
-            elif jnp.issubdtype(fdt, jnp.floating):
-                res = jax.lax.bitcast_convert_type(res, fdt)
-        elif s == "sum":
-            # Float sums mask invalid rows to +0.0, which IEEE adds
-            # as identity EXCEPT for the sign of zero: a group whose
-            # true sum is -0.0 comes back +0.0 here (the scan engine,
-            # folding only real rows, preserves -0.0). Accepted
-            # divergence — the unordered-reduce contract never
-            # promised sign-of-zero, and excluding float sums would
-            # forfeit the specialization for the dominant use case.
-            contrib = jnp.where(v, leaf, jnp.zeros_like(leaf))
-            res = jops.segment_sum(contrib, seg, num_segments=n,
-                                   indices_are_sorted=True)
-        elif s == "min":
-            fill = jnp.array(jnp.iinfo(leaf.dtype).max, leaf.dtype)
-            contrib = jnp.where(v, leaf, fill)
-            res = jops.segment_min(contrib, seg, num_segments=n,
-                                   indices_are_sorted=True)
-        else:  # "max"
-            fill = jnp.array(jnp.iinfo(leaf.dtype).min, leaf.dtype)
-            contrib = jnp.where(v, leaf, fill)
-            res = jops.segment_max(contrib, seg, num_segments=n,
-                                   indices_are_sorted=True)
-        out_leaves.append(jnp.take(res, seg, axis=0))
-    return (words, jax.tree.unflatten(td, out_leaves),
-            _rep_mask(starts, valid))
+    with jax.named_scope("run_fold"):
+        out_leaves = [
+            _rows_at(leaf, start_of) if s == "first" else
+            _rows_at(_segmented_scan(leaf, starts, _FIELD_OPS[s]), end_of)
+            for s, leaf in zip(flat_specs, leaves)]
+        return ([_rows_at(w, start_of) for w in words],
+                jax.tree.unflatten(td, out_leaves), n_runs)
 
 
 # ----------------------------------------------------------------------
@@ -255,20 +252,22 @@ def sorted_fold_plan(pos: jnp.ndarray, num_rows: int):
     return perm, offsets, starts
 
 
-def _segmented_cumsum(x: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive sums that begin anew at every set flag, in log2(n)
-    shifted passes (Hillis-Steele: 1.5 ms for 2^21 binary64 values on a
-    v5e, where ``lax.associative_scan`` takes 7.7 ms and four minutes to
-    compile). Every partial sum adds terms of one run only: no prefix
-    is subtracted anywhere, so a small run beside a large one keeps its
-    precision."""
+def _segmented_scan(x: jnp.ndarray, starts: jnp.ndarray,
+                    op: Callable = jnp.add) -> jnp.ndarray:
+    """Inclusive folds by ``op`` that begin anew at every set flag, in
+    log2(n) shifted passes (Hillis-Steele: 1.5 ms for 2^21 binary64
+    sums on a v5e, where ``lax.associative_scan`` takes 7.7 ms and four
+    minutes to compile). Every partial result combines terms of one run
+    only: no prefix is subtracted anywhere, so a small run beside a
+    large one keeps its precision. The zeros shifted in at the front
+    reach only rows ahead of the first flag, which belong to no run."""
     n = x.shape[0]
     flags = starts
     d = 1
     while d < n:
         shifted = jnp.concatenate(
             [jnp.zeros((d,) + x.shape[1:], x.dtype), x[:-d]])
-        x = jnp.where(_bshape(flags, x), x, shifted + x)
+        x = jnp.where(_bshape(flags, x), x, op(shifted, x))
         flags = flags | jnp.concatenate(
             [jnp.ones(d, jnp.bool_), flags[:-d]])
         d *= 2
@@ -292,7 +291,7 @@ def sorted_fold_sum(leaf: jnp.ndarray, plan) -> jnp.ndarray:
     perm, offsets, starts = plan
     n = perm.shape[0] - 1
     with jax.named_scope("sorted_fold"):
-        sums = _segmented_cumsum(
+        sums = _segmented_scan(
             jnp.take(leaf, perm, axis=0, mode="fill", fill_value=0),
             starts)
         last = jnp.where(offsets[1:] > offsets[:-1], offsets[1:] - 1, n)
