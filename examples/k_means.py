@@ -1,14 +1,18 @@
-"""k-means clustering: classify + ReducePair + Collapse loop.
+"""k-means clustering: classify + ReduceToIndex + AllGatherArrays loop.
 
 Reference: /root/reference/examples/k-means/k-means.hpp:176-259 —
 points classified to the nearest center, per-center sums reduced
 (ReduceByKey on center index), new centers broadcast, loop with
 Collapse'd DIAs.
 
-TPU-native: points are a device [n, dim] column; classification is a
-batched distance matmul (MXU work!), the per-center reduction is
-ReduceToIndex, and centers travel to the next iteration as a small host
-array (the reference's AllReduce/broadcast step).
+TPU-native: points are a device [n, dim] column; classification is
+elementwise binary64 arithmetic (squared differences per dimension and
+an argmin over k: a TPU has no binary64 unit, XLA runs it as pairs of
+binary32 on the vector unit, and none of it reaches the MXU), the
+per-center reduction is ReduceToIndex over 0..k-1, the division a Map,
+and the centers travel to the next iteration as a small device array
+(the reference's AllReduce/broadcast step). ``chipbench/jobs/kmeans.py``
+is the same pipeline as the chip benchmark runs it.
 """
 
 from __future__ import annotations
@@ -18,7 +22,7 @@ import _bootstrap  # noqa: F401  (repo root on sys.path for CLI runs)
 
 import numpy as np
 
-from thrill_tpu.api import Context
+from thrill_tpu.api import Bind, Context, FieldReduce, Iterate
 
 
 # Module-level stacked/keyed functions (identity-stable -> executable
@@ -30,9 +34,7 @@ from thrill_tpu.api import Context
 
 def _label(x, c):                       # x: [n_local, dim] batched
     import jax.numpy as jnp
-    d2 = (jnp.sum(x * x, axis=1, keepdims=True)
-          - 2.0 * x @ c.T
-          + jnp.sum(c * c, axis=1)[None, :])
+    d2 = ((x[:, None, :] - c[None, :, :]) ** 2).sum(-1)
     return {"i": jnp.argmin(d2, axis=1).astype(jnp.int64), "x": x,
             "cnt": x[:, 0] * 0 + 1.0}
 
@@ -46,60 +48,62 @@ def _cluster_i(t):
 # device dispatch at any backend, so the loop body is fully recordable
 # for LoopPlan replay (a generic reduce lambda would demote to the
 # host engine on CPU and break the capture)
-def _cluster_sum():
-    from thrill_tpu.api import FieldReduce
-    return FieldReduce({"i": "first", "x": "sum", "cnt": "sum"})
+_CLUSTER_SUM = FieldReduce({"i": "first", "x": "sum", "cnt": "sum"})
 
 
-def _center_update(sum_x, cnt, centers):
+def _with_row(t, row):
+    # row j of ReduceToIndex's output is cluster j, also where no point
+    # fell and "i" reads the neutral
+    return {"j": row, "x": t["x"], "cnt": t["cnt"]}
+
+
+def _center_update(t, centers):
     import jax.numpy as jnp
+    cnt = t["cnt"]
     return jnp.where((cnt > 0)[:, None],
-                     sum_x / jnp.maximum(cnt, 1.0)[:, None],
-                     centers)
+                     t["x"] / jnp.maximum(cnt, 1.0)[:, None],
+                     centers[t["j"]])
+
+
+def _lloyd(centers, pts, k, zeros):
+    """One Lloyd iteration; module-level and handed everything it
+    reads (``invariants=``), so a later ``k_means`` call over points of
+    the same shape rebinds the tape the first one captured."""
+    sums = pts.Map(Bind(_label, centers)).ReduceToIndex(
+        _cluster_i, _CLUSTER_SUM, k,
+        neutral={"i": 0, "x": zeros, "cnt": 0.0})
+    return sums.ZipWithIndex(_with_row).Map(
+        Bind(_center_update, centers)).AllGatherArrays()
 
 
 def k_means(ctx: Context, points: np.ndarray, k: int, iterations: int = 10,
             seed: int = 0):
-    """points: [n, dim] float64. Returns (centers [k, dim], labels DIA)."""
-    from thrill_tpu.api import Bind
+    """points: [n, dim] float64. Returns the centers [k, dim]."""
+    import jax.numpy as jnp
 
     n, dim = points.shape
     rng = np.random.default_rng(seed)
     centers = points[rng.choice(n, size=k, replace=False)].copy()
 
     pts = ctx.Distribute(points.astype(np.float64)).Cache() \
-        .Keep(2 * iterations + 1)
+        .Keep(iterations + 1)
 
     # The Lloyd loop stays entirely in jax's async dispatch stream:
-    # AllGatherArrays returns the per-cluster sums as DEVICE arrays,
-    # the centroid update runs as a small cached program, and the
-    # updated centers re-enter the classify program through Bind
-    # (device operands pass straight through). Zero blocking host
-    # syncs per iteration; the reference's AllReduce/broadcast step
-    # (k-means.hpp:176-259) is host-side and has no such cost.
+    # AllGatherArrays returns the new centers as DEVICE arrays and they
+    # re-enter the classify program through Bind (device operands pass
+    # straight through). Zero blocking host syncs per iteration; the
+    # reference's AllReduce/broadcast step (k-means.hpp:176-259) is
+    # host-side and has no such cost.
     #
     # The loop is driven by the iteration layer (api/loop.py): every
-    # device step of the body — classify+reduce, columnar egress,
-    # centroid update — is a recordable dispatch, so iterations 2..N
-    # replay a captured LoopPlan (and, the body being exchange-free at
-    # W=1, lower into one whole-loop fori_loop dispatch) instead of
-    # rebuilding the DIA graph per iteration.
-    from thrill_tpu.api import Iterate
-    import jax.numpy as jnp
-    red = _cluster_sum()
-    update = ctx.mesh_exec.jit_cached(("kmeans_center_update",),
-                                      _center_update)
-
-    def body(centers):
-        labeled = pts.Map(Bind(_label, centers))
-        sums = labeled.ReduceToIndex(
-            _cluster_i, red,
-            k, neutral={"i": 0, "x": np.zeros(dim), "cnt": 0.0})
-        cols = sums.AllGatherArrays()
-        return update(cols["x"], cols["cnt"], centers)
-
-    centers = Iterate(ctx, body, jnp.asarray(centers), iterations,
-                      name="k_means")
+    # device step of the body is a recordable dispatch, so iterations
+    # 2..N replay a captured LoopPlan (and, the body being
+    # exchange-free at W=1, lower into one whole-loop fori_loop
+    # dispatch) instead of rebuilding the DIA graph per iteration.
+    centers = Iterate(ctx, _lloyd, jnp.asarray(centers), iterations,
+                      name="k_means",
+                      invariants=(pts, k, np.zeros(dim)))
+    pts.Dispose()
     return np.asarray(centers)
 
 

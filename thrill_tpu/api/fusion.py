@@ -186,6 +186,7 @@ _INDEX_PLANS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 def note_index_plans(fn, info: IndexPlans) -> None:
     _INDEX_PLANS[fn.raw] = info
+    fn.index_plans = info.count     # counted by every dispatch of it
 
 
 def index_plans(fn) -> Optional[IndexPlans]:
@@ -471,9 +472,6 @@ class FusionPlan:
                              predicted=pred, reason=why,
                              ops=ops_label, n_ops=len(segs),
                              dia_ids=[s.dia_id for s in segs])
-        plans = index_plans(fn)
-        if plans is not None:
-            mex.stats_r2i_index_plans += plans.count
         try:
             out = fn(*args)
         except Exception as e:

@@ -810,7 +810,6 @@ class LoopPlan:
         self._pro = pro = {}
         for p, call in enumerate(self.prologue):
             out = call.fn(*[self._resolve(ref) for ref in call.arg_refs])
-            self._count_index_plans(call)
             for j, o in enumerate(jax.tree.leaves(out)):
                 pro[(p, j)] = o
 
@@ -843,18 +842,10 @@ class LoopPlan:
                     mex.stats_loop_donated_bytes += sum(
                         getattr(args[p], "nbytes", 0) for p in pos)
             out = fn(*args)
-            self._count_index_plans(call)
             for j, o in enumerate(jax.tree.leaves(out)):
                 if (i, j) in self.used_outputs:
                     vals[(i, j)] = o
         return [self._resolve(ref, carry, vals) for ref in self.carry_out]
-
-    def _count_index_plans(self, call: "_Call", runs: int = 1) -> None:
-        """``r2i_index_plans``: the index plans that ``runs`` runs of a
-        recorded call compute in place (api/fusion.py)."""
-        plans = fusion.index_plans(call.fn)
-        if plans is not None:
-            self.mex.stats_r2i_index_plans += plans.count * runs
 
     # -- whole-loop fori_loop lowering ---------------------------------
     def fori_eligible(self) -> bool:
@@ -873,10 +864,13 @@ class LoopPlan:
                      for ref in _leaf_refs(c.arg_refs)
                      if ref[0] in _OPERAND_KINDS)
 
-    def run_fori(self, carry: List[Any], k: int) -> Optional[List[Any]]:
+    def run_fori(self, carry: List[Any],
+                 k: int) -> Optional[Tuple[List[Any], int]]:
         """Lower the remaining ``k`` iterations into ONE jitted
         ``lax.fori_loop`` dispatch over the whole tape, or return None
-        when the body cannot be lowered (version/topology limits).
+        when the body cannot be lowered (version/topology limits);
+        else the next carry and the index plans the dispatch computed
+        (its ``loop`` span says so, its ``dispatch`` span cannot).
 
         The incoming carry is never donated here: fori only ever runs
         as the FIRST replay after a (re)capture, whose carry buffers
@@ -1023,10 +1017,12 @@ class LoopPlan:
             self._fori = fn
         # the dispatch counter ticks inside _CountedJit.__call__ now
         out = self._fori(tuple(carry), self._fori_consts(), np.int32(k))
-        for i, c in enumerate(calls):
-            # in every iteration, or once where they are hoisted
-            self._count_index_plans(c, 1 if i in hoisted else k)
-        return list(out)
+        # the calls' plans ran in every iteration, or once where they
+        # are hoisted; the whole-loop program's own dispatch counts none
+        index_plans = sum(c.fn.index_plans * (1 if i in hoisted else k)
+                          for i, c in enumerate(calls))
+        self.mex.stats_r2i_index_plans += index_plans
+        return list(out), index_plans
 
 
 # ----------------------------------------------------------------------
@@ -1051,6 +1047,20 @@ class _LoopCarryNode(DIABase):
 
 def _carry_dia(ctx, shards, pipe: Optional[int] = None) -> DIA:
     return DIA(_LoopCarryNode(ctx, shards, pipe))
+
+
+def _place_carry_leaf(mex, leaf):
+    """Where a leaf of a pytree carry lives: replicated over the mesh,
+    as the programs of an iteration hand it back (``AllGatherArrays``
+    under a recorder, a whole-loop program). A carry that came in on
+    one device, or from the host, would make the call that runs the
+    whole-loop program with it another compile than the warm one. A
+    host leaf is a counted upload."""
+    if mex.num_processes > 1:
+        return jnp.asarray(leaf)
+    if isinstance(leaf, jax.Array):
+        return jax.device_put(leaf, mex.replicated)
+    return mex._put_replicated(np.asarray(leaf))
 
 
 def _shards_carry_ids(shards: DeviceShards) -> Tuple[Dict[int, int], int]:
@@ -1112,12 +1122,21 @@ def Iterate(ctx, body: Callable, carry, n: int, *, name: str = "loop",
     method, a ``functools.partial``, a callable object or a function
     made by a factory captures in every call, as before.
 
-    The whole loop runs under one ``stage`` span named ``Iterate`` of
-    the carry's pipeline, the root of its ``loop`` spans."""
+    The whole loop runs under one ``stage`` span named ``Iterate``,
+    the root of its ``loop`` spans, of the carry's pipeline; a pytree
+    carry has none, and the loop joins that of its first invariant
+    DIA."""
     if n <= 0:
         return carry
     node = carry.node if isinstance(carry, DIA) \
         else carry if isinstance(carry, DIABase) else None
+    if node is None:
+        # a carry that is no DIA belongs to no pipeline: the loop joins
+        # that of its first invariant DIA, which its body reads in
+        # every iteration
+        node = next((x.node if isinstance(x, DIA) else x
+                     for x in invariants
+                     if isinstance(x, (DIA, DIABase))), None)
     if node is not None:
         span = node.stage_span("Iterate")
         pipe = node.pipe
@@ -1145,7 +1164,7 @@ def _iterate(ctx, body, carry, n, name, checkpoint_every, invariants,
         dia_mode = True
         state = carry
     else:
-        state = jax.tree.map(jnp.asarray, carry)
+        state = jax.tree.map(lambda l: _place_carry_leaf(mex, l), carry)
 
     if checkpoint_every and not dia_mode:
         # sealing requires the shard-file epoch path (DIA/DeviceShards
@@ -1344,8 +1363,9 @@ def _iterate(ctx, body, carry, n, name, checkpoint_every, invariants,
                     if faults.REGISTRY.active():
                         faults.check(_F_REPLAY, loop=name, iter=i)
                     if fori_ok:
-                        out = plan.run_fori(leaves, remaining)
-                        if out is not None:
+                        ran = plan.run_fori(leaves, remaining)
+                        if ran is not None:
+                            out, index_plans = ran
                             mex.stats_loop_fori_iters += remaining
                             report["fori_iters"] += remaining
                             state = _rebuild_carry(out, treedef, dia_mode,
@@ -1354,6 +1374,7 @@ def _iterate(ctx, body, carry, n, name, checkpoint_every, invariants,
                             report["replay_s"] += dt
                             if sp is not None:
                                 sp.attrs["fori_iters"] = remaining
+                                sp.attrs["index_plans"] = index_plans
                             if log.enabled:
                                 log.line(event="loop_replay", loop=name,
                                          iter=i, iters=remaining, fori=True,

@@ -1129,10 +1129,9 @@ class ReduceToIndexNode(DIABase):
 
         fn = mex.cached(key, build)
         if sc is not None and _wants_sorted_fold(sc, leaves):
-            # the plan in place; a tape that replays this call counts it
+            # the plan in place: every dispatch of ``fn`` counts it
             from .. import fusion
             fusion.note_index_plans(fn, fusion.IndexPlans(count=1))
-            mex.stats_r2i_index_plans += 1
         rs = mex.put_small(bounds[:W].astype(np.int64)[:, None])
         rsz = mex.put_small(local_sizes[:, None])
         out = fn(shards.counts_device(), rs, rsz, *leaves)

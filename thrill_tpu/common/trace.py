@@ -34,8 +34,12 @@ Category / name; site; read by:
   ``shape``, ``dtype``; ``upload_s_per_job``, ``upload_bytes_per_job``.
 * ``dispatch`` / program label; ``parallel/mesh.py
   _CountedJit.__call__``, every device dispatch, the whole-loop fori
-  program included; ``dispatch_call_s_per_job``,
-  tests/common/test_trace.py.
+  program included, with ``index_plans``: the ``ReduceToIndex`` index
+  plans the program computes in place (``api/fusion.py
+  note_index_plans``; 0 on most, and 0 on the whole-loop program, whose
+  plans its ``loop`` / ``replay`` span counts);
+  ``dispatch_call_s_per_job``, ``index_plans_per_job``,
+  tests/common/test_trace.py, tests/api/test_loop_tree_carry.py.
 * ``compile`` / program label; ``parallel/mesh.py _on_jax_duration``
   (``jax.monitoring``), a backend compile or cache load under a
   dispatch, by ``emit_span``; ``compile_s_in_window``, and taken out of
@@ -59,21 +63,26 @@ Category / name; site; read by:
 * ``mem`` / ladder rung (instants); ``mem/pressure.py``,
   ``api/fusion.py`` degradations; tests/common/test_trace.py (lane).
 * ``stage`` / ``Iterate``; ``api/loop.py Iterate``, the root of a
-  loop: of the carry DIA's pipeline (``pipe``, ``dia_id``; a pytree
-  carry has neither), around the carry's first pull and every
-  iteration; ``_LoopCarryNode`` and the carry rebuilt after a replay
-  join that pipeline instead of starting one, so a job that loops is
-  ONE pipeline; ``host_plan_s_per_job`` (self time) and the window
-  rule, tests/api/test_loop_spans.py.
+  loop: of the carry DIA's pipeline (``pipe``, ``dia_id``), or, the
+  carry being a pytree of arrays, of its first invariant DIA's (a
+  pytree carry with no DIA among the invariants has neither), around
+  the carry's first pull and every iteration; ``_LoopCarryNode`` and
+  the carry rebuilt after a replay join that pipeline instead of
+  starting one, so a job that loops is ONE pipeline;
+  ``host_plan_s_per_job`` (self time) and the window rule,
+  tests/api/test_loop_spans.py, tests/api/test_loop_tree_carry.py.
 * ``loop`` / ``capture`` (one iteration through the pull recursion,
   captured or plain, ``mode``), ``replay`` (one iteration off the
-  tape, or ``fori_iters`` of them in one whole-loop dispatch;
-  ``error`` where it fell back), ``rebind`` (a call that took over a
-  kept tape: its prologue, ``calls``); ``api/loop.py``, children of
-  ``stage`` / ``Iterate``, parents of an iteration's dispatches, waits,
-  fetches and stages; ``loop_host_s_per_job`` (self time),
+  tape, or ``fori_iters`` of them in one whole-loop dispatch, then
+  with ``index_plans``: the plans that dispatch computed, once where
+  they are hoisted ahead of the iterations, else in each; ``error``
+  where it fell back), ``rebind`` (a call that took over a kept tape:
+  its prologue, ``calls``); ``api/loop.py``, children of ``stage`` /
+  ``Iterate``, parents of an iteration's dispatches, waits, fetches
+  and stages; ``loop_host_s_per_job`` (self time),
   ``loop_captures_in_window``, ``iterations_replayed_share``
-  (``chipbench/loop_window.py``), tests/api/test_loop_spans.py,
+  (``chipbench/loop_window.py``), ``index_plans_per_job``,
+  tests/api/test_loop_spans.py, tests/api/test_loop_tree_carry.py,
   tests/common/test_trace.py (lane).
 * ``service`` / ``queue_wait`` (``emit_span``), ``job:<name>``;
   ``service/scheduler.py``; tests/common/test_trace.py.

@@ -153,6 +153,11 @@ class _CountedJit:
         # unknown names to the jitted function, so it must exist here)
         self._adm_est: Optional[Tuple[int, int]] = None
         self._donate_base: Optional["_CountedJit"] = None
+        # ReduceToIndex index plans that one run of this program
+        # computes in place (api/fusion.py note_index_plans): every
+        # dispatch adds them to ``r2i_index_plans`` and says so on its
+        # ``dispatch`` span
+        self.index_plans = 0
         # the name the jitted callable carries (module ``jit_<label>``
         # on the device plane) and every host span of this program
         self._trace_label: Optional[str] = label
@@ -170,12 +175,14 @@ class _CountedJit:
         tr = self._mex.tracer
         if tr is None or not tr.enabled:
             return self._dispatch(args, kwargs)
-        with tr.span("dispatch", self._label()):
+        with tr.span("dispatch", self._label(),
+                     index_plans=self.index_plans):
             return self._dispatch(args, kwargs)
 
     def _dispatch(self, args, kwargs):
         mex = self._mex
         mex.stats_dispatches += 1
+        mex.stats_r2i_index_plans += self.index_plans
         pres = mex.pressure
         if pres is not None and pres.enabled:
             # rung 1, admission control: estimate this dispatch's
@@ -266,6 +273,7 @@ class _CountedJit:
             # donating dispatch through THIS base so the retry never
             # re-donates buffers the failed attempt may have consumed
             fn._donate_base = self
+            fn.index_plans = self.index_plans
             self._donating[donate_argnums] = fn
         return fn
 
