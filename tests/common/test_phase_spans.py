@@ -393,10 +393,11 @@ def test_named_scopes_are_in_the_programs_metadata():
     try:
         mex = ctx.mesh_exec
         ship = mex.smap(lambda x: exchange.ship_blocks(
-            x[0], jnp.arange(4), 2, 2)[None], 1)
+            x[0], jnp.array([0, 2]), jnp.array([2, 2]), 2, 2)[None], 1)
         text = ship.lower(mex.put(np.zeros((2, 4), np.int32))) \
             .as_text(debug_info=True)
         assert f'"{exchange.SCOPE}/all_to_all"' in text
+        assert f"{exchange.SCOPE}/send_slice/" in text
     finally:
         ctx.close()
 

@@ -935,6 +935,9 @@ class Context:
             "exchanges_overlapped": mex.stats_exchanges_overlapped,
             "cap_cache_hits": mex.stats_cap_cache_hits,
             "cap_cache_misses": mex.stats_cap_cache_misses,
+            # send blocks cut as slices of dest-sorted rows by the
+            # dispatched exchange programs (data/exchange.py)
+            "xchg_send_slices": mex.stats_xchg_send_slices,
             "bytes_wire_device": mex.stats_bytes_wire_device,
             "bytes_wire_host": mex.stats_bytes_wire_host,
             "bytes_on_wire": (mex.stats_bytes_wire_device

@@ -903,7 +903,7 @@ def _device_sample_sort(shards: DeviceShards, key_fn: Callable,
     if fused_ok and exchange.dense_all_to_all_applies(
             mex, S, exchange.leaf_item_bytes(sorted_payload)
             + 8 * (nwords + 1)):
-        return _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
+        return _fused_exchange_merge(mex, words_mat, gidx_s,
                                      sorted_payload, treedef, S, nwords,
                                      token)
 
@@ -986,11 +986,12 @@ def _host_radix_w1(mex, shards: DeviceShards, key_fn, leaves, treedef,
     return DeviceShards(mex, tree_out, shards.counts.copy())
 
 
-def _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
+def _fused_exchange_merge(mex, words_mat, gidx_s,
                           sorted_payload, treedef, S: np.ndarray,
                           nwords: int, token) -> DeviceShards:
-    """Phase 2.5+3 fused: scatter sends, all_to_all, then MERGE the W
-    received runs — one jitted program, one payload gather.
+    """Phase 2.5+3 fused: slice the send blocks out of the key-sorted
+    rows, all_to_all, then MERGE the W received runs — one jitted
+    program, one payload gather, no scatter.
 
     The received blocks land rank-ordered at static ``M_pad`` run
     boundaries, each run internally sorted by (key words, global index)
@@ -1005,7 +1006,7 @@ def _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
     from ...core.device_sort import (choose_engine, merge_sorted_runs,
                                      prepare_sort_words)
     W = mex.num_workers
-    cap = sorted_dest.shape[1]
+    cap = words_mat.shape[1]
     R = S.sum(axis=0)
     new_counts = R.astype(np.int64)
 
@@ -1033,15 +1034,16 @@ def _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
            tuple((l.dtype, l.shape[2:]) for l in sorted_payload))
 
     def build():
-        def f(sdest, srow, scol, wm_a, gi_a, *ls):
+        def f(srow, scol, wm_a, gi_a, *ls):
             from ...core import rowmove
-            d = sdest[0]
             S_row = srow[0]
             S_col = scol[0]
-            send_idx = exchange.send_slot_index(d, S_row, W, M_pad, cap)
+            # key-sorted rows are grouped by destination, valid first:
+            # destination d's block is rows off[d] .. off[d]+S_row[d]-1
+            off = exchange._ex_cumsum(S_row)
 
             def ship(x):
-                return exchange.ship_blocks(x, send_idx, W, M_pad)
+                return exchange.ship_blocks(x, off, S_row, W, M_pad)
 
             wm_r = ship(wm_a[0])                  # [W*M_pad, nwords]
             gi_r = ship(gi_a[0])                  # [W*M_pad]
@@ -1094,16 +1096,19 @@ def _fused_exchange_merge(mex, sorted_dest, words_mat, gidx_s,
                     rowmove.unpack_rows(jnp.take(p, perm, axis=0), m)[None]
                     for p, m in zip(payload_r, pmetas))
 
-        return mex.smap(f, 5 + len(sorted_payload))
+        return mex.smap(f, 4 + len(sorted_payload))
 
     fb = mex.cached(key, build)
     srow = mex.put_small(S.astype(np.int32))
     scol = mex.put_small(S.T.copy().astype(np.int32))
     from ...common import trace as _trace
+    # W send blocks per shipped leaf: key words, index, the payload's
+    send_slices = W * (2 + len(sorted_payload))
+    mex.stats_xchg_send_slices += send_slices
     with _trace.span_of(getattr(mex, "tracer", None), "exchange",
-                        "sort_fused", m_pad=M_pad, out_cap=out_cap):
-        out = fb(sorted_dest, srow, scol, words_mat, gidx_s,
-                 *sorted_payload)
+                        "sort_fused", m_pad=M_pad, out_cap=out_cap,
+                        send_slices=send_slices):
+        out = fb(srow, scol, words_mat, gidx_s, *sorted_payload)
     tree = jax.tree.unflatten(treedef, list(out))
     return DeviceShards(mex, tree, new_counts)
 

@@ -55,8 +55,11 @@ Category / name; site; read by:
   stitched launch; ``host_plan_s_per_job`` (self time).
 * ``exchange`` / ``phase_a``, ``phase_b``, ``optimistic`` (with a verdict
   instant), ``synced``, ``sort_fused``; ``data/exchange.py``,
-  ``api/ops/sort.py``; ``host_plan_s_per_job`` (self time),
-  tests/common/test_doctor.py, test_trace.py (the flight dump names it).
+  ``api/ops/sort.py``; ``phase_b`` and ``sort_fused`` carry
+  ``send_slices``, the send blocks their programs cut as slices (what
+  ``xchg_send_slices`` counts); ``host_plan_s_per_job`` (self time),
+  tests/common/test_doctor.py, test_trace.py (the flight dump names it),
+  tests/data/test_exchange_send_slice.py (the field).
 * ``plan`` / decision kind (instants); ``common/decisions.py`` (each
   ledger record and audit), ``common/doctor.py`` (skew verdict);
   tests/common/test_doctor.py.

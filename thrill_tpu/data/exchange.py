@@ -12,7 +12,8 @@ bulk-synchronous exchange of columnar shards over the ICI mesh:
   Host step: agree on padded block capacity from the [W, W] send-count
       matrix (tiny transfer; shapes must be static for XLA). Capacities
       round up to powers of two so recompilation is rare.
-  Phase B (jit): scatter into [W, M] padded per-destination blocks,
+  Phase B (jit): cut the dest-sorted rows into [W, M] padded
+      per-destination blocks (slices: the send side scatters nothing),
       ``lax.all_to_all`` over the mesh, compact received blocks into a
       fresh [out_cap] shard -> the analog of Multiplexer block transit +
       receive-side BlockQueues.
@@ -34,7 +35,7 @@ Overlapped data plane (the MixStream-analog dispatch discipline):
   Every output row is written by exactly one chunk at the exact position
   the bulk program would use, so results are bit-identical for any K;
   jax's async dispatch keeps chunk i's ``all_to_all`` + compaction in
-  flight while chunk i+1 is scattered, and the consumer's next program
+  flight while chunk i+1's blocks are cut, and the consumer's next program
   can be enqueued before the last chunks land. ``THRILL_TPU_XCHG_CHUNKS``
   forces K; the auto policy chunks only volumes worth pipelining.
 * The mid-shuffle host sync on the [W, W] send matrix is ELIDED in
@@ -72,9 +73,9 @@ from ..parallel.mesh import AXIS, MeshExec
 from .shards import DeviceShards
 
 # the name the exchange's device work carries in a device profile (the
-# scatter into per-destination blocks and the collective; jax.named_scope:
-# HLO metadata, no operation added), beside core/device_sort.py's and
-# core/rowmove.py's
+# per-destination send blocks, under SCOPE/send_slice, and the
+# collective; jax.named_scope: HLO metadata, no operation added), beside
+# core/device_sort.py's and core/rowmove.py's
 SCOPE = "exchange"
 
 # per-chunk injection at the chunked phase-B dispatch loop: fires
@@ -479,28 +480,46 @@ def resolve_mode(mex: MeshExec) -> str:
     return env or getattr(mex, "exchange_mode", "dense")
 
 
-def send_slot_index(dest, S_row, W: int, M_pad: int, cap: int):
-    """Traced helper: flat [W*M_pad] send-buffer position per item
-    (dump row W*M_pad for invalid), given dest-sorted destinations and
-    this worker's send-count row."""
-    off = _ex_cumsum(S_row)
-    dc = jnp.clip(dest, 0, W - 1)
-    slot = jnp.arange(cap) - jnp.take(off, dc)
-    return jnp.where(dest < W, dc * M_pad + slot, W * M_pad)
+@jax.named_scope("send_slice")
+def send_slice(x, starts, counts, M: int):
+    """Traced helper (call it under ``SCOPE``): the send blocks of one
+    leaf, cut out of its dest-grouped rows. ``x`` is [cap, ...];
+    block ``b`` of the returned [B, M, ...] holds rows ``starts[b] ..
+    starts[b] + M - 1`` of ``x`` with rows ``j >= counts[b]`` zeroed.
+    Nothing is scattered: a block is a copy of a row range.
+
+    The zero rows are part of the contract (the fused sort's merge
+    reads the key words of a received block's unused rows as zeros).
+    ``starts[b] + M`` may pass ``cap`` — ``M`` is sticky and up to
+    twice the mean block — and ``lax.dynamic_slice`` would clamp such
+    a start, shifting the data: the slices read through ``M`` zero
+    rows kept behind ``x``, which no start with ``counts[b] > 0`` can
+    pass (such a block ends inside ``x``). A block longer than ``M``
+    gives its first ``M`` rows; the caller's overflow flag decides."""
+    trail = x.shape[1:]
+    xp = jnp.concatenate([x, jnp.zeros((M,) + trail, x.dtype)])
+    j = jnp.arange(M).reshape((M,) + (1,) * len(trail))
+    zero = jnp.zeros((), x.dtype)
+    return jnp.stack([
+        jnp.where(j < counts[b],
+                  lax.dynamic_slice_in_dim(xp, starts[b], M, axis=0),
+                  zero)
+        for b in range(starts.shape[0])])
 
 
 @jax.named_scope(SCOPE)
-def ship_blocks(x, send_idx, W: int, M_pad: int):
-    """Traced helper: scatter one leaf into [W, M_pad] padded
-    per-destination blocks and all_to_all them; returns the received
-    [W*M_pad, ...] rank-ordered runs (run w = source w's items)."""
-    trail = x.shape[1:]
-    buf = jnp.zeros((W * M_pad + 1,) + trail, x.dtype)
-    buf = buf.at[send_idx].set(x)
-    blocks = buf[:W * M_pad].reshape((W, M_pad) + trail)
+def ship_blocks(x, off, n_send, W: int, M: int):
+    """Traced helper: cut one leaf into [W, M] padded per-destination
+    blocks (:func:`send_slice`: destination ``d``'s block is rows
+    ``off[d] .. off[d] + n_send[d] - 1`` of ``x``, zeros behind) and
+    all_to_all them; returns the received [W*M, ...] rank-ordered runs
+    (run w = source w's items). The send side scatters nothing: ``x``
+    must hold each destination's rows contiguously, in order, at
+    ``off[d]`` (the contract of :func:`exchange_presorted`)."""
+    blocks = send_slice(x, off, n_send, M)
     recv = lax.all_to_all(blocks, AXIS, split_axis=0,
                           concat_axis=0, tiled=True)
-    return recv.reshape((W * M_pad,) + trail)
+    return recv.reshape((W * M,) + x.shape[1:])
 
 
 def send_counts(dest: jnp.ndarray, W: int) -> jnp.ndarray:
@@ -522,11 +541,18 @@ def exchange_presorted(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
     Public entry for operators whose upstream order makes destinations
     monotone (Sort: items are key-sorted, so splitter rank never
     decreases) — they skip the generic phase-A destination sort
-    entirely. Contract: ``sorted_dest`` is [W, cap] int32 with each
-    worker's valid items contiguous per destination in rank order
-    (monotone suffices) and W marking invalid slots; ``sorted_leaves``
-    are [W, cap, ...] in that same order; ``S[w, d]`` counts w's items
-    bound for d (as produced by ``send_counts``). ``ranges`` ([L, 2]
+    entirely. Contract, exactly: with ``off = exclusive cumsum of
+    S[w]``, worker w's valid items bound for destination d are rows
+    ``off[d] .. off[d] + S[w, d] - 1`` of its leaves, in the order they
+    are to arrive; every invalid slot lies behind them (rows
+    ``sum(S[w])`` and up). ``sorted_dest`` is [W, cap] int32, monotone
+    over the valid rows, with W marking invalid slots;
+    ``sorted_leaves`` are [W, cap, ...] in that same order; ``S[w, d]``
+    counts w's items bound for d (as produced by ``send_counts``). The
+    send side of every plan (dense chunks, 1-factor rounds, ragged)
+    cuts destination d's block out of the leaves as that row range and
+    scatters nothing, so a valid row outside its range is lost, not
+    repaired. ``ranges`` ([L, 2]
     int64 over the narrowable leaves, see
     :func:`presorted_range_leaves`) opts the call into phase-B row
     narrowing — presorted callers compute it inside their own phase-A
@@ -1099,7 +1125,7 @@ def _dispatch_chunked(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
     bit-identical. Each chunk is its own ``_CountedJit`` dispatch, so
     admission control, the OOM-retry ladder and dispatch stats cover
     every chunk, and jax async dispatch pipelines chunk i's collective
-    with chunk i+1's scatter. The FIRST chunk additionally returns the
+    with chunk i+1's block cutting. The FIRST chunk additionally returns the
     device-resident output counts and the capacity-overflow flag (both
     functions of ``smat`` alone), so the optimistic path's deferred
     check blocks only until chunk 0 lands — chunks 1..K-1 and the
@@ -1171,15 +1197,11 @@ def _dispatch_chunked(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
                 widx = lax.axis_index(AXIS)
                 S_row = jnp.take(smat_a, widx, axis=0).astype(jnp.int32)
                 S_col = jnp.take(smat_a, widx, axis=1).astype(jnp.int32)
-                off = _ex_cumsum(S_row)
                 roff = _ex_cumsum(S_col)
-                d = sdest[0]
-                i = jnp.arange(cap)
-                dc = jnp.clip(d, 0, W - 1)
-                slot = i - jnp.take(off, dc)
-                sel = (d < W) & (slot >= lo) & (slot < hi)
-                send_idx = jnp.where(sel, dc * M_j + (slot - lo),
-                                     W * M_j)
+                # the window lo:hi of a destination's block starts at
+                # row off[dest] + lo of the dest-sorted leaf
+                start = _ex_cumsum(S_row) + lo
+                n_send = jnp.clip(S_row - lo, 0, M_j)
                 jj = jnp.arange(M_j)
                 n_from = jnp.clip(S_col - lo, 0, M_j)
                 pos = jnp.where(jj[None, :] < n_from[:, None],
@@ -1200,7 +1222,7 @@ def _dispatch_chunked(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
                     if nd is not None:
                         if first:
                             info = np.iinfo(np.dtype(nd))
-                            v = d < W
+                            v = sdest[0] < W
                             vm = v.reshape((-1,) + (1,)
                                            * (xw.ndim - 1))
                             oob = vm & ((xw < info.min)
@@ -1211,7 +1233,7 @@ def _dispatch_chunked(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
                         xw = xw.astype(np.dtype(nd))
                     x, m = rowmove.pack_rows(xw) if pack \
                         else (xw, None)
-                    recv = ship_blocks(x, send_idx, W, M_j)
+                    recv = ship_blocks(x, start, n_send, W, M_j)
                     if first:
                         acc = jnp.zeros((out_cap + 1,) + x.shape[1:],
                                         x.dtype)
@@ -1261,8 +1283,12 @@ def _dispatch_chunked(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
     acc_pos = tuple(range(2 + n_leaves, 2 + 2 * n_leaves))
     counts_dev = flag = None
     accs: List[Any] = []
+    # every chunk program cuts W send blocks per leaf (send_slice)
+    send_slices = len(ranges) * W * n_leaves
+    mex.stats_xchg_send_slices += send_slices
     with _trace.span_of(getattr(mex, "tracer", None), "exchange",
                         "phase_b", chunks=len(ranges),
+                        send_slices=send_slices,
                         narrowed=narrow is not None or None):
         for j, (lo, hi) in enumerate(ranges):
             first, last = j == 0, j == len(ranges) - 1
@@ -1594,19 +1620,18 @@ def _exchange_onefactor(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
                 inv[to] = np.arange(W)
                 d_r = jnp.take(jnp.asarray(to), widx)   # partner I send to
                 s_r = jnp.take(jnp.asarray(inv), widx)  # partner I recv from
-                sel = d == d_r
-                slot = i - jnp.take(off, d_r)
                 M_r = M_rounds[r]
-                send_idx = jnp.where(sel, slot, M_r)
+                # my partner's block, cut out of the dest-sorted rows
+                start = jnp.take(off, d_r)[None]
+                n_send = jnp.take(S_row, d_r)[None]
                 perm = [(w, int(to[w])) for w in range(W)]
                 j = jnp.arange(M_r)
                 n_recv = jnp.take(S_col, s_r)
                 pos = jnp.where(j < n_recv, jnp.take(roff, s_r) + j,
                                 out_cap)
                 for li, x in enumerate(xs):
-                    buf = jnp.zeros((M_r + 1,) + x.shape[1:], x.dtype)
-                    buf = buf.at[send_idx].set(x)[:M_r]
                     with jax.named_scope(SCOPE):
+                        buf = send_slice(x, start, n_send, M_r)[0]
                         recv = lax.ppermute(buf, AXIS, perm=perm)
                     outs[li] = outs[li].at[pos].set(recv)
             res = []
@@ -1622,6 +1647,8 @@ def _exchange_onefactor(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
     fb = mex.cached(key_b, build_b)
     srow = mex.put_small(S.astype(np.int32))
     scol = mex.put_small(S.T.copy().astype(np.int32))
+    # one send block per leaf and round (send_slice)
+    mex.stats_xchg_send_slices += len(rounds) * len(sorted_leaves)
     out_leaves = list(fb(sorted_dest, srow, scol, *sorted_leaves))
     tree = jax.tree.unflatten(treedef, out_leaves)
     return DeviceShards(mex, tree, new_counts)

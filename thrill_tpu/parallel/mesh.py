@@ -326,6 +326,11 @@ class MeshExec:
         # donate_argnums on the chunk accumulator — 0 on CPU where
         # aliasing is never real, >0 on TPU where the HBM reuse pays
         self.stats_xchg_donated = 0
+        # send blocks the dispatched exchange programs cut out of their
+        # dest-sorted rows as slices (data/exchange.py send_slice): W x
+        # shipped leaves per dense program (a chunk, Sort's fused
+        # exchange-merge), one x leaves per 1-factor round
+        self.stats_xchg_send_slices = 0
         # per-exchange-site plan kind ('dense' = optimistic-eligible,
         # 'sync' = the site needs the host plan step every time); the
         # capacity values themselves live in _sticky_caps
