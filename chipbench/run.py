@@ -23,6 +23,7 @@ _PROCESS_START = time.perf_counter()
 import argparse
 import importlib.util
 import json
+import numbers
 import os
 import shutil
 import sys
@@ -31,6 +32,8 @@ import traceback
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the counters a run PRINTS (standard error, a rehearsal's ``counts``);
+# readers get every counter the program has, by the program's own names
 STAT_KEYS = ("device_dispatches", "device_uploads", "device_fetches",
              "exchanges", "bytes_moved", "oom_retries", "segment_splits",
              "host_fallbacks", "admission_spills", "hbm_spills")
@@ -80,6 +83,16 @@ def load_cell(bench: dict, name: str) -> dict:
 def metrics_of(bench: dict, group: str, cell: str) -> list:
     return [m for m in bench[group]
             if cell in m.get("workloads", [cell])]
+
+
+def stat_deltas(before: dict, after: dict) -> dict:
+    """What every numeric key of ``ctx.overall_stats()`` gained over the
+    window. A key that one end lacks (a counter this commit's program
+    does not have) is left out: its reader finds nothing to read."""
+    def number(v):
+        return isinstance(v, numbers.Real) and not isinstance(v, bool)
+    return {k: after[k] - before[k] for k in after
+            if k in before and number(after[k]) and number(before[k])}
 
 
 def sampled_jobs(seed: int, traffic: dict) -> set:
@@ -285,10 +298,11 @@ def main(argv=None) -> int:
         stats1 = ctx.overall_stats()
         out["compiles"] = compiles[0] - compiles0
         out["memory"] = memory_stats(mex.devices)
-        out["stats"] = {k: stats1[k] - stats0[k] for k in STAT_KEYS}
+        out["stats"] = stat_deltas(stats0, stats1)
+        out["counts"] = {k: out["stats"][k] for k in STAT_KEYS}
         out["window"] = window
         say("stats over the window: " + " ".join(
-            f"{k}={v}" for k, v in out["stats"].items()))
+            f"{k}={v}" for k, v in out["counts"].items()))
         say("job seconds: " + " ".join(f"{s:.3f}" for s in window.seconds))
         # the window has closed and the peak has been read: only now is a
         # result brought to the host
@@ -344,7 +358,8 @@ def main(argv=None) -> int:
         if not args.keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
         run = {"trace": summary, "stats": out["stats"], "memory": memory,
-               "jobs": done, "compiles": out["compiles"], "cell": cell,
+               "jobs": done, "job_seconds": window.seconds,
+               "compiles": out["compiles"], "cell": cell,
                "traffic": traffic, "config": config,
                "peaks": peaks.get(devices[0].device_kind),
                "min_bytes": job.min_bytes(traffic, config, want)}
@@ -364,7 +379,6 @@ def main(argv=None) -> int:
         values = {
             "records_per_s": done * n_records / window.elapsed
             if done else None,
-            "job_s_slowest": max(window.seconds) if done else None,
             "setup_s": out["setup_s"],
         }
         for m in metrics_of(bench, "end_to_end", cell["name"]):
@@ -382,7 +396,7 @@ def main(argv=None) -> int:
         print(json.dumps({"rehearsal": True, "workload": cell["name"],
                           "correct": correct, "attempted": window.attempted,
                           "failed": window.failed, "check": check,
-                          "counts": out["stats"],
+                          "counts": out["counts"],
                           "reported": sorted(result["metrics"])}),
               flush=True)
         return 0

@@ -4,11 +4,9 @@ the contract's rules, and ``run.py`` end to end in a rehearsal -- with the
 control and with the timed path broken underneath, where ``correct`` has
 to come out false. No number here is a device number."""
 
-import glob
 import importlib.util
 import json
 import os
-import re
 import shutil
 import subprocess
 import sys
@@ -19,9 +17,6 @@ import pytest
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _BENCH = os.path.join(_ROOT, "chipbench")
-_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-_UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-_SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
 def _load(path, name):
@@ -29,6 +24,13 @@ def _load(path, name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+# the rules on the data files, as functions of a root directory:
+# test_append_only.py holds a copy with entries added to the same code
+contract = _load(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "bench_contract.py"),
+                 "chipbench_bench_contract")
 
 
 @pytest.fixture(scope="module")
@@ -233,70 +235,16 @@ def test_reference_passes_itself_and_fails_its_control(run_py, bench, kind):
 # ------------------------------------------------ data files and contract
 
 def test_every_data_file_loads_and_is_used(bench):
-    configs = {os.path.join(_ROOT, c["file"]) for c in bench["configs"]}
-    assert configs == set(glob.glob(os.path.join(_BENCH, "configs",
-                                                 "*.json")))
-    for path in configs:
-        with open(path) as f:
-            c = json.load(f)
-        assert os.path.exists(os.path.join(_BENCH, "jobs", c["job"] + ".py"))
-        assert c["guarantees"] and c["shapes"] and c["source"]
-    used = {w["traffic"] for w in bench["workloads"]}
-    have = {os.path.basename(p)[:-5] for p in
-            glob.glob(os.path.join(_BENCH, "traffic", "*.json"))}
-    assert used == have
-    for name in have:
-        with open(os.path.join(_BENCH, "traffic", name + ".json")) as f:
-            t = json.load(f)
-        assert t["loop"] == "closed" and t["clients"] == 1
-    with open(os.path.join(_BENCH, "peaks.json")) as f:
-        assert "TPU v5 lite" in json.load(f)["device_kinds"]
+    contract.check_data_files(_ROOT, bench)
 
 
 def test_names_units_and_keys_are_within_the_contract(bench):
-    assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert 1 <= bench["run_seconds"] <= 51
-    for c in bench["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert _NAME.match(c["name"]) and all(map(_NAME.match, c["reduced"]))
-        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
-    for w in bench["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert _NAME.match(w["name"]) and _NAME.match(w["traffic"])
-        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
-    four = sum(w["chips"] == 4 for w in bench["workloads"])
-    assert four <= max(1, len(bench["workloads"]) // 2)
-    for m in bench["end_to_end"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
-                                          "source"}
-        assert 0.01 <= m["bound"] <= 0.25
-        assert m["source"] in ("host_clock", "device_trace")
-    for m in bench["per_layer"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
-                                          "layer", "moves"}
-        assert m["source"] in _SOURCES
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        assert _NAME.match(m["name"]) and _UNIT.match(m["unit"])
-        assert m["better"] in ("lower", "higher")
-    names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
-    assert len(names) == len(set(names))
-    assert "setup_s" in names
+    contract.check_names_units_and_keys(bench)
 
 
 def test_every_per_layer_metric_has_a_reader_and_moves_a_reported_metric(
-        bench, run_py):
-    cells = [w["name"] for w in bench["workloads"]]
-    for m in bench["per_layer"]:
-        assert callable(run_py.load_module("layer_metrics", m["name"]).read)
-        moved = next(e for e in bench["end_to_end"]
-                     if e["name"] == m["moves"])
-        for cell in m.get("workloads", cells):
-            assert cell in cells
-            assert cell in moved.get("workloads", cells)
-    for cell in cells:
-        assert len(run_py.metrics_of(bench, "end_to_end", cell)) >= 2
-        assert run_py.metrics_of(bench, "per_layer", cell)
+        bench):
+    contract.check_readers(_ROOT, bench)
 
 
 def test_readers_on_a_made_up_run(run_py):
@@ -310,7 +258,8 @@ def test_readers_on_a_made_up_run(run_py):
                      "admission_spills": 2, "hbm_spills": 0},
            "memory": [{"peak_bytes_in_use": 4e9, "bytes_limit": 16e9},
                       {"peak_bytes_in_use": 8e9, "bytes_limit": 16e9}],
-           "jobs": 3, "compiles": 0, "cell": {"chips": 4},
+           "jobs": 3, "job_seconds": [1.0, 2.5, 1.5], "compiles": 0,
+           "cell": {"chips": 4},
            "peaks": {"hbm_bytes_per_s": 800e9}, "min_bytes": 3.2e9}
     want = {"dispatches_per_job": 3, "fetches_per_job": 2,
             "compiles_in_window": 0, "device_idle_share": 25.0,
@@ -318,15 +267,15 @@ def test_readers_on_a_made_up_run(run_py):
             # 0.8e9 B per chip / 800e9 B/s = 1 ms of 2,000 ms
             "job_roofline": 0.05, "hbm_peak_share": 50.0,
             "oom_ladder_events": 3, "collective_share": 5.0,
-            "exchange_bytes_per_job": 1000}
+            "exchange_bytes_per_job": 1000, "job_s_max": 2.5}
     for name, value in want.items():
         read = run_py.load_module("layer_metrics", name).read
         assert read(run) == pytest.approx(value), name
     blind = dict(run, trace=None, memory=[{}], min_bytes=None,
-                 stats=dict(run["stats"], exchanges=0))
+                 job_seconds=[], stats=dict(run["stats"], exchanges=0))
     for name in ("device_idle_share", "device_busy_ms_per_job",
                  "job_roofline", "collective_share", "hbm_peak_share",
-                 "exchange_bytes_per_job"):
+                 "exchange_bytes_per_job", "job_s_max"):
         assert run_py.load_module("layer_metrics", name).read(blind) is None
 
 
@@ -376,10 +325,51 @@ def test_rehearsal_is_correct_and_never_prints_the_result_line(
         assert last["attempted"] == 3
         # a CPU has no device plane: every trace reader stays silent
         assert "device_idle_share" not in last["reported"]
-        assert "dispatches_per_job" in last["reported"]
+        assert {"dispatches_per_job", "job_s_max"} <= set(last["reported"])
     else:
-        assert {"records_per_s", "job_s_slowest", "setup_s"} \
-            <= set(last["reported"])
+        # whatever BENCHMARK.json lists end to end for the cell, no more
+        bench = run_py.load_json("BENCHMARK.json")
+        assert set(last["reported"]) == {
+            m["name"] for m in run_py.metrics_of(bench, "end_to_end", cell)}
+        assert {"records_per_s", "setup_s"} <= set(last["reported"])
+
+
+def test_readers_get_every_counter_and_a_run_prints_ten(
+        run_py, rehearsal_env, monkeypatch, capsys):
+    """``run["stats"]`` holds whatever ``overall_stats()`` counts, by the
+    program's own names, so a counter that a later PR adds reaches its
+    reader with no edit here; what a run PRINTS stays the ten."""
+    seen = {}
+    reader = run_py.load_module("layer_metrics", "dispatches_per_job")
+    real = reader.read
+    monkeypatch.setattr(reader, "read",
+                        lambda run: (seen.update(run), real(run))[1])
+    assert run_py.main(["--workload", "pagerank.w1", "--seed",
+                        str(2**31 + 35), "--seconds", "0.05", "--trace",
+                        "1", "--rehearse"]) == 0
+    captured = capsys.readouterr()
+    last = json.loads(captured.out.strip().splitlines()[-1])
+    assert last["correct"] is True and last["attempted"] == 3
+    assert tuple(last["counts"]) == run_py.STAT_KEYS
+    assert len(run_py.STAT_KEYS) == 10
+    line = next(l for l in captured.err.splitlines()
+                if l.startswith("stats over the window: "))
+    assert [kv.split("=")[0] for kv in line.split(": ", 1)[1].split()] \
+        == list(run_py.STAT_KEYS)
+    stats = seen["stats"]
+    assert all(stats[k] == last["counts"][k] for k in run_py.STAT_KEYS)
+    # one plan for the degrees, one hoisted ahead of the loop (PR 29)
+    assert stats["r2i_index_plans"] == 2 * seen["jobs"] == 6
+    assert stats["upload_bytes"] > 0 and stats["device_uploads"] > 0
+    # a gauge reads its change, a label is left out
+    assert stats["workers"] == 0
+    assert all(isinstance(v, (int, float)) for v in stats.values())
+
+
+def test_stat_deltas_leave_out_what_one_end_lacks(run_py):
+    before = {"a": 1, "gone": 2, "label": "x", "flag": True, "s": 0.5}
+    after = {"a": 4, "new": 7, "label": "y", "flag": False, "s": 2.0}
+    assert run_py.stat_deltas(before, after) == {"a": 3, "s": 1.5}
 
 
 @pytest.mark.parametrize("cell", _cells())

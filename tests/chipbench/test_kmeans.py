@@ -20,6 +20,12 @@ TRAFFIC = {"points": 512, "dim": 3, "clusters": 10, "iterations": 10}
 EVERY_CELL = ("dispatches_per_job", "fetches_per_job", "compiles_in_window",
               "device_idle_share", "device_busy_ms_per_job", "job_roofline",
               "hbm_peak_share", "oom_ladder_events")
+LOOP_METRICS = ("loop_host_s_per_job", "loop_captures_in_window",
+                "iterations_replayed_share")
+SPAN_METRICS = ("upload_s_per_job", "upload_bytes_per_job",
+                "dispatch_call_s_per_job", "sync_wait_s_per_job",
+                "fetch_s_per_job", "host_plan_s_per_job",
+                "compile_s_in_window")
 
 
 def _load(path, name):
@@ -229,13 +235,16 @@ def test_the_reader_with_nothing_to_read_returns_none(reader, monkeypatch):
 # ------------------------------------------------------ the data files
 
 def test_benchmark_json_names_the_cell_and_its_metrics():
+    """Every entry is found by its NAME: where it stands in a list is the
+    driver's business (``test_append_only.py``), and later cells and
+    metrics follow it."""
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == ("kmeans.w1", "kmeans-uniform3d", "closed_uniform_k10_i10", 1)
-    entry = bench["configs"][-1]
-    assert entry["name"] == "kmeans-uniform3d"
+    cell = next(w for w in bench["workloads"] if w["name"] == "kmeans.w1")
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == ("kmeans-uniform3d", "closed_uniform_k10_i10", 1)
+    entry = next(c for c in bench["configs"]
+                 if c["name"] == "kmeans-uniform3d")
     assert entry["reduced"] == ["points_per_job"]
     with open(os.path.join(_ROOT, entry["file"])) as f:
         config = json.load(f)
@@ -250,17 +259,16 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
         == (3, 10, 10)
     assert traffic["points"] in (1 << 21, 1 << 22, 1 << 23)
     assert traffic["check"] == {"jobs": "all"}
-    metric = bench["per_layer"][-1]
-    assert metric == {
+    metric = next(m for m in bench["per_layer"]
+                  if m["name"] == "index_plans_per_job")
+    assert {k: v for k, v in metric.items() if k != "workloads"} == {
         "name": "index_plans_per_job", "unit": "count", "better": "lower",
         "source": "program_span", "layer": "DIA ops and fusion",
-        "moves": "records_per_s", "workloads": ["kmeans.w1"]}
+        "moves": "records_per_s"}
+    assert "kmeans.w1" in metric["workloads"]
     reported = {m["name"] for m in bench["per_layer"]
                 if "kmeans.w1" in m["workloads"]}
-    assert reported == set(EVERY_CELL) | {"index_plans_per_job"}
-    for m in bench["per_layer"]:
-        if m["name"] in EVERY_CELL:
-            assert m["workloads"][-1] == "kmeans.w1"
+    assert reported >= set(EVERY_CELL) | {"index_plans_per_job"}
 
 
 # ------------------------------------------------------- run.py end to end
@@ -300,6 +308,8 @@ def test_a_rehearsal_rebinds_the_tape_and_counts_ten_plans(
     if not trace:
         return
     assert jobs == 3 and "index_plans_per_job" in last["reported"]
+    # the host phases and the loop's metrics are in the line, each a number
+    assert set(LOOP_METRICS) | set(SPAN_METRICS) <= set(last["reported"])
 
     def said(start):
         line = next(l for l in captured.err.splitlines()

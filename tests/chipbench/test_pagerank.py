@@ -19,6 +19,10 @@ TRAFFIC = {"graph500_scale": 10, "edge_factor": 16, "iterations": 10,
            "damping": 0.85}
 LOOP_METRICS = ("loop_host_s_per_job", "loop_captures_in_window",
                 "iterations_replayed_share")
+SPAN_METRICS = ("upload_s_per_job", "upload_bytes_per_job",
+                "dispatch_call_s_per_job", "sync_wait_s_per_job",
+                "fetch_s_per_job", "host_plan_s_per_job",
+                "compile_s_in_window")
 
 
 def _load(path, name):
@@ -260,14 +264,28 @@ def test_a_replay_that_fell_back_ran_no_iteration():
 
 
 def test_benchmark_json_lists_the_loop_metrics_in_the_loop_cell_alone():
+    """In the loop cells alone: the first loop cell first, and every cell
+    listed runs a job kind whose ``pipeline`` calls ``Iterate`` (today
+    ``pagerank`` and ``kmeans``; a later kind is told by its source)."""
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    loop_kinds = set()
     for name in LOOP_METRICS:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
         assert entry["source"] == "program_span"
-        assert entry["workloads"] == ["pagerank.w1"]
+        assert entry["workloads"][0] == "pagerank.w1"
         assert entry["layer"] == "iteration (`api/loop.py`)"
-    cell = next(w for w in bench["workloads"] if w["name"] == "pagerank.w1")
+        for listed in entry["workloads"]:
+            with open(os.path.join(_ROOT,
+                                   files[cells[listed]["config"]])) as f:
+                kind = json.load(f)["job"]
+            with open(os.path.join(_BENCH, "jobs", kind + ".py")) as f:
+                assert " Iterate(" in f.read(), (listed, kind)
+            loop_kinds.add(kind)
+    assert loop_kinds >= {"pagerank", "kmeans"}
+    cell = cells["pagerank.w1"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "pagerank-graph500", "closed_rmat_i10", 1)
 
@@ -293,7 +311,7 @@ def test_a_traced_rehearsal_reports_the_loop_metrics(rehearsal_env, capsys):
     captured = capsys.readouterr()
     last = json.loads(captured.out.strip().splitlines()[-1])
     assert last["correct"] is True and last["attempted"] == 3
-    assert set(LOOP_METRICS) <= set(last["reported"])
+    assert set(LOOP_METRICS) | set(SPAN_METRICS) <= set(last["reported"])
 
     def said(start):
         line = next(l for l in captured.err.splitlines()
@@ -305,7 +323,11 @@ def test_a_traced_rehearsal_reports_the_loop_metrics(rehearsal_env, capsys):
     loops = said("loop spans over 3 jobs")
     assert (loops["captures"], loops["rebinds"]) == ("0", "3")
     assert loops["iterations_replayed"] == loops["iterations"] == "30"
-    assert last["counts"]["device_dispatches"] == 9
+    # the same programs in each of the three jobs, and a handful of them:
+    # a tape replayed call by call reads 36 or more for 10 iterations a
+    # job, an index plan dispatched on its own (ROADMAP D17) 12
+    dispatches = last["counts"]["device_dispatches"]
+    assert dispatches % 3 == 0 and 0 < dispatches <= 12
     assert last["counts"]["device_fetches"] == 3
     # the six phases and the loop's self time account for the root stages
     both = said("six phases + loop self")

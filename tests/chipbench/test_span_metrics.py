@@ -194,11 +194,13 @@ def test_where_nothing_sound_can_be_read_a_reader_returns_none(
 def test_benchmark_json_lists_the_reader_in_the_three_cells(bench, name):
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
     assert entry["source"] == "program_span" and entry["better"] == "lower"
-    assert entry["workloads"] == ["terasort.w1", "wordcount.w1",
-                                  "terasort.w4"]
+    # the three cells of PR 26 first: later cells are appended, not put in
+    assert entry["workloads"][:3] == ["terasort.w1", "wordcount.w1",
+                                      "terasort.w4"]
+    cells = {w["name"] for w in bench["workloads"]}
+    assert set(entry["workloads"]) <= cells
     assert os.path.exists(os.path.join(_BENCH, "layer_metrics",
                                        name + ".py"))
-    assert bench["per_layer"].index(entry) >= 10    # appended, not put in
 
 
 @pytest.fixture
