@@ -1,71 +1,178 @@
 """Suffix array construction by prefix doubling — the Sort-heaviest user.
 
-Reference: /root/reference/examples/suffix_sorting/prefix_doubling.cpp
-(also DC3/DC7 in dc3.cpp/dc7.cpp): iterative rank refinement where each
-round sorts (rank[i], rank[i+2^k], i) triples — log n distributed sorts.
+Reference: Thrill's examples/suffix_sorting/prefix_doubling.cpp and
+T. Bingmann, S. Gog, F. Kurpicz, "Scalable Construction of Text Indexes
+with Thrill" (arXiv:1610.03007), its prefix doubling "using sorting and
+windows" after Flick and Aluru (also DC3/DC7 in dc3.cpp/dc7.cpp). The
+pipeline, in DIA operators only (``suffix_array``):
 
-TPU-native: ranks live as device columns; each doubling round is one
-device Sort + neighbor-compare rank assignment (PrefixSum of boundary
-flags), the exact structure the reference runs over its sample sort.
+1. names: every position ``i`` with the first 4 characters of its
+   suffix packed big-endian into one 32-bit integer (a padded
+   ``Window(4)`` over the indexed text), ``Sort`` by it, a padded
+   ``Window(2)`` that flags where a row's key differs from its
+   successor's, an ``ExPrefixSum`` that turns the flags into names:
+   ``(index, rank)``; ``h`` = 4.
+2. a round: ``Sort`` by ``(index mod h, index)`` (brings ``rank[i]`` and
+   ``rank[i + h]`` side by side), a padded ``Window(2)`` that emits
+   ``(index, rank1, rank2)`` with ``rank2 = 0`` past the end, ``Sort`` by
+   ``(rank1, rank2)``, flags, names; ONE scalar read back (``Max`` of
+   the names: are they all distinct?) decides whether ``h`` doubles and
+   another round runs.
+3. the suffix array is the index column in the order of the last sort.
+
+The text is distributed ONCE and every round's DIAs descend from it: a
+job is one pipeline. ``h`` enters each round's programs as an operand
+(``Bind``) and every stacked function is module-level, so all rounds
+share two compiled programs and no job compiles after the first.
 """
 
 from __future__ import annotations
 
 import _bootstrap  # noqa: F401  (repo root on sys.path for CLI runs)
 
+import functools
 
+import jax
 import numpy as np
 
-from thrill_tpu.api import Context
+from thrill_tpu.api import Bind, Context, Zip
+
+KMER = 4        # characters packed into the first key: the index type's bytes
 
 
-def _sa_rank_key(t):
-    # module-level (identity-stable): each doubling round reuses the
-    # same compiled sort executable — a fresh lambda per round would
-    # recompile every round (20-40 s each on TPU)
-    return (t["r1"], t["r2"])
+# ------------------------------------------------------- stacked functions
+# Module-level, hence identity-stable: the program caches its compiled
+# programs on the function objects, and a fresh lambda per round or per
+# job would compile anew each time (20-66 s each on a TPU). Each works
+# on batched columns (jax or numpy arrays with a leading item axis) and
+# on single host items alike, so the tiny inputs that fall back to the
+# host path run the same arithmetic.
+
+def _u32(x):
+    return x.astype(np.uint32) if hasattr(x, "astype") else np.uint32(x)
 
 
-def suffix_array(ctx: Context, text: np.ndarray) -> np.ndarray:
-    """text: [n] uint8. Returns the suffix array [n] int64.
+def _u64(x):
+    return x.astype(np.uint64) if hasattr(x, "astype") else np.uint64(x)
 
-    The doubling loop is device-resident: sorted columns come back as
-    device arrays (AllGatherArrays), the rank recomputation is eager
-    jnp math, and Distribute re-splits device arrays without a host
-    round trip — the only per-round sync is the scalar
-    distinct-rank count that decides loop termination."""
-    import jax.numpy as jnp
 
+def _char_row(c, g):
+    """ZipWithIndex: a character with its position in the text."""
+    return {"i": _u32(g), "c": c}
+
+
+def _kmer(w):
+    """Padded Window(4) over (index, character): the first 4 characters
+    of the suffix packed big-endian, and how many of them the text
+    holds. A pad row has index 0, a row behind another never, so the
+    index column tells the end of the text from a character 0: the
+    key ``(k-mer, length)`` orders "end of text" below every
+    character."""
+    i, c = w["i"], _u32(w["c"])
+    kmer = (c[:, 0] << 24) | (c[:, 1] << 16) | (c[:, 2] << 8) | c[:, 3]
+    length = 1 + _u32(i[:, 1] != 0) + _u32(i[:, 2] != 0) \
+        + _u32(i[:, 3] != 0)
+    return {"i": i[:, 0], "r1": kmer, "r2": length}
+
+
+def _by_pair(t):
+    """Sort key (rank1, rank2) as ONE 64-bit word: the device sort
+    compares it as exactly its two 32-bit halves."""
+    return (_u64(t["r1"]) << np.uint64(32)) | _u64(t["r2"])
+
+
+def _group_end(w):
+    """Padded Window(2) over rows sorted by (rank1, rank2): 1 where a
+    row's pair differs from its successor's (the last row's always
+    does: a pad row is (0, 0) and a real rank1 is never 0)."""
+    r1, r2 = w["r1"], w["r2"]
+    return _u32((r1[:, 0] != r1[:, 1]) | (r2[:, 0] != r2[:, 1]))
+
+
+def _index_of(t):
+    return t["i"]
+
+
+def _index_rank(i, r):
+    return {"i": i, "r": r}
+
+
+def _with_class(t, h):
+    """Map bound to ``h`` (a power of two): the residue class of the
+    index. Within a class the order of ``index div h`` is the order of
+    the index, so the round's first sort key is (index mod h, index)."""
+    return {"i": t["i"], "r": t["r"], "m": t["i"] & (h - np.uint32(1))}
+
+
+def _by_class(t):
+    return (_u64(t["m"]) << np.uint64(32)) | _u64(t["i"])
+
+
+def _pair(w, h):
+    """Padded Window(2) bound to ``h`` over rows sorted by class:
+    (index, rank1, rank2) with rank2 the successor row's rank where
+    that row is position index + h, else 0 (past the end; a pad row
+    has index 0, which no index + h is)."""
+    i, r = w["i"], w["r"]
+    return {"i": i[:, 0], "r1": r[:, 0],
+            "r2": r[:, 1] * _u32(i[:, 1] == i[:, 0] + h)}
+
+
+def _on_host(device_fn, index, window, *operands):
+    """A window function's host form: the k items stacked into one
+    batched window of one row, through the same arithmetic."""
+    stacked = jax.tree.map(
+        lambda *xs: np.stack([np.asarray(x) for x in xs])[None], *window)
+    return jax.tree.map(lambda a: np.asarray(a)[0],
+                        device_fn(stacked, *operands))
+
+
+_kmer_host = functools.partial(_on_host, _kmer)
+_group_end_host = functools.partial(_on_host, _group_end)
+
+
+def _pair_host(step):
+    return lambda index, window: _on_host(_pair, index, window, step)
+
+
+def _named(pairs):
+    """Rows (index, rank1, rank2) -> the index column and the names,
+    both in (rank1, rank2) order: a name is 1 + the number of groups
+    that end before the row. The sorted rows feed two consumers (Keep):
+    the naming, which the round's read pulls, and the index column,
+    which the next round's Zip or the final gather pulls."""
+    rows = pairs.Sort(_by_pair).Keep()
+    names = rows.Window(2, _group_end_host, device_fn=_group_end,
+                        pad=True).ExPrefixSum(initial=1)
+    return rows.Map(_index_of), names
+
+
+def suffix_array(ctx: Context, text: np.ndarray,
+                 stats: dict = None) -> np.ndarray:
+    """text: [n] uint8. Returns the suffix array [n] uint32; ``stats``,
+    where given, receives ``rounds`` (doubling rounds run) and ``h``."""
     n = len(text)
     if n == 0:
-        return np.array([], dtype=np.int64)
-
-    # initial ranks = byte values; sentinel handling via +1
-    rank = jnp.asarray(text.astype(np.int64) + 1)
-    idx = jnp.arange(n, dtype=jnp.int64)
-    h = 1
-    while True:
-        rank2 = jnp.zeros(n, dtype=jnp.int64)
-        if h < n:
-            rank2 = rank2.at[:n - h].set(rank[h:])
-
-        d = ctx.Distribute({"i": idx, "r1": rank, "r2": rank2})
-        s = d.Sort(key_fn=_sa_rank_key)
-        # columnar egress in ranked worker order = global sort order
-        cols = s.AllGatherArrays()
-        si, r1, r2 = cols["i"], cols["r1"], cols["r2"]
-
-        # new ranks: 1 + prefix count of strict (r1, r2) boundaries
-        boundary = jnp.concatenate([
-            jnp.ones(1, jnp.int64),
-            ((r1[1:] != r1[:-1]) | (r2[1:] != r2[:-1])).astype(jnp.int64)])
-        new_rank_sorted = jnp.cumsum(boundary)
-        rank = jnp.zeros(n, dtype=jnp.int64).at[si].set(new_rank_sorted)
-        if int(new_rank_sorted[-1]) == n:       # termination sync
-            return np.asarray(si, dtype=np.int64)
+        return np.array([], dtype=np.uint32)
+    chars = ctx.Distribute(np.ascontiguousarray(text, dtype=np.uint8))
+    kmers = chars.ZipWithIndex(_char_row).Window(
+        KMER, _kmer_host, device_fn=_kmer, pad=True)
+    index, names = _named(kmers)
+    h, rounds = KMER, 0
+    # the ACTION that ends a round: all names distinct = the largest is n
+    while int(names.Keep().Max()) < n:
+        step = np.uint32(h)
+        pairs = Zip(index, names, zip_fn=_index_rank) \
+            .Map(Bind(_with_class, step)).Sort(_by_class) \
+            .Window(2, _pair_host(step), device_fn=Bind(_pair, step),
+                    pad=True)
+        index, names = _named(pairs)
         h *= 2
-        if h >= 2 * n:
-            return np.asarray(si, dtype=np.int64)
+        rounds += 1
+    names.Dispose()
+    if stats is not None:
+        stats.update(rounds=rounds, h=h)
+    return np.asarray(index.AllGatherArrays(), dtype=np.uint32)
 
 
 def suffix_array_quadrupling(ctx: Context, text: np.ndarray) -> np.ndarray:
@@ -390,7 +497,7 @@ def wavelet_access(levels, n: int, i: int, bits: int = 8) -> int:
 def bwt(ctx: Context, text: np.ndarray) -> np.ndarray:
     """Burrows-Wheeler transform via the suffix array
     (reference: examples/suffix_sorting/wavelet_tree / bwt usage)."""
-    sa = suffix_array(ctx, text)
+    sa = suffix_array(ctx, text).astype(np.int64)
     return text[(sa - 1) % len(text)]
 
 

@@ -195,10 +195,12 @@ def test_sgd_and_logreg_zero_syncs():
     assert fetch == 0, fetch
 
 
-def test_suffix_doubling_zero_syncs():
-    """The suffix-array doubling loop re-Distributes DEVICE arrays:
-    zero uploads and zero mesh fetches for a whole build at W=1 (the
-    only per-round sync is the scalar termination read)."""
+def test_suffix_doubling_one_upload_one_read_per_round():
+    """The suffix-array doubling loop is ONE pipeline of operators: the
+    text goes up once, every round (and the names step) reads ONE
+    scalar back to decide whether another follows, and a round is four
+    dispatches (the index column, the sorts with the pairing window
+    between them, the naming, the reduction) at W=1."""
     sys.path.insert(0, _EXAMPLES)
     import suffix_sorting as ss
     mex = MeshExec(num_workers=1)
@@ -211,11 +213,15 @@ def test_suffix_doubling_zero_syncs():
     assert all(sb[sa[i]:] < sb[sa[i + 1]:]
                for i in range(0, len(sa) - 1, 29))
     s0 = _snap(mex)
-    ss.suffix_array(ctx, text)
+    stats = {}
+    ss.suffix_array(ctx, text, stats=stats)
     disp, up, fetch = (_snap(mex) - s0).tolist()
-    assert up == 0, up
-    assert fetch == 0, fetch
-    assert disp <= 8, disp        # one fused sort per doubling round
+    rounds = stats["rounds"]
+    assert up == 1, up
+    assert fetch == rounds + 1, (fetch, rounds)
+    # names: sort + naming + reduction; a round: the four above; the
+    # result: its index column
+    assert disp == 3 + 4 * rounds + 1, (disp, rounds)
 
 
 def _wc_text_file(tmp_path):

@@ -346,7 +346,9 @@ def test_stream_fault_typed_error_conn_survives(ctx):
         # the SAME connection keeps working: a torn stream is a
         # stream failure, not a connection or scheduler failure
         assert c.submit("echo", "after").result(60) == "after"
-    assert fd.conns_dropped == 0
+        # read while the client is still connected: once it closes, the
+        # server's reader counts that close as a drop, sooner or later
+        assert fd.conns_dropped == 0
     fd.close()
 
 

@@ -113,10 +113,16 @@ class DIA:
         return _s.Sort(self, key_fn, compare_fn, stable=True)
 
     def PrefixSum(self, fn: Callable = None, initial: Any = 0) -> "DIA":
+        """Inclusive scan. Without ``fn`` it is the additive scan and
+        runs on the device, inside the stitched program of the chain it
+        ends; a generic ``fn`` folds on the host (api/ops/prefix_sum.py
+        says what a job that must stay on the device spells instead)."""
         from .ops import prefix_sum as _p
         return _p.PrefixSum(self, fn, initial, inclusive=True)
 
     def ExPrefixSum(self, fn: Callable = None, initial: Any = 0) -> "DIA":
+        """Exclusive scan starting at ``initial``; device and host
+        paths as ``PrefixSum``'s."""
         from .ops import prefix_sum as _p
         return _p.PrefixSum(self, fn, initial, inclusive=False)
 
@@ -125,9 +131,13 @@ class DIA:
         return _z.ZipWithIndex(self, zip_fn)
 
     def Window(self, k: int, fn: Callable,
-               device_fn: Optional[Callable] = None) -> "DIA":
+               device_fn: Optional[Callable] = None,
+               pad: bool = False) -> "DIA":
+        """``pad=True``: the sequence is read as continued by k-1 zero
+        items, so every item starts a window (n windows for n items;
+        api/ops/window.py). ``device_fn`` may be a ``Bind``."""
         from .ops import window as _w
-        return _w.Window(self, k, fn, device_fn, disjoint=False)
+        return _w.Window(self, k, fn, device_fn, disjoint=False, pad=pad)
 
     def FlatWindow(self, k: int, fn: Callable = None,
                    device_fn: Optional[Callable] = None,

@@ -139,9 +139,14 @@ class DIABase:
         of every upload, dispatch, wait and fetch underneath, so that a
         stage's self time is its duration minus its children's. Pulls
         nest by the pull recursion. ``name`` is an action's, which
-        works on this node's output."""
-        return span_of(getattr(self.context, "tracer", None), "stage",
-                       name or self.label, dia_id=self.id,
+        works on this node's output. A stage span with no span open
+        above it is the root of a PULL (an action's, a loop's): counted
+        as ``overall_stats()["pulls"]``, how often a job sent the pull
+        recursion and the planner off."""
+        tr = getattr(self.context, "tracer", None)
+        if tr is not None and tr.enabled and tr.current_id() is None:
+            self.context.mesh_exec.stats_pulls += 1
+        return span_of(tr, "stage", name or self.label, dia_id=self.id,
                        pipe=self.pipe)
 
     def _barrier_decision(self, reason: str) -> None:

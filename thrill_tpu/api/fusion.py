@@ -133,6 +133,10 @@ class Segment:
     # ReduceToIndex range, ReduceByKey's fold, which gathers one row
     # per run): the final compaction scatter is skipped
     already_compact: bool = False
+    # output row j is input row j's and the mask passes through (a
+    # scan over the rows): with ``preserves_counts``, a prefix of valid
+    # rows stays one, as behind a map-only stack
+    keeps_rows: bool = False
     # host-known output counts this segment imposes (ReduceToIndex's
     # dense range sizes); replaces the plan's known counts
     sets_counts: Optional[np.ndarray] = None
@@ -250,10 +254,15 @@ class FusionPlan:
         return ([self.head] if self.head is not None else []) \
             + self.segments
 
-    def counts_preserved(self) -> bool:
-        """Every pending segment keeps per-worker counts unchanged."""
-        return self.head is None and all(s.preserves_counts
-                                         for s in self.segments)
+    def rows_are_a_known_prefix(self) -> bool:
+        """At the end of the pending chain every worker's valid rows
+        are a prefix whose length the host knows: a source's are, a
+        head's that says so and hands its counts over (Zip), and every
+        tail segment keeps them. What a Window needs to fuse (its halo
+        rule reads the counts, its trace takes rows ``0 .. count-1``)."""
+        return self.stitchable and self.known_counts is not None \
+            and (self.head is None or self.head.already_compact) \
+            and all(s.preserves_counts for s in self.segments)
 
     # -- execution ------------------------------------------------------
     def finish(self):
@@ -323,7 +332,8 @@ class FusionPlan:
         compact = head is None
         for seg in segs:
             compact = seg.already_compact or (
-                compact and seg.preserves_counts and seg.row_local)
+                compact and seg.preserves_counts
+                and (seg.row_local or seg.keeps_rows))
         nd = len(srcs) + sum(len(f_[0]) for f_ in src_flat)
         nb = sum(len(bf[0]) for bf in bound_flat)
         in_specs = (P(AXIS),) * nd + (P(),) * nb

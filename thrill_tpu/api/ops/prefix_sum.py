@@ -1,10 +1,24 @@
 """PrefixSum / ExPrefixSum.
 
 Reference: thrill/api/prefix_sum.hpp:28 — local sum, net.ExPrefixSum of
-partials, re-emit. Device path: one SPMD program doing a masked local
-cumulative sum plus a cross-worker exclusive offset via all_gather of
-local totals (the FlowControlChannel step become an XLA collective).
-Generic (non-additive) functions run on the host path sequentially.
+partials, re-emit.
+
+What runs on the device: the ADDITIVE scan (``fn is None``), inclusive
+or exclusive, with any ``initial``, over every leaf of the item tree: a
+masked local ``cumsum`` plus the cross-worker exclusive offset from an
+``all_gather`` of the local totals (the FlowControlChannel step become
+an XLA collective). It rides the stitched program of the chain it ends
+(a ``Sort -> Window -> PrefixSum`` is one dispatch), its operations
+carry the named scope ``prefix_sum``, and its rows stay where they are
+(``keeps_rows``), so a chain whose rows were a prefix is not compacted
+behind it. An unsigned column wraps, as its dtype does.
+
+What does not: a generic ``fn`` (Thrill's ``PrefixSum`` takes any
+associative functor, its suffix sorters name by ``max``) folds on the
+host path, sequentially. A job that must stay on the device spells its
+scan additively: names are 1 + the sum of the boundary flags before a
+row (examples/suffix_sorting.py), and a column that must pass through
+unsummed is zipped back on afterwards.
 """
 
 from __future__ import annotations
@@ -20,6 +34,10 @@ from ...data.shards import DeviceShards, HostShards
 from ...parallel.mesh import AXIS
 from ..dia import DIA
 from ..dia_base import DIABase
+
+# HLO metadata only: the scan's operations carry this name in a device
+# profile's op_name
+SCOPE = "prefix_sum"
 
 
 class PrefixSumNode(DIABase):
@@ -39,6 +57,7 @@ class PrefixSumNode(DIABase):
         inclusive = self.inclusive
         initial = self.initial
 
+        @jax.named_scope(SCOPE)
         def trace(fctx, tree, mask, _bound):
             def one(x):
                 m = mask.reshape(mask.shape + (1,) * (x.ndim - 1))
@@ -47,10 +66,12 @@ class PrefixSumNode(DIABase):
                 local_total = incl[-1]
                 totals = lax.all_gather(local_total, AXIS)   # [W, ...]
                 widx = lax.axis_index(AXIS)
+                # in the leaf's dtype, like the cumsum: a jnp sum of
+                # 32-bit integers would widen the whole column to 64
                 prev = jnp.where(
                     (jnp.arange(totals.shape[0]) < widx
                      ).reshape((-1,) + (1,) * (totals.ndim - 1)),
-                    totals, 0).sum(axis=0)
+                    totals, 0).sum(axis=0, dtype=x.dtype)
                 scan = incl if inclusive else incl - xm
                 return scan + prev + jnp.asarray(initial).astype(x.dtype)
 
@@ -60,7 +81,8 @@ class PrefixSumNode(DIABase):
             label=self.label,
             token=("prefix_sum_fused", inclusive,
                    np.asarray(initial).tobytes()),
-            trace=trace, preserves_counts=True, dia_id=self.id)
+            trace=trace, preserves_counts=True, keeps_rows=True,
+            dia_id=self.id)
 
     def compute_plan(self):
         from .. import fusion
@@ -118,6 +140,7 @@ class PrefixSumNode(DIABase):
                tuple((l.dtype, l.shape[2:]) for l in leaves))
 
         def build():
+            @jax.named_scope(SCOPE)
             def f(counts_dev, *ls):
                 mask = jnp.arange(cap) < counts_dev[0, 0]
                 outs = []
@@ -132,7 +155,7 @@ class PrefixSumNode(DIABase):
                     prev = jnp.where(
                         (jnp.arange(totals.shape[0]) < widx
                          ).reshape((-1,) + (1,) * (totals.ndim - 1)),
-                        totals, 0).sum(axis=0)
+                        totals, 0).sum(axis=0, dtype=x.dtype)
                     scan = incl if self.inclusive else incl - xm
                     outs.append((scan + prev + jnp.asarray(initial)
                                  .astype(x.dtype))[None])

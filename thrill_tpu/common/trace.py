@@ -28,7 +28,11 @@ Category / name; site; read by:
   (``data/shards.py _put_staged`` by ``add_to_open``: 0 where they went
   up as views); ``host_plan_s_per_job`` (self time) and the window rule
   (``pipe``), tests/common/test_phase_spans.py,
-  tests/data/test_shards_staging.py.
+  tests/data/test_shards_staging.py. One with no span open above it is
+  the root of a PULL, counted there as ``overall_stats()["pulls"]``:
+  ``pulls_per_job`` (that counter over the jobs) and
+  ``replan_gap_s_per_job`` (from one root's last ``fetch`` / ``wait`` to
+  the next root's first ``dispatch``), tests/api/test_suffix_rounds.py.
 * ``upload`` / ``put``, ``put_replicated``; ``parallel/mesh.py
   MeshExec._upload`` (not the ``put_small`` hit), with ``bytes``,
   ``shape``, ``dtype``; ``upload_s_per_job``, ``upload_bytes_per_job``.
@@ -98,6 +102,18 @@ Category / name; site; read by:
 * ``io`` / ``hbm_restore``, ``writeback``, ``prefetch_reader``;
   ``mem/hbm.py``, ``data/writeback.py``, ``vfs/file_io.py``; nobody by
   name.
+
+Named scopes (``jax.named_scope``: metadata in the compiled HLO's
+``op_name``, no record on this spine, no operation) mark device
+operations for a device profile: ``sort_engine``, ``row_move``,
+``exchange`` / ``send_slice``, ``join_gather``, ``reduce_to_index`` /
+``index_plan`` / ``sorted_fold``, ``segmented_reduce`` / ``run_bounds`` /
+``run_fold``, and since PR 36 ``window`` (``api/ops/window.py``: the
+slices, the halo and the window function) and ``prefix_sum``
+(``api/ops/prefix_sum.py``); read by a throw-away script that joins the
+trace to the HLO (PERF.md section 7 item 9b),
+tests/api/test_suffix_rounds.py (the two new ones, in the lowered
+text).
 
 No cell of the benchmark runs the last seven entries' planes; what nothing
 reads by name is listed under ROADMAP D7 for the PR that folds the

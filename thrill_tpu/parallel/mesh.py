@@ -382,6 +382,9 @@ class MeshExec:
         # a whole-loop program one per iteration where the index
         # changes with the carry, and one per dispatch where it does not
         self.stats_r2i_index_plans = 0
+        # root ``stage`` spans opened (api/dia_base.py stage_span): one
+        # per pull an action or a loop starts; 0 with the tracer off
+        self.stats_pulls = 0
         self.stats_loop_replays = 0
         self.stats_loop_fori_iters = 0
         self.stats_loop_fallbacks = 0
