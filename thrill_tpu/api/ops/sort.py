@@ -795,16 +795,15 @@ def _device_sample_sort(shards: DeviceShards, key_fn: Callable,
             gidx = offset_dev[0, 0] + jnp.arange(cap, dtype=jnp.int64)
             words = keymod.encode_key_words(key_fn(tree))
             holder["nwords"] = len(words)
-            from ...core.device_sort import argsort_words
-            if full:
-                sort_words = list(words) + [gidx.astype(jnp.uint64)]
-            else:
+            from ...core.device_sort import sort_words
+            keys = list(words) + [gidx.astype(jnp.uint64)]
+            if not full:
                 valid = jnp.arange(cap) < count
-                sort_words = ([(~valid).astype(jnp.uint32)]
-                              + list(words) + [gidx.astype(jnp.uint64)])
-            perm = argsort_words(sort_words)
-            words_s = [jnp.take(w, perm) for w in words]
-            gidx_s = jnp.take(gidx, perm)
+                keys = [(~valid).astype(jnp.uint32)] + keys
+            # the keys come back from the sort: no gather by ``perm``
+            keys_s, perm = sort_words(keys)
+            words_s = keys_s[-1 - len(words):-1]
+            gidx_s = keys_s[-1].astype(jnp.int64)
             # quantile positions over the valid prefix (sorted: valid
             # items occupy [0, count))
             qpos = quantile_positions(count, cap)

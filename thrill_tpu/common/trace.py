@@ -43,7 +43,13 @@ Category / name; site; read by:
   note_index_plans``; 0 on most, and 0 on the whole-loop program, whose
   plans its ``loop`` / ``replay`` span counts);
   ``dispatch_call_s_per_job``, ``index_plans_per_job``,
-  tests/common/test_trace.py, tests/api/test_loop_tree_carry.py.
+  tests/common/test_trace.py, tests/api/test_loop_tree_carry.py. The
+  same choke point counts ``overall_stats()["sort_keys_reused"]``, on
+  no span: the sorted key words the program takes from its sort
+  (``core/device_sort.py sort_words``, noted at trace time by
+  ``parallel/mesh.py note_sort_keys_reused``), and ``api/loop.py
+  run_fori`` the calls' in every iteration of a whole-loop dispatch;
+  no metric reads it, tests/core/test_sort_words.py does.
 * ``compile`` / program label; ``parallel/mesh.py _on_jax_duration``
   (``jax.monitoring``), a backend compile or cache load under a
   dispatch, by ``emit_span``; ``compile_s_in_window``, and taken out of

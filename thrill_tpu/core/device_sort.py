@@ -1,7 +1,10 @@
 """Device sort engine: XLA sort, chunked sort+merge, or bitonic network.
 
-The DIA operators sort through one entry point, ``argsort_words``
-(stable argsort by a list of uint64 key words). Three interchangeable
+The DIA operators sort through two entry points over the same engines:
+``argsort_words`` (stable argsort by a list of uint64 key words) for a
+caller that needs only the permutation, and ``sort_words`` (the sorted
+words beside it) for one that reads its keys back, so that no caller
+gathers a word it has just sorted. Three interchangeable
 implementations:
 
 * ``xla``     — ``lax.sort`` multi-operand (fastest where the XLA sort
@@ -30,12 +33,14 @@ from __future__ import annotations
 
 import math
 import os
-from typing import List
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from . import rowmove
 
 # HLO metadata only (jax.named_scope adds, moves and fuses nothing): a
 # device profile tells the sort engine's operations from the row
@@ -157,21 +162,25 @@ def prepare_sort_words(words: List[jnp.ndarray], n: int):
     return words, idt
 
 
+def _radix_argsort(words: List[jnp.ndarray]) -> jnp.ndarray:
+    """LSD radix over 8-bit digits (O(n * passes), no comparison
+    network, no XLA-sort compile cliff): Pallas stable-partition kernel
+    on TPU, lax.scan fallback elsewhere. u32 split is irrelevant —
+    digits are extracted by shifts either way."""
+    from .pallas_sort import radix_argsort_device
+    bits = [32 if w.dtype == jnp.uint32 else 64 for w in words]
+    return radix_argsort_device(
+        [w.astype(jnp.uint64) for w in words],
+        word_bits=bits).astype(jnp.int32)
+
+
 @jax.named_scope(SCOPE)
 def argsort_words(words: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable argsort by uint64 key words (lexicographic). [n] int32."""
     n = words[0].shape[0]
     impl = choose_engine(n, words)
     if impl == "radix":
-        # LSD radix over 8-bit digits (O(n * passes), no comparison
-        # network, no XLA-sort compile cliff): Pallas stable-partition
-        # kernel on TPU, lax.scan fallback elsewhere. u32 split is
-        # irrelevant — digits are extracted by shifts either way.
-        from .pallas_sort import radix_argsort_device
-        bits = [32 if w.dtype == jnp.uint32 else 64 for w in words]
-        return radix_argsort_device(
-            [w.astype(jnp.uint64) for w in words],
-            word_bits=bits).astype(jnp.int32)
+        return _radix_argsort(words)
     words, idt = prepare_sort_words(words, n)
     if impl == "xla":
         iota = jnp.arange(n, dtype=idt)
@@ -181,6 +190,59 @@ def argsort_words(words: List[jnp.ndarray]) -> jnp.ndarray:
     if impl == "chunked":
         return _chunked_argsort(words, index_dtype=idt)
     return _bitonic_argsort(words, index_dtype=idt)
+
+
+@jax.named_scope(SCOPE)
+def sort_words(words: List[jnp.ndarray]
+               ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
+    """Stable sort by key words (lexicographic, as ``argsort_words``):
+    ``(sorted words, perm)``, the words in the dtypes passed and
+    ``perm`` the [n] int32 permutation ``argsort_words`` returns.
+
+    The engines end with every operand in sorted order, so the words
+    are taken from there, not gathered by ``perm``; a uint64 word split
+    into u32 halves comes back as ``(hi << 32) | lo``, elementwise, which
+    fuses into its consumer. ``radix`` yields only a permutation and
+    gathers the words here, so a caller has one path whatever engine
+    :func:`choose_engine` picks. The words taken from a sort are counted
+    as ``overall_stats()["sort_keys_reused"]``, one its caller leaves
+    unread as well (``sort_keys``' validity word on shards not full)."""
+    n = words[0].shape[0]
+    impl = choose_engine(n, words)
+    if impl == "radix":
+        perm = _radix_argsort(words)
+        with jax.named_scope(rowmove.SCOPE):
+            return [jnp.take(w, perm) for w in words], perm
+    split, idt = prepare_sort_words(words, n)
+    if impl == "xla":
+        arrs = lax.sort(tuple(split) + (jnp.arange(n, dtype=idt),),
+                        dimension=0, num_keys=len(split), is_stable=True)
+    elif n == 1:                 # nothing to sort, nothing to pad to
+        arrs = split + [jnp.zeros(1, idt)]
+    elif impl == "chunked":
+        arrs = [a.reshape(-1)[:n]
+                for a in _chunked_sorted(split, CHUNK_ROWS, idt)]
+    else:
+        arrs = [a[:n] for a in _bitonic_sorted(split, idt)]
+    from ..parallel.mesh import note_sort_keys_reused
+    note_sort_keys_reused(len(words))
+    return _unsplit_words(words, arrs[:-1]), arrs[-1].astype(jnp.int32)
+
+
+def _unsplit_words(words: List[jnp.ndarray],
+                   sorted_split: List[jnp.ndarray]) -> List[jnp.ndarray]:
+    """The caller's ``words`` from their sorted :func:`prepare_sort_words`
+    form: a uint64 word that came back as u32 (hi, lo) halves is
+    ``(hi << 32) | lo``; any other word is its own sorted operand."""
+    it = iter(sorted_split)
+    out = []
+    for w in words:
+        s = next(it)
+        if w.dtype == jnp.uint64 and s.dtype == jnp.uint32:
+            s = ((s.astype(jnp.uint64) << jnp.uint64(32))
+                 | next(it).astype(jnp.uint64))
+        out.append(s.astype(w.dtype))
+    return out
 
 
 def _lex_gt(a_words, b_words):
@@ -252,6 +314,16 @@ def _chunked_argsort(words: List[jnp.ndarray],
     n_real = words[0].shape[0]
     if n_real == 1:
         return jnp.zeros(1, jnp.int32)
+    arrs = _chunked_sorted(words, chunk, index_dtype)
+    return arrs[-1].reshape(-1)[:n_real].astype(jnp.int32)
+
+
+def _chunked_sorted(words: List[jnp.ndarray], chunk: int,
+                    index_dtype) -> List[jnp.ndarray]:
+    """The chunked engine's operands, the words padded to a power of
+    two and the index behind them, after the sort: ``[C, L]`` arrays
+    whose rows read in order are the sorted tuples, pads last."""
+    n_real = words[0].shape[0]
     n = 1 << (n_real - 1).bit_length()
     c = min(chunk, n)
     pad = n - n_real
@@ -264,8 +336,7 @@ def _chunked_argsort(words: List[jnp.ndarray],
     # base case: batched sort of every tile (compiles like one tile)
     arrs = list(lax.sort(tuple(arrs), dimension=1, num_keys=len(arrs),
                          is_stable=False))
-    arrs = merge_sorted_runs(arrs)
-    return arrs[-1].reshape(-1)[:n_real].astype(jnp.int32)
+    return merge_sorted_runs(arrs)
 
 
 @jax.named_scope(SCOPE)
@@ -292,6 +363,14 @@ def _bitonic_argsort(words: List[jnp.ndarray],
     n_real = words[0].shape[0]
     if n_real == 1:
         return jnp.zeros(1, jnp.int32)
+    arrs = _bitonic_sorted(words, index_dtype)
+    return arrs[-1].astype(jnp.int32)[:n_real]
+
+
+def _bitonic_sorted(words: List[jnp.ndarray],
+                    index_dtype) -> List[jnp.ndarray]:
+    """The bitonic engine's operands after the sort, flat, pads last."""
+    n_real = words[0].shape[0]
     # pad to a power of two with max-words; pads carry the largest iota
     # so they sort strictly last and perm[:n_real] is exactly the sorted
     # real items (handles non-pow2 caps, e.g. after local concat)
@@ -304,5 +383,4 @@ def _bitonic_argsort(words: List[jnp.ndarray],
                                               w.dtype)])
                  if pad else w for w in words) + (iota,)
 
-    arrs = _bitonic_stages(arrs, 0, k)
-    return arrs[-1].astype(jnp.int32)[:n_real]
+    return _bitonic_stages(arrs, 0, k)

@@ -111,11 +111,3 @@ def _pack_bytes(leaf: jnp.ndarray) -> List[jnp.ndarray]:
     # -> [n, nwords]; split into word list
     return [packed[..., i] for i in range(nwords)]
 
-
-def sort_by_words(words: List[jnp.ndarray], operands: List[jnp.ndarray]):
-    """Stable multi-word sort along axis 0: returns (words, operands)
-    permuted by lexicographic key order."""
-    from .device_sort import argsort_words
-    perm = argsort_words(list(words))
-    take = lambda x: jnp.take(x, perm, axis=0)
-    return [take(w) for w in words], [take(o) for o in operands]

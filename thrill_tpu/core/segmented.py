@@ -26,26 +26,22 @@ def sort_by_key_words(words: List[jnp.ndarray], tree: Any, valid: jnp.ndarray,
                       extra_words: List[jnp.ndarray] = ()):
     """Stable sort of (words, tree, valid) with invalid items last.
 
-    Returns (sorted_words, sorted_tree, sorted_valid). ``extra_words``
-    sort after the key words (e.g. global index for stability).
+    Returns (sorted_words, sorted_tree, sorted_valid, sorted_extra_words).
+    ``extra_words`` sort after the key words (e.g. global index for
+    stability). The words and the validity come back from the sort
+    itself (``device_sort.sort_words``); only the tree's leaves are
+    gathered by its permutation.
     """
+    from .device_sort import sort_words
     invalid_first_word = (~valid).astype(jnp.uint32)  # valid(0) < invalid(1)
-    sort_keys = [invalid_first_word] + list(words) + list(extra_words)
-    perm = _argsort_multi(sort_keys)
-    take = lambda x: jnp.take(x, perm, axis=0)
+    nw = len(words)
+    keys, perm = sort_words([invalid_first_word] + list(words)
+                            + list(extra_words))
     # row movement by a permutation, like core/rowmove.py's: the same
     # name in a device profile
     with jax.named_scope(rowmove.SCOPE):
-        return ([take(w) for w in words],
-                jax.tree.map(take, tree),
-                take(valid),
-                [take(w) for w in extra_words])
-
-
-def _argsort_multi(keys: List[jnp.ndarray]) -> jnp.ndarray:
-    """Stable argsort by multiple uint64 key arrays (lexicographic)."""
-    from .device_sort import argsort_words
-    return argsort_words(keys)
+        tree_s = jax.tree.map(lambda x: jnp.take(x, perm, axis=0), tree)
+    return keys[1:1 + nw], tree_s, keys[0] == 0, keys[1 + nw:]
 
 
 def segment_boundaries(words: List[jnp.ndarray], valid: jnp.ndarray

@@ -607,10 +607,10 @@ def _phase_a(shards: DeviceShards, dest_builder: Callable,
             widx = lax.axis_index(AXIS)
             dest = dest_builder(tree, mask, widx).astype(jnp.int32)
             dest = jnp.where(mask, jnp.clip(dest, 0, W - 1), W)
-            from ..core.device_sort import argsort_words
+            from ..core.device_sort import sort_words
             from ..core.rowmove import take_rows_multi
-            perm = argsort_words([dest.astype(jnp.uint64)])
-            sorted_dest = jnp.take(dest, perm)
+            (sorted_dest,), perm = sort_words([dest.astype(jnp.uint64)])
+            sorted_dest = sorted_dest.astype(jnp.int32)
             sorted_ls = take_rows_multi([l[0] for l in ls], perm)
             # replicate the [W, W] send-count matrix: every process can
             # then fetch it locally (multi-controller safe host step)

@@ -63,6 +63,37 @@ def current_program() -> Optional["_CountedJit"]:
     return getattr(_TL, "prog", None)
 
 
+def note_sort_keys_reused(k: int) -> None:
+    """Trace time: the program being traced takes ``k`` sorted key-word
+    arrays from its sort's output (core/device_sort.py ``sort_words``)
+    where it would have gathered them by the permutation. Kept on the
+    traced program (:func:`_noting`); outside one, nothing is noted."""
+    notes = getattr(_TL, "notes", None)
+    if notes is not None:
+        notes.append(k)
+
+
+def _noting(fn: Callable) -> Callable:
+    """``fn`` keeping on itself, as ``sort_keys_reused``, what its trace
+    noted. jax traces a program once and shares that trace between
+    ``lower``, the first call and a donating twin, whichever comes
+    first, so what a trace notes lives on the traced function; a
+    program traced inside another (a call in a whole-loop body) notes
+    on its own."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        prev = getattr(_TL, "notes", None)
+        _TL.notes = notes = []
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _TL.notes = prev
+            traced.sort_keys_reused = sum(notes)
+
+    traced.sort_keys_reused = 0
+    return traced
+
+
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _compile_listener_on = False
@@ -163,6 +194,14 @@ class _CountedJit:
         self._trace_label: Optional[str] = label
         functools.update_wrapper(self, jitted, updated=())
 
+    @property
+    def sort_keys_reused(self) -> int:
+        """Sorted key-word arrays one run of this program takes from its
+        sort (:func:`note_sort_keys_reused`): every dispatch adds them to
+        ``sort_keys_reused``; 0 until the program has been traced."""
+        base = self._donate_base or self
+        return getattr(base.raw, "sort_keys_reused", 0)
+
     def _label(self) -> str:
         return self._trace_label \
             or getattr(self._jitted, "__name__", None) or "jit"
@@ -227,6 +266,8 @@ class _CountedJit:
         # enough samples accumulate. Two perf_counter reads per
         # dispatch — no allocation, no env reads.
         dt = time.perf_counter() - t0
+        # after the call: a program's first call is its trace
+        mex.stats_sort_keys_reused += self.sort_keys_reused
         if dt < mex._disp_lat_min:
             mex._disp_lat_min = dt
         mex._disp_lat_n += 1
@@ -382,6 +423,11 @@ class MeshExec:
         # a whole-loop program one per iteration where the index
         # changes with the carry, and one per dispatch where it does not
         self.stats_r2i_index_plans = 0
+        # sorted key-word arrays the dispatched programs took from their
+        # sort's output instead of gathering them by the permutation
+        # (core/device_sort.py sort_words), counted where those are
+        # dispatched (_CountedJit._dispatch, api/loop.py run_fori)
+        self.stats_sort_keys_reused = 0
         # root ``stage`` spans opened (api/dia_base.py stage_span): one
         # per pull an action or a loop starts; 0 with the tracer off
         self.stats_pulls = 0
@@ -757,7 +803,7 @@ class MeshExec:
         is being built under (``"xchg_chunk"``, ``"sort_fused"``...) —
         so the device plane of a profile reads ``jit_<label>``."""
         label = name or getattr(_TL, "build_tag", None)
-        fn = _named(fn, label)
+        fn = _noting(_named(fn, label))
         return _CountedJit(self, jax.jit(fn), raw=fn, label=label)
 
     def smap(self, fn: Callable, num_args: int, out_specs=P(AXIS),
