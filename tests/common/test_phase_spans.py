@@ -270,6 +270,7 @@ def test_a_check_is_a_fetch_span_but_not_a_counted_fetch():
     try:
         mex = ctx.mesh_exec
         arr = mex.put(np.arange(16, dtype=np.int32).reshape(1, 16))
+        mex.flush_device_records()      # the put's transfer record
         n = len(spans_of(ctx))
         f0 = mex.stats_fetches
         assert mex._fetch_raw(arr).sum() == 120

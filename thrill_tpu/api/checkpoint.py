@@ -749,9 +749,7 @@ class CheckpointManager:
 
         def read():
             faults.check(_F_READ, file=finfo["name"])
-            with file_io.OpenReadStream(
-                    path, tracer=getattr(self.ctx.mesh_exec, "tracer",
-                                         None)) as f:
+            with file_io.OpenReadStream(path) as f:
                 return f.read()
 
         data = default_policy().run(read, what="ckpt.read")

@@ -1559,6 +1559,8 @@ class Context:
         from ..common import faults
         if faults.REGISTRY._log == self.logger.line:
             faults.REGISTRY.set_logger(None)
+        # the records of what is still in flight, before the logger goes
+        self.mesh_exec.close_watcher()
         self.logger.close()
         self.hbm.close()
         if self._aborted:

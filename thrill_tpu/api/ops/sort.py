@@ -337,8 +337,7 @@ class SortNode(DIABase):
         # wall-clock win — ARCHITECTURE "Out-of-core storage tier").
         # Slots are reserved at submit so run order in ``files`` is
         # the arrival order regardless of who executes.
-        writer = AsyncWriter("em_sort.spill",
-                             tracer=getattr(mex, "tracer", None))
+        writer = AsyncWriter("em_sort.spill")
 
         def _widen_concat(arrs):
             """One S-W key array from per-batch arrays of possibly

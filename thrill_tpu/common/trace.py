@@ -36,6 +36,19 @@ Category / name; site; read by:
 * ``upload`` / ``put``, ``put_replicated``; ``parallel/mesh.py
   MeshExec._upload`` (not the ``put_small`` hit), with ``bytes``,
   ``shape``, ``dtype``; ``upload_s_per_job``, ``upload_bytes_per_job``.
+* ``transfer`` / ``put``, ``put_replicated``; :class:`DeviceWatcher`,
+  handed the buffer by ``MeshExec._upload``: a child of the ``upload``
+  span, from the put's start to the bytes being on the device, with its
+  ``bytes``, ``shape``, ``dtype``; ``transfer_s_per_job`` (the union per
+  job, GB/s by leaf), ``device_idle_s_per_job`` (the cause
+  ``transfer``), tests/common/test_device_records.py.
+* ``device`` / program label; :class:`DeviceWatcher`, handed the final
+  outputs by ``_CountedJit.__call__``: a child of the ``dispatch`` span,
+  from the program's effective start to its outputs being ready
+  (``donated`` where they were all donated first);
+  ``device_idle_s_per_job`` (what they leave uncovered, and device
+  seconds by program), tests/common/test_device_records.py. Neither
+  category is a leaf of ``span_window``'s six phases, nor mirrored.
 * ``dispatch`` / program label; ``parallel/mesh.py
   _CountedJit.__call__``, every device dispatch, the whole-loop fori
   program included, with ``index_plans``: the ``ReduceToIndex`` index
@@ -99,15 +112,11 @@ Category / name; site; read by:
   tests/common/test_trace.py (lane).
 * ``service`` / ``queue_wait`` (``emit_span``), ``job:<name>``;
   ``service/scheduler.py``; tests/common/test_trace.py.
-* ``front_door`` / ``admit``, ``stream:<name>``;
-  ``service/front_door.py``; nobody by name.
 * ``net`` / collective site, ``heal``, ``reconnect`` (instant);
   ``net/group.py``, ``net/tcp.py``; tests/common/test_trace.py (lane).
 * ``host`` / host frames, ``async_send``; ``data/multiplexer.py``;
   tests/common/test_trace.py (lane).
-* ``io`` / ``hbm_restore``, ``writeback``, ``prefetch_reader``;
-  ``mem/hbm.py``, ``data/writeback.py``, ``vfs/file_io.py``; nobody by
-  name.
+* ``io`` / ``hbm_restore``; ``mem/hbm.py``; tests/mem/test_hbm_spill.py.
 
 Named scopes (``jax.named_scope``: metadata in the compiled HLO's
 ``op_name``, no record on this spine, no operation) mark device
@@ -121,14 +130,22 @@ trace to the HLO (PERF.md section 7 item 9b),
 tests/api/test_suffix_rounds.py (the two new ones, in the lowered
 text).
 
-No cell of the benchmark runs the last seven entries' planes; what nothing
+No cell of the benchmark runs the last four entries' planes; what nothing
 reads by name is listed under ROADMAP D7 for the PR that folds the
-observability mechanisms.
+observability mechanisms (``front_door`` / ``admit`` and
+``stream:<name>``, ``io`` / ``writeback`` and ``prefetch_reader`` went in
+PR 38: nothing read them).
 
 A span of the categories in :data:`MIRRORED` is also a
 ``jax.profiler.TraceAnnotation``; a record carries ``ts`` (wall-anchored
-microseconds, for the log files) and ``t0_s`` (its start on
-``time.perf_counter()``, the clock of a reader in the process).
+microseconds, for the log files and the device trace) and ``t0_s`` (its
+start on ``time.perf_counter()``, the clock of a reader in the process).
+``ts`` is the profiler's clock (a device plane's event times are
+relative to its ``profile_start_time``, wall-clock nanoseconds): a
+``device`` record's end by ``ts`` (``ts + dur_us``) against the end of
+its ``jit_<label>`` module on the device plane of a trace taken beside
+it is the rule for laying the program's spans on the trace; its offset
+is NOT yet measured (PERF.md section 7 item 35).
 
 Spans emit through the existing JsonLogger as ``event=span`` lines
 (json2profile ignores unknown events, so the HTML report keeps
@@ -154,7 +171,11 @@ Two always-on companions make this production-shaped:
 Overhead contract: ``THRILL_TPU_TRACE=0`` is a pinned no-op fast path
 — the dispatch choke point pays ONE attribute read plus one predicate
 check and allocates no span objects (tests/common/test_trace.py pins
-this via the module's ``SPANS_CREATED`` counter).
+this via the module's ``SPANS_CREATED`` counter) and no
+:class:`DeviceWatcher`: no thread, no queue (test_device_records.py).
+With it on, the watcher costs each upload and dispatch one queue append
+and each completion a wake-up of a lane's thread: PERF.md section 5 has
+``records_per_s`` with the Tracer off and on.
 """
 
 from __future__ import annotations
@@ -166,6 +187,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from typing import Any, Dict, Optional
 
 from jax.profiler import TraceAnnotation
@@ -345,6 +367,7 @@ class Tracer:
         self.lane_counts: Dict[str, int] = {}
         # records ever written to the ring, against its capacity
         self.records_written = 0
+        self._record_lock = threading.Lock()
         global _LATEST
         _LATEST = self
         if logger is not None and hasattr(logger, "now_us"):
@@ -411,7 +434,6 @@ class Tracer:
                     del st[i:]
                     break
         sp.close_mirror()
-        self.lane_counts[sp.cat] = self.lane_counts.get(sp.cat, 0) + 1
         self._record(sp.rec())
 
     def add_to_open(self, cat: str, key: str, amount: int) -> None:
@@ -446,7 +468,6 @@ class Tracer:
         if self.gen_fn is not None:
             rec["generation"] = self.gen_fn()
         rec.update({k: v for k, v in attrs.items() if v is not None})
-        self.lane_counts[cat] = self.lane_counts.get(cat, 0) + 1
         self._record(rec)
 
     def instant(self, cat: str, name: str, **attrs: Any) -> None:
@@ -469,16 +490,18 @@ class Tracer:
         if self.current_job is not None:
             rec["job"] = self.current_job
         rec.update({k: v for k, v in attrs.items() if v is not None})
-        # instants count toward the lane totals too: the mem lane is
-        # emitted EXCLUSIVELY as instants (ladder rungs) and must show
-        # up in bench trace_spans / the trace_spans metric
-        self.lane_counts[cat] = self.lane_counts.get(cat, 0) + 1
         self._record(rec)
 
     def _record(self, rec: dict) -> None:
-        if self.ring is not None:
-            self.ring.append(rec)
-            self.records_written += 1
+        # instants count toward the lane totals too (the mem lane is
+        # emitted EXCLUSIVELY as instants); the lock because a
+        # DeviceWatcher records from its own thread
+        with self._record_lock:
+            cat = rec["cat"]
+            self.lane_counts[cat] = self.lane_counts.get(cat, 0) + 1
+            if self.ring is not None:
+                self.ring.append(rec)
+                self.records_written += 1
         log = self.logger
         if log is not None and log.enabled:
             log.line(**rec)
@@ -522,6 +545,228 @@ class Tracer:
         except OSError:
             pass
         return path
+
+
+class DeviceWatcher:
+    """When each upload and each program is done on the device.
+
+    One per ``MeshExec`` (``parallel/mesh.py MeshExec._watch``), made on
+    the first hand-off while ``tracer.enabled`` is true: with
+    ``THRILL_TPU_TRACE=0`` nothing makes one, so there is no thread, no
+    queue and no allocation. ``MeshExec._upload`` hands it the placed
+    buffer with its ``upload`` span, ``_CountedJit.__call__`` a
+    program's final outputs and arguments with its ``dispatch`` span;
+    each hand-off is one queue append. The watcher waits until each is
+    ready and records, through :meth:`Tracer.emit_span`, parented to
+    that span:
+
+    * ``transfer`` / ``put`` or ``put_replicated``: from the put's start
+      to the bytes being on the device, with ``bytes``, ``shape``,
+      ``dtype``;
+    * ``device`` / program label: from the program's effective start to
+      its outputs being ready. The effective start is the latest of its
+      dispatch (taken when the call has handed the program to the
+      runtime: the call's own Python before that, 0.4 ms a dispatch in
+      ``suffix.w1``, is no device time), the previous program's ready
+      on this mesh, and the ready of every transfer whose buffer is
+      among its arguments, told by identity. Where an argument is a
+      device array that came from neither a transfer nor a program the
+      watcher saw (an eager ``jnp`` result, a buffer from before the
+      watcher started), what it depends on cannot be told, and the
+      ready of every transfer issued before the program counts instead.
+
+    Two lanes, each one daemon thread, started with the watcher, taking
+    its entries in order until :meth:`stop` (``Context.close``, or the
+    ``MeshExec`` going away): transfers and programs
+    run on separate streams of the device, and one thread in order
+    would close a transfer issued behind a running program at that
+    program's end (a 1 MB put 249 ms late on the CPU). The program lane
+    waits for a program's outputs first and only then for the transfer
+    lane to have recorded every transfer issued before it.
+
+    Donation: a loop's replay hands outputs to donating twins, so the
+    watcher waits on any output that is still alive (a program's leaves
+    are ready together) and never holds a donation up (it keeps a
+    reference, no hold). Where none is alive, a program's record is
+    closed at the next ready the program lane sees, which is no later
+    than its consumer's, and carries ``donated`` (a transfer's at the
+    moment that is seen).
+
+    The GIL: the end is taken when the wait returns, which is late by up
+    to the interpreter's switch interval (5 ms) while the dispatching
+    thread runs Python, and exact while it is blocked (a ``wait``, a
+    caller's ``block_until_ready``). Against the device trace: NOT yet
+    measured (PERF.md section 7 item 35); device seconds over the
+    trace's busy time read +0.08 to +0.88 % a job on the chip."""
+
+    LANES = ("transfer", "device")
+
+    def __init__(self, tracer: "Tracer") -> None:
+        import queue
+        self.tracer = tracer
+        self._lock = threading.Lock()       # hand-offs in order
+        self._queues = {lane: queue.SimpleQueue() for lane in self.LANES}
+        self._handed = 0            # transfers handed over
+        # the transfer lane's state, read by the program lane under it
+        self._settled = threading.Condition()
+        self._recorded = 0          # transfers recorded, in order
+        self._last_transfer = 0.0   # the latest transfer's ready
+        # transfers whose ready may lie after a later dispatch's start:
+        # id -> (weak reference, ready)
+        self._transfers: Dict[int, tuple] = {}
+        # live buffers from a transfer or a program: id -> weak reference
+        self._known: Dict[int, Any] = {}
+        self._last_ready = 0.0      # the previous program's ready
+        self._pending: list = []    # programs whose outputs were donated
+        self._threads = [threading.Thread(
+            target=self._run, args=(lane,), daemon=True,
+            name=f"thrill-tpu-watch-{lane}") for lane in self.LANES]
+        for t in self._threads:
+            t.start()
+
+    # -- the dispatching threads ----------------------------------------
+    def transfer(self, span: Span, buf) -> None:
+        self._put("transfer", span, buf, None)
+
+    def device(self, span: Span, out, args) -> None:
+        # the dispatch call has handed the program to the runtime: the
+        # call's own Python before that is no device time
+        self._put("device", span, out, (args, time.perf_counter()))
+
+    def flush(self, timeout: Optional[float] = None) -> bool:
+        """Block until every entry handed over so far is recorded (a
+        program whose outputs were all donated is closed now)."""
+        return self._signal("flush", timeout)
+
+    def stop(self, timeout: Optional[float] = None) -> bool:
+        """:meth:`flush`, then end the threads (``Context.close``)."""
+        return self._signal("stop", timeout)
+
+    def _signal(self, kind: str, timeout: Optional[float]) -> bool:
+        events = [threading.Event() for _ in self.LANES]
+        for lane, done in zip(self.LANES, events):
+            self._put(lane, None, done, kind)
+        return all(done.wait(timeout) for done in events)
+
+    def _put(self, lane: str, span, payload, args) -> None:
+        with self._lock:
+            if span is not None and lane == "transfer":
+                self._handed += 1
+            self._queues[lane].put((span, payload, args, self._handed))
+
+    # -- the lanes' threads ---------------------------------------------
+    def _run(self, lane: str) -> None:
+        q = self._queues[lane]
+        settle = self._settle_transfer if lane == "transfer" \
+            else self._settle_device
+        while True:
+            span, payload, args, n = q.get()
+            if span is None:                # flush or stop
+                if lane == "device":
+                    self._close_pending(time.perf_counter())
+                payload.set()
+                if args == "stop":
+                    return
+                continue
+            try:
+                settle(span, payload, args, n)
+            except Exception as e:          # never let a lane die
+                self.tracer.emit_span(lane, span.name, span.t0,
+                                      time.perf_counter(),
+                                      parent=span.span_id,
+                                      error=repr(e)[:200])
+            # let go of the buffers now, not when the next entry comes:
+            # held over an idle lane they raised the peak of HBM by a
+            # job's input and output (terasort.w1: 3.86 -> 5.74 GB)
+            span = payload = args = None
+
+    def _settle_transfer(self, span: Span, buf, _args, n: int) -> None:
+        ready = None
+        try:
+            ready, error = self._wait([buf])
+            self.tracer.emit_span(
+                "transfer", span.name, span.t0,
+                time.perf_counter() if ready is None else ready,
+                parent=span.span_id, error=error,
+                donated=True if ready is None else None,
+                **{k: span.attrs.get(k) for k in ("bytes", "shape", "dtype")})
+            if ready is not None:
+                self._remember([buf])
+        finally:
+            with self._settled:
+                if ready is not None:
+                    self._transfers[id(buf)] = (weakref.ref(buf), ready)
+                    self._last_transfer = max(self._last_transfer, ready)
+                self._recorded = n
+                self._settled.notify_all()
+
+    def _settle_device(self, span: Span, out, args, n: int) -> None:
+        import jax
+        leaves = [l for l in jax.tree.leaves(out) if isinstance(l, jax.Array)]
+        ready, error = self._wait(leaves)
+        if ready is None:
+            self._pending.append((span, self._start(span, args, n)))
+            return
+        self._close_pending(ready)
+        start = min(self._start(span, args, n), ready)
+        self.tracer.emit_span("device", span.name, start, ready,
+                              parent=span.span_id, error=error)
+        self._last_ready = max(self._last_ready, ready)
+        self._remember(leaves)
+
+    def _start(self, span: Span, args, n: int) -> float:
+        """The program's effective start (the class docstring), once the
+        transfer lane has recorded the ``n`` transfers issued before."""
+        import jax
+        args, returned = args
+        start = max(returned, self._last_ready)
+        told = True
+        with self._settled:
+            self._settled.wait_for(lambda: self._recorded >= n, 60.0)
+            for leaf in jax.tree.leaves(args):
+                if not isinstance(leaf, jax.Array):
+                    continue
+                hit = self._transfers.get(id(leaf))
+                if hit is not None and hit[0]() is leaf:
+                    start = max(start, hit[1])
+                elif id(leaf) not in self._known:
+                    told = False
+            if not told:
+                start = max(start, self._last_transfer)
+            # a transfer on the device before this dispatch began cannot
+            # move a later one
+            self._transfers = {k: v for k, v in self._transfers.items()
+                               if v[1] > returned}
+        return start
+
+    def _remember(self, leaves) -> None:
+        known = self._known
+        for leaf in leaves:
+            known[id(leaf)] = weakref.ref(
+                leaf, lambda _, i=id(leaf): known.pop(i, None))
+
+    @staticmethod
+    def _wait(leaves):
+        """(ready, error) of the first output still alive; (None, None)
+        where every one was donated."""
+        for leaf in leaves:
+            try:
+                if leaf.is_deleted():
+                    continue
+                leaf.block_until_ready()
+            except Exception as e:
+                if leaf.is_deleted():
+                    continue
+                return time.perf_counter(), repr(e)[:200]
+            return time.perf_counter(), None
+        return None, None
+
+    def _close_pending(self, ready: float) -> None:
+        pending, self._pending = self._pending, []
+        for span, start in pending:
+            self.tracer.emit_span("device", span.name, min(start, ready),
+                                  ready, parent=span.span_id, donated=True)
+            self._last_ready = max(self._last_ready, ready)
 
 
 def _prune(d: str, keep: int) -> None:

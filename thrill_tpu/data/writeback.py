@@ -76,7 +76,6 @@ class AsyncWriter:
 
     def __init__(self, what: str, depth: Optional[int] = None,
                  sync: Optional[bool] = None, poison: bool = True,
-                 tracer=None,
                  on_error: Optional[Callable[[BaseException, Any],
                                              None]] = None) -> None:
         self.what = what
@@ -84,10 +83,6 @@ class AsyncWriter:
         self.depth = writeback_queue_depth() if depth is None else depth
         self.poison = poison
         self.on_error = on_error
-        self._tracer = tracer
-        self._parent = (tracer.current_id()
-                        if tracer is not None and tracer.enabled
-                        else None)
         self._cv = threading.Condition()
         self._jobs: collections.deque = collections.deque()
         self._err: Optional[BaseException] = None
@@ -105,7 +100,6 @@ class AsyncWriter:
             self._t.start()
 
     def _run(self) -> None:
-        tr = self._tracer
         while True:
             with self._cv:
                 while not self._jobs and not self._closed:
@@ -123,13 +117,7 @@ class AsyncWriter:
                 if faults.REGISTRY.active():
                     faults.check(_F_WRITEBACK, what=self.what, tag=tag)
                 t0 = time.perf_counter()
-                if tr is not None and tr.enabled:
-                    with tr.span("io", "writeback", parent=self._parent,
-                                 what=self.what, tag=tag):
-                        nbytes = fn()
-                else:
-                    nbytes = fn()
-                nbytes = int(nbytes or 0)
+                nbytes = int(fn() or 0)
                 _IOSTATS.add(io_busy_s=time.perf_counter() - t0,
                              writeback_bytes=nbytes)
                 with self._cv:

@@ -20,6 +20,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
+import weakref
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -28,7 +29,7 @@ import numpy as np
 
 from ..common import faults
 from ..common.retry import default_policy
-from ..common.trace import span_of
+from ..common.trace import DeviceWatcher, span_of
 from ..mem import pressure as _pressure
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -215,8 +216,12 @@ class _CountedJit:
         if tr is None or not tr.enabled:
             return self._dispatch(args, kwargs)
         with tr.span("dispatch", self._label(),
-                     index_plans=self.index_plans):
-            return self._dispatch(args, kwargs)
+                     index_plans=self.index_plans) as sp:
+            out = self._dispatch(args, kwargs)
+            # the final outputs (after an OOM-ladder retry), for the
+            # ``device`` record of when they are ready
+            self._mex._watch().device(sp, out, (args, kwargs))
+            return out
 
     def _dispatch(self, args, kwargs):
         mex = self._mex
@@ -447,6 +452,8 @@ class MeshExec:
         # = the dispatch choke point pays one attribute read plus one
         # predicate and allocates nothing
         self.tracer = None
+        # when uploads and programs are done on the device (_watch)
+        self._watcher: Optional[DeviceWatcher] = None
         # decision ledger (common/decisions.py), attached by the
         # Context; same off-path contract as the tracer — None or
         # THRILL_TPU_DECISIONS=0 means every plan-choice choke point
@@ -604,17 +611,44 @@ class MeshExec:
         """One counted host->device upload through ``place(arr)``, as an
         ``upload`` span. It ends when ``jax.device_put`` returns, which
         may be before the bytes are on the device: nothing here waits
-        for them."""
+        for them; the ``transfer`` record says when they got there."""
         self.stats_uploads += 1
         nbytes = int(getattr(arr, "nbytes", 0) or 0)
         t0 = time.perf_counter()
         with span_of(self.tracer, "upload", name, bytes=nbytes,
                      shape=list(getattr(arr, "shape", ())),
-                     dtype=str(getattr(arr, "dtype", ""))):
+                     dtype=str(getattr(arr, "dtype", ""))) as sp:
             buf = self._bless(place(arr))
+        if sp is not None:
+            self._watch().transfer(sp, buf)
         self.stats_upload_s += time.perf_counter() - t0
         self.stats_upload_bytes += nbytes
         return buf
+
+    def _watch(self) -> DeviceWatcher:
+        """This mesh's completion watcher (``common/trace.py``), made on
+        the first record while the tracer is on; never with it off."""
+        w = self._watcher
+        if w is None or w.tracer is not self.tracer:
+            if w is not None:
+                w.stop(0)
+            w = self._watcher = DeviceWatcher(self.tracer)
+            # its threads end with this mesh where nothing closes it
+            weakref.finalize(self, w.stop, 0)
+        return w
+
+    def flush_device_records(self, timeout: float = 60.0) -> None:
+        """Block until every upload and program handed to the watcher so
+        far has its ``transfer`` / ``device`` record in the ring."""
+        if self._watcher is not None:
+            self._watcher.flush(timeout)
+
+    def close_watcher(self, timeout: float = 60.0) -> None:
+        """Record what is still in flight and end the watcher's threads
+        (``Context.close``)."""
+        w, self._watcher = self._watcher, None
+        if w is not None:
+            w.stop(timeout)
 
     def keeps_host_memory(self, arr: np.ndarray) -> bool:
         """Whether ``put(arr)`` ([W, ...], one shard per worker) would

@@ -451,9 +451,6 @@ class FrontDoor:
     def _handle_submit(self, c: _Conn, req: dict) -> None:
         jid = int(req.get("id", 0))
         name = str(req.get("pipeline") or "")
-        tr = getattr(self.ctx, "tracer", None)
-        # perf_counter, not monotonic: these stamps feed emit_span,
-        # which places spans by perf_counter deltas (common/trace.py)
         t_accept = time.perf_counter()
         # elastic fence gate (regression: a queued-but-unaccepted job
         # during a resize): wait out any pending resize BEFORE any
@@ -483,7 +480,7 @@ class FrontDoor:
         args = req.get("args")
         import inspect
         streaming = inspect.isgeneratorfunction(fn)
-        wrapper = self._make_job(c, jid, name, fn, args, deadline_at,
+        wrapper = self._make_job(c, jid, fn, args, deadline_at,
                                  t_accept, streaming)
         fut = self.ctx.submit(
             wrapper, tenant=c.tenant,
@@ -512,10 +509,6 @@ class FrontDoor:
         if c.proto >= 2:
             meta["gen"] = int(getattr(self.ctx, "generation", 0))
         c.enqueue(("accept", jid, meta))
-        if tr is not None and tr.enabled:
-            tr.emit_span("front_door", "admit", t_accept,
-                         time.perf_counter(), tenant=c.tenant,
-                         job=jid, pipeline=name)
 
     def _reject(self, c: _Conn, jid: int, kind: str,
                 retry_after_s: float, msg: str) -> None:
@@ -531,7 +524,7 @@ class FrontDoor:
                      retry_after_s=retry_after_s)
 
     # -- the job wrapper (runs on the DISPATCHER) -----------------------
-    def _make_job(self, c: _Conn, jid: int, name: str, fn: Callable,
+    def _make_job(self, c: _Conn, jid: int, fn: Callable,
                   args, deadline_at: Optional[float],
                   t_accept: float, streaming: bool) -> Callable:
         def job(ctx):
@@ -546,9 +539,9 @@ class FrontDoor:
                 return None
             try:
                 if streaming:
-                    out = self._stream_items(c, jid, fn, ctx, args)
+                    self._stream_items(c, jid, fn, ctx, args)
                 else:
-                    out = self._stream_blob(c, jid, fn(ctx, args))
+                    self._stream_blob(c, jid, fn(ctx, args))
             except _StreamAborted:
                 # the stream died (slow client / injected stream
                 # fault) but the JOB is fine — typed error frame went
@@ -562,11 +555,6 @@ class FrontDoor:
                                       repr(e)[:300]))
                 raise
             self._settle(c, jid, None)
-            tr = getattr(self.ctx, "tracer", None)
-            if tr is not None and tr.enabled:
-                tr.emit_span("front_door", f"stream:{name}", t0,
-                             time.perf_counter(), tenant=c.tenant,
-                             job=jid, chunks=out)
             return None
 
         return job

@@ -397,7 +397,7 @@ class PrefetchingReader:
 
     def __init__(self, path: str, offset: int = 0,
                  depth: Optional[int] = None,
-                 tracer=None, readahead_to: Optional[int] = None) -> None:
+                 readahead_to: Optional[int] = None) -> None:
         self._path = path
         self._pos = offset          # absolute offset of _buf[0]
         self._closed = False
@@ -413,10 +413,6 @@ class PrefetchingReader:
         self._limit = readahead_to
         self._buf = bytearray()     # dequeued, not yet returned
         self._demand: Optional[RetryingReader] = None
-        self._tracer = tracer
-        self._parent = (tracer.current_id()
-                        if tracer is not None and tracer.enabled
-                        else None)
         self._hits = 0
         self._misses = 0
         self._wait_s = 0.0
@@ -442,13 +438,7 @@ class PrefetchingReader:
 
     def _fill(self, st: "_FillState", offset: int) -> None:
         inner = None
-        tr = self._tracer
-        span = (tr.span("io", "prefetch_reader", parent=self._parent,
-                        path=self._path)
-                if tr is not None and tr.enabled else None)
         try:
-            if span is not None:
-                span.__enter__()
             inner = RetryingReader(self._path, offset)
             fill_pos = offset
             while True:
@@ -489,8 +479,6 @@ class PrefetchingReader:
         finally:
             if inner is not None:
                 inner.close()
-            if span is not None:
-                span.__exit__(None, None, None)
 
     def _teardown_thread(self) -> None:
         st = self._st
@@ -706,7 +694,6 @@ class PrefetchingReader:
 
 
 def OpenReadStream(path: str, offset: int = 0,
-                   tracer=None,
                    readahead_to: Optional[int] = None) -> IO[bytes]:
     """Open for reading, transparently decompressing by suffix, with
     transient-fault retry (reopen at offset) built in.
@@ -725,7 +712,7 @@ def OpenReadStream(path: str, offset: int = 0,
     depth = prefetch_depth()
     if depth <= 0:
         return RetryingReader(path, offset)
-    return PrefetchingReader(path, offset, depth=depth, tracer=tracer,
+    return PrefetchingReader(path, offset, depth=depth,
                              readahead_to=readahead_to)
 
 
