@@ -3,7 +3,10 @@ api/loop.py ``run_fori``): where the index is an invariant of the loop,
 the whole-loop program sorts it once, ahead of the iterations, on the
 call that captured the tape and on every call that rebinds it; where the
 index changes with the carry, the plan runs in every iteration and the
-tape replays all the same. ``r2i_index_plans`` counts the plans run."""
+tape replays all the same. ``r2i_index_plans`` counts the plans run. The
+range is wider than a dense fold takes, so these are the plans of the
+fold over sorted runs (the dense fold's own loops:
+tests/api/test_reduce_to_index_dense.py)."""
 
 import numpy as np
 import pytest
@@ -12,8 +15,9 @@ import jax.numpy as jnp
 
 from thrill_tpu.api import (Bind, FieldReduce, InnerJoin, Iterate,
                             RunLocalMock)
+from thrill_tpu.api.ops.reduce import DENSE_FOLD_ROWS
 
-N, M, ITERATIONS = 64, 2048, 5
+N, M, ITERATIONS = 2 * DENSE_FOLD_ROWS, 2048, 5
 
 
 @pytest.fixture(autouse=True)

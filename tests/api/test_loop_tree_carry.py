@@ -15,12 +15,14 @@ import numpy as np
 import pytest
 
 from thrill_tpu.api import RunLocalMock
+from thrill_tpu.api.ops.reduce import DENSE_FOLD_ROWS
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 TRAFFIC = {"points": 1024, "dim": 3, "clusters": 10, "iterations": 10}
 STATS = ("loop_plan_builds", "loop_plan_rebinds", "loop_fori_iters",
-         "loop_replay_fallbacks", "device_dispatches", "r2i_index_plans")
+         "loop_replay_fallbacks", "device_dispatches", "r2i_index_plans",
+         "r2i_dense_plans")
 
 
 def _load(*parts):
@@ -154,8 +156,10 @@ def test_the_spans_count_the_index_plans_the_counter_counts(run):
         assert all("index_plans" in r for r in recs
                    if r["cat"] == "dispatch")
         # the label is computed from the carry: a plan in every
-        # iteration, none hoisted ahead of the loop
+        # iteration, none hoisted ahead of the loop; ten rows fold
+        # densely, so every one of them is a dense fold's
         assert sum(_plans(recs)) == stats["r2i_index_plans"] == 10
+        assert stats["r2i_dense_plans"] == 10
     if run["workers"] == 1:
         # a whole-loop dispatch's plans are on the replay span around
         # it, and the dispatch span under it carries none
@@ -188,26 +192,31 @@ def test_pageranks_spans_count_its_two_plans():
     """A loop whose index is an invariant: the plan of the degrees and
     the loop's one plan, hoisted ahead of the ten iterations."""
     pr = _load("chipbench", "jobs", "pagerank")
-    traffic = {"graph500_scale": 8, "edge_factor": 16, "iterations": 10,
-               "damping": 0.85}
+    # twice as many pages as a dense fold takes rows: both folds are the
+    # sorted ones, as at the cell's 2^17 pages
+    traffic = {"graph500_scale": DENSE_FOLD_ROWS.bit_length(),
+               "edge_factor": 16, "iterations": 10, "damping": 0.85}
     seen = []
 
     def job(ctx):
         for seed in (1, 2):
             inp = pr.generate(seed, traffic, {})
-            s0 = ctx.overall_stats()["r2i_index_plans"]
+            s0 = ctx.overall_stats()
             n0 = len(ctx.tracer.ring)
             pr.pipeline(ctx, inp)
-            seen.append((ctx.overall_stats()["r2i_index_plans"] - s0,
-                         list(ctx.tracer.ring)[n0:]))
+            s1 = ctx.overall_stats()
+            seen.append((s1["r2i_index_plans"] - s0["r2i_index_plans"],
+                         list(ctx.tracer.ring)[n0:],
+                         s1["r2i_dense_plans"] - s0["r2i_dense_plans"]))
         assert not ctx.tracer.wrapped
 
     RunLocalMock(job, 1)
     # the job that captures sorts once more, in its captured iteration
-    assert [delta for delta, _ in seen] == [3, 2]
-    for delta, recs in seen:
+    assert [delta for delta, _, _ in seen] == [3, 2]
+    for delta, recs, dense in seen:
         assert sum(_plans([r for r in recs
                            if r.get("kind") != "instant"])) == delta
+        assert dense == 0
 
 
 def test_without_fusion_the_centroids_are_the_same_bit_for_bit(
@@ -220,3 +229,4 @@ def test_without_fusion_the_centroids_are_the_same_bit_for_bit(
         assert np.array_equal(got, same)
     for recs, stats in zip(_by_pipe(unfused), unfused["stats"]):
         assert sum(_plans(recs)) == stats["r2i_index_plans"] == 10
+        assert stats["r2i_dense_plans"] == 10

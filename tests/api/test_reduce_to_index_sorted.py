@@ -2,7 +2,9 @@
 runs sorted by index (core/segmented.py ``sorted_fold_*``) gives what
 numpy gives, at every mesh width, for every shape of index; narrower
 leaves keep the single-operand scatter. The choice is made from the
-leaf's dtype width and spec alone, so the CPU mesh runs the chip's path."""
+leaf's dtype width, spec and padded range alone, so the CPU mesh runs
+the chip's path; every range here is wider than a dense fold takes
+(``DENSE_FOLD_ROWS``: tests/api/test_reduce_to_index_dense.py)."""
 
 import re
 import zlib
@@ -17,7 +19,9 @@ from thrill_tpu.api import Context, FieldReduce
 from thrill_tpu.api.ops import reduce as reduce_mod
 from thrill_tpu.parallel.mesh import MeshExec
 
-SIZE = 53          # dense rows; not a multiple of any mesh width
+# dense rows; not a multiple of any mesh width, and more on each of four
+# workers than a dense fold takes
+SIZE = 4 * reduce_mod.DENSE_FOLD_ROWS + 13
 N = 700
 DTYPES = {"int64": np.int64, "uint64": np.uint64, "float64": np.float64}
 
@@ -172,7 +176,7 @@ def _sorts(text):
 
 
 def _lowered(dtype, spec):
-    cap, out_cap = 256, 64
+    cap, out_cap = 256, 2 * reduce_mod.DENSE_FOLD_ROWS
     tree = {"i": jnp.zeros(cap, jnp.int32), "v": jnp.zeros(cap, dtype)}
     pos = jnp.zeros(cap, jnp.int32)
     return str(jax.make_jaxpr(

@@ -13,6 +13,8 @@ a module-scoped fixture (never at import) and every compile is made in
 this process.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -202,3 +204,25 @@ def test_leaf_range_analysis_compiles_on_four_chips(topo):
     mex.smap(f, 2, out_specs=P()).lower(
         sds((4, 1024), jnp.int64, sharding=mex.sharded),
         sds((4, 1), jnp.int32, sharding=mex.sharded)).compile()
+
+
+def test_dense_fold_writes_no_row_by_item_array(one_chip):
+    """ReduceToIndex's dense fold at k-means' size (2^22 items into 16
+    rows: a binary64 ``[n, 3]`` and ``[n]`` sum, an int64 "first"): each
+    leaf is read by one reduce fusion with the compare inside it, so no
+    array of one row per item and output row is written, and the
+    temporaries stay under what one such array would take."""
+    from thrill_tpu.api.ops import reduce as reduce_mod
+    n, rows = 1 << 22, 16
+    sds = jax.ShapeDtypeStruct
+    args = [sds((n,), jnp.int32, sharding=one_chip),
+            sds((n,), jnp.int64, sharding=one_chip),
+            sds((n, 3), jnp.float64, sharding=one_chip),
+            sds((n,), jnp.float64, sharding=one_chip)]
+    compiled = jax.jit(lambda p, i, x, c: reduce_mod._scatter_reduce_apply(
+        (i, x, c), p, rows, ("first", "sum", "sum"), None)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert f"[{n},{rows}]" not in text and f"[{rows},{n}]" not in text
+    assert not re.search(r"\b(sort|scatter)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < n * rows * 4

@@ -994,6 +994,7 @@ class Context:
             "loop_plan_builds": mex.stats_loop_plan_builds,
             "loop_plan_rebinds": mex.stats_loop_plan_rebinds,
             "r2i_index_plans": mex.stats_r2i_index_plans,
+            "r2i_dense_plans": mex.stats_r2i_dense_plans,
             "sort_keys_reused": mex.stats_sort_keys_reused,
             "pulls": mex.stats_pulls,
             "loop_replays": mex.stats_loop_replays,

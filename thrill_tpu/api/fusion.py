@@ -64,6 +64,7 @@ from ..common import decisions as _decisions
 from ..common import faults
 from ..common import trace as _trace
 from ..common.retry import default_policy
+from ..core import segmented
 from ..core.jaxpr_deps import output_deps
 from ..data.shards import DeviceShards, HostShards, compact_valid
 from ..parallel.mesh import AXIS
@@ -174,6 +175,9 @@ class IndexPlans:
     """The index plans (Segment.index_plan) that one run of a compiled
     program computes in place."""
     count: int
+    # of them, the plans of a dense fold (core/segmented.py
+    # DenseFoldPlan), counted in ``r2i_dense_plans`` too
+    dense: int = 0
     # each plan as a program of its own: (the positions of the
     # program's arguments that it reads, the shard_map program over
     # just those)
@@ -191,6 +195,7 @@ _INDEX_PLANS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 def note_index_plans(fn, info: IndexPlans) -> None:
     _INDEX_PLANS[fn.raw] = info
     fn.index_plans = info.count     # counted by every dispatch of it
+    fn.dense_plans = info.dense
 
 
 def index_plans(fn) -> Optional[IndexPlans]:
@@ -420,9 +425,10 @@ class FusionPlan:
             if plans:
                 note_index_plans(fn, IndexPlans(
                     count=len(plans),
-                    plans=tuple((used, raw) for _, _, used, raw in plans),
+                    dense=sum(dense for _, _, _, _, dense in plans),
+                    plans=tuple((used, raw) for _, _, used, raw, _ in plans),
                     body=program([(k, n_k)
-                                  for k, n_k, _, _ in plans]).raw))
+                                  for k, n_k, _, _, _ in plans]).raw))
             return fn, holder
 
         fn, h = mex.cached(key, build)
@@ -525,7 +531,8 @@ class FusionPlan:
         """The index plans of this chain's tail segments
         (Segment.index_plan) as programs of their own: ``(tail index,
         number of outputs, the positions of the arguments it reads, the
-        shard_map program over just those)`` for each that yields one.
+        shard_map program over just those, 1 for a dense fold's plan
+        else 0)`` for each that yields one.
 
         A plan is traced behind the chain up to its segment, and the
         arguments it reads are those its outputs can depend on
@@ -542,7 +549,8 @@ class FusionPlan:
                 fctx = TraceCtx(mex.num_workers)
                 tree, mask, bounds_t = chain(fctx, a, k, {})
                 res = seg.index_plan(fctx, tree, mask, bounds_t[k]) or ()
-                n_out[:] = [len(res)]
+                n_out[:] = [len(res),
+                            int(isinstance(res, segmented.DenseFoldPlan))]
                 return tuple(r[None] for r in res)
 
             closed = jax.make_jaxpr(
@@ -565,7 +573,7 @@ class FusionPlan:
 
             out.append((k, n_out[0], used, mex.smap(
                 pruned, len(used),
-                in_specs=tuple(in_specs[i] for i in used)).raw))
+                in_specs=tuple(in_specs[i] for i in used)).raw, n_out[1]))
         return out
 
     def reexecute(self, new_cap: int) -> DeviceShards:

@@ -1022,6 +1022,9 @@ class LoopPlan:
         index_plans = sum(c.fn.index_plans * (1 if i in hoisted else k)
                           for i, c in enumerate(calls))
         self.mex.stats_r2i_index_plans += index_plans
+        self.mex.stats_r2i_dense_plans += sum(
+            c.fn.dense_plans * (1 if i in hoisted else k)
+            for i, c in enumerate(calls))
         # likewise the calls' sorted key words, taken in every iteration
         self.mex.stats_sort_keys_reused += k * sum(
             c.fn.sort_keys_reused for c in calls)

@@ -11,21 +11,28 @@ pre-phase table, and the whole pipeline is three jitted SPMD programs.
 Host storage falls back to dict-based aggregation per worker (the same
 algorithm the reference runs, in Python).
 
-ReduceToIndex's dense local phase has three engines, chosen from what
-the code can see of the reduce function and the leaves:
+ReduceToIndex's dense local phase has four engines, chosen from what
+the code can see of the reduce function, the leaves' specs and dtypes,
+and the static padded range ``out_cap``:
 
 - declarative ``FieldReduce`` specs over leaves of 4 bytes or fewer:
   one ``.at[row].add/min/max`` per field, no sort (6.8 ns per update on
   a v5e; ``_scatter_reduce_apply``);
-- a ``"sum"`` of 8-byte leaves in such a tree: the fold over runs sorted
-  by row (core/segmented.py ``sorted_fold_*``), because XLA:TPU scatters
-  8-byte values as pairs at 122-126 ns per update; the index plan reads
-  no value, so a loop with an invariant index sorts once
-  (api/fusion.py ``Segment.index_plan``);
+- a ``"sum"`` of 8-byte leaves in such a tree, into at most
+  ``DENSE_FOLD_ROWS`` rows: the dense fold, one masked reduction per row
+  over every leaf in one pass (core/segmented.py ``dense_fold*``);
+- the same into more rows: the fold over runs sorted by row
+  (core/segmented.py ``sorted_fold_*``), because XLA:TPU scatters
+  8-byte values as pairs at 122-126 ns per update;
 - any other reduce function: sort the items by index and fold the
   runs (``sort_by_key_words`` + ``reduce_runs``, which hands back one
   row per run, compact and in index order), then scatter those rows to
   their dense places by the gathered index word.
+
+The two folds read an index plan (the sorted fold's permutation and
+runs; the dense fold's first arrivals, where a field needs them) that
+reads no value, so a loop with an invariant index computes it once
+(api/fusion.py ``Segment.index_plan``).
 """
 
 from __future__ import annotations
@@ -48,8 +55,9 @@ from ..dia_base import DIABase
 from ...parallel.mesh import AXIS
 
 # the name ReduceToIndex's FieldReduce engines carry in a device profile
-# (jax.named_scope: HLO metadata, no operation added); the fold of
-# 8-byte sums nests ``index_plan`` and ``sorted_fold`` under it
+# (jax.named_scope: HLO metadata, no operation added); the folds of
+# 8-byte sums nest ``index_plan`` and ``sorted_fold``, or ``dense_fold``,
+# under it
 SCATTER_SCOPE = "reduce_to_index"
 
 
@@ -788,14 +796,72 @@ def _run_pos(index_word, n_runs, range_start, out_cap):
     return jnp.clip(jnp.where(live, local_idx, out_cap), 0, out_cap)
 
 
-def _wants_sorted_fold(specs, leaves) -> bool:
-    """Does any leaf take the fold over sorted runs? A "sum" of 8-byte
-    values does: XLA:TPU scatters those as pairs of 32-bit values at
-    122-126 ns per update (6.8 ns for a 32-bit scatter on the same
-    indices). Decided from spec and dtype width alone, so every backend
-    runs the path the chip runs."""
+# a tree with a "sum" of 8-byte values folds densely, by one masked
+# reduction per output row (core/segmented.py ``dense_fold``), where its
+# padded range has at most this many rows, and over sorted runs where it
+# has more. On a v5e, 2^22 rows of k-means' tree (binary64 [n, 3] and
+# [n] sums, an int64 "first"): dense 3.8 / 8.6 / 14.5 / 27.7 / 105.2 ms
+# at 16 / 64 / 128 / 256 / 1,024 rows, sorted 176.4-176.8 ms at each
+# (PERF.md section 7, item 26)
+DENSE_FOLD_ROWS = 1024
+
+
+def _wide_sum(specs, leaves) -> bool:
+    """Is any leaf a "sum" of 8-byte values? XLA:TPU scatters those as
+    pairs of 32-bit values at 122-126 ns per update (6.8 ns for a 32-bit
+    scatter on the same indices), so such a tree never scatters."""
     return any(s == "sum" and l.dtype.itemsize == 8
                for s, l in zip(specs, leaves))
+
+
+def _wants_sorted_fold(specs, leaves, out_cap: int) -> bool:
+    """Does the tree fold over sorted runs? Where it has a "sum" of
+    8-byte values (:func:`_wide_sum`) and its static padded range
+    ``out_cap`` has more than ``DENSE_FOLD_ROWS`` rows: the masked sums
+    of the dense fold grow with the rows, the sort, the histogram and the
+    gathers of the sorted fold do not. Decided from spec, dtype width
+    and ``out_cap`` alone, so every backend runs the path the chip
+    runs."""
+    return out_cap > DENSE_FOLD_ROWS and _wide_sum(specs, leaves)
+
+
+def _wants_dense_fold(specs, leaves, out_cap: int) -> bool:
+    """The other side of :func:`_wants_sorted_fold`'s choice: a "sum" of
+    8-byte values into at most ``DENSE_FOLD_ROWS`` rows. The whole tree
+    then folds by masked reductions, its narrower leaves too."""
+    return out_cap <= DENSE_FOLD_ROWS and _wide_sum(specs, leaves)
+
+
+def _neutral_leaves(neutral, count: int) -> list:
+    return (jax.tree.leaves(neutral) if neutral is not None
+            else [None] * count)
+
+
+def _zero_neutral(nv) -> bool:
+    """A row no item reaches sums to zero: with this neutral it needs no
+    presence mask."""
+    return nv is None or not np.any(np.asarray(nv))
+
+
+def _index_plan_kind(specs, leaves, neutral, out_cap: int):
+    """Which index plan the tree's engine reads, if any: "sorted" for
+    the fold over sorted runs; "dense" where a dense fold needs the
+    first arrival of every row (a "first", "min" or "max" field, or a
+    sum whose neutral is not zero, for the rows no item reaches); None
+    where neither (the scatter, or a dense fold of zero-neutral sums)."""
+    if _wants_sorted_fold(specs, leaves, out_cap):
+        return "sorted"
+    if _wants_dense_fold(specs, leaves, out_cap) and any(
+            s != "sum" or not _zero_neutral(nv)
+            for s, nv in zip(specs, _neutral_leaves(neutral, len(leaves)))):
+        return "dense"
+    return None
+
+
+def _index_plan(kind: str, pos, out_cap: int):
+    if kind == "sorted":
+        return segmented.sorted_fold_plan(pos, out_cap)
+    return segmented.dense_fold_plan(pos, out_cap)
 
 
 @jax.named_scope(SCATTER_SCOPE)
@@ -808,27 +874,42 @@ def _scatter_reduce_apply(tree, pos, out_cap, specs, neutral, plan=None):
     over arrival positions and one gather: 6.8 ns per update for values
     of 4 bytes or fewer on a v5e, which no sort of the rows beats. A
     "sum" of 8-byte values would cost 122-126 ns per update there
-    (XLA:TPU's two-operand scatter), so it folds over the runs of an
-    index plan instead (core/segmented.py ``sorted_fold_sum``: one
-    gather by the plan's permutation, 16 ns per row, a segmented scan
-    and a gather at the run ends); where a tree has such a leaf, its
-    "first" fields and presence masks read the same plan and the
-    scatter-min is not run. The plan sorts the 32-bit target rows, never
-    the items. Out-of-range indices are DROPPED (routed to the dump
-    row) rather than clamped like the sorted engine's clip: they cannot
-    occur through the public op (the exchange routes every item into
-    its worker's range).
+    (XLA:TPU's two-operand scatter), so a tree with such a leaf takes
+    one of two folds, by ``out_cap`` (:func:`_wants_sorted_fold`):
+
+    - into at most ``DENSE_FOLD_ROWS`` rows, the dense fold: every leaf
+      is one masked sum, min or max per row in its own dtype, all rows
+      in one pass over the leaf (core/segmented.py ``dense_fold``);
+      "first" fields and presence masks read the first arrival of every
+      row, a masked min (``dense_fold_plan``), computed only where one
+      of them needs it;
+    - into more, the fold over the runs of an index plan that sorts the
+      32-bit target rows, never the items (``sorted_fold_sum``: one
+      gather by the plan's permutation, 16 ns per row, a segmented scan
+      and a gather at the run ends); "first" fields and presence masks
+      read the same plan and the scatter-min is not run.
+
+    Out-of-range indices are DROPPED (routed to the dump row) rather
+    than clamped like the sorted engine's clip: they cannot occur
+    through the public op (the exchange routes every item into its
+    worker's range).
 
     ``pos``: target rows [cap] (:func:`_dense_pos`); ``out_cap``:
     static padded output rows; ``plan``: the index plan of ``pos``
-    where the caller has it (the fused segment's own), else it is
-    computed here when a leaf needs it. Returns the dense output
+    (:func:`_index_plan_kind`) where the caller has it (the fused
+    segment's own), else it is computed here. Returns the dense output
     tree ([out_cap, ...] leaves, neutral at untouched rows).
     """
     leaves, td = jax.tree.flatten(tree)
     cap = pos.shape[0]
-    if plan is None and _wants_sorted_fold(specs, leaves):
-        plan = segmented.sorted_fold_plan(pos, out_cap)
+    dense = _wants_dense_fold(specs, leaves, out_cap)
+    kind = _index_plan_kind(specs, leaves, neutral, out_cap)
+    if kind is not None and plan is None:
+        plan = _index_plan(kind, pos, out_cap)
+    if kind == "dense":
+        # a hoisted plan arrives as a plain tuple; its rows are ``pos``
+        plan = segmented.DenseFoldPlan(*plan)
+        pos = plan.pos
     win = None          # first-arrival winner per bin, computed lazily
 
     def winners():
@@ -841,25 +922,30 @@ def _scatter_reduce_apply(tree, pos, out_cap, specs, neutral, plan=None):
         return win
 
     def present_rows():
-        if plan is not None:
+        if kind == "sorted":
             return plan[1][1:] > plan[1][:-1]
+        if kind == "dense":
+            return plan.first < cap
         return winners() < cap
 
-    nleaves = (jax.tree.leaves(neutral) if neutral is not None
-               else [None] * len(leaves))
     outs = []
-    for s, leaf, nv in zip(specs, leaves, nleaves):
+    for s, leaf, nv in zip(specs, leaves,
+                           _neutral_leaves(neutral, len(leaves))):
         trail = leaf.shape[1:]
         if s == "first":
-            if plan is not None:
+            if kind == "sorted":
                 col, present = segmented.sorted_fold_first(leaf, plan)
+            elif kind == "dense":
+                col, present = segmented.dense_fold_first(leaf, plan)
             else:
                 w = winners()
                 col = jnp.take(leaf, jnp.clip(w, 0, cap - 1), axis=0)
                 present = w < cap
         elif s == "sum":
             from ...core import pallas_kernels as _pk
-            if leaf.dtype.itemsize == 8:
+            if dense:
+                col = segmented.dense_fold(leaf, pos, out_cap, "sum", 0)
+            elif leaf.dtype.itemsize == 8:
                 col = segmented.sorted_fold_sum(leaf, plan)
             elif (leaf.dtype == jnp.float32 and not trail
                     and _pk.pallas_enabled()
@@ -874,7 +960,7 @@ def _scatter_reduce_apply(tree, pos, out_cap, specs, neutral, plan=None):
             else:
                 col = jnp.zeros((out_cap + 1,) + trail,
                                 leaf.dtype).at[pos].add(leaf)[:out_cap]
-            if nv is None or not np.any(np.asarray(nv)):
+            if _zero_neutral(nv):
                 # zero neutral == what an untouched row sums to: skip
                 # the presence mask (the PageRank/k-means hot shape)
                 outs.append(col)
@@ -885,9 +971,12 @@ def _scatter_reduce_apply(tree, pos, out_cap, specs, neutral, plan=None):
                               if s == "min"
                               else _type_min(np.dtype(leaf.dtype)),
                               leaf.dtype)
-            base = jnp.full((out_cap + 1,) + trail, big, leaf.dtype)
-            col = (base.at[pos].min(leaf) if s == "min"
-                   else base.at[pos].max(leaf))[:out_cap]
+            if dense:
+                col = segmented.dense_fold(leaf, pos, out_cap, s, big)
+            else:
+                base = jnp.full((out_cap + 1,) + trail, big, leaf.dtype)
+                col = (base.at[pos].min(leaf) if s == "min"
+                       else base.at[pos].max(leaf))[:out_cap]
             present = present_rows()
         fill = (jnp.zeros((), leaf.dtype) if nv is None
                 else jnp.asarray(nv, leaf.dtype))
@@ -952,19 +1041,21 @@ class ReduceToIndexNode(DIABase):
             return idx, starts[widx], sizes[widx]
 
         def index_plan(fctx, tree, mask, bound_t):
-            """The index plan where a leaf folds over sorted runs: it
-            reads the index and the mask, no value, so a whole-loop
+            """The index plan of the fold over sorted runs or of a dense
+            fold that needs first arrivals (:func:`_index_plan_kind`):
+            it reads the index and the mask, no value, so a whole-loop
             program whose index is invariant runs it once, before the
             loop (api/fusion.py Segment.index_plan, api/loop.py)."""
             leaves, td = jax.tree.flatten(tree)
             sc = _scatter_fold_specs(reduce_fn, td, leaves)
-            if sc is None or not _wants_sorted_fold(sc, leaves):
+            kind = (None if sc is None
+                    else _index_plan_kind(sc, leaves, neutral, out_cap))
+            if kind is None:
                 return None
             idx, range_start, range_size = local_index(tree, bound_t)
             with jax.named_scope(SCATTER_SCOPE):
-                return segmented.sorted_fold_plan(
-                    _dense_pos(mask, idx - range_start, range_size,
-                               out_cap), out_cap)
+                return _index_plan(kind, _dense_pos(
+                    mask, idx - range_start, range_size, out_cap), out_cap)
 
         def trace(fctx, tree, mask, bound_t):
             idx, range_start, range_size = local_index(tree, bound_t)
@@ -1128,10 +1219,13 @@ class ReduceToIndexNode(DIABase):
             return mex.smap(f, 3 + len(leaves))
 
         fn = mex.cached(key, build)
-        if sc is not None and _wants_sorted_fold(sc, leaves):
+        kind = (None if sc is None
+                else _index_plan_kind(sc, leaves, neutral, out_cap))
+        if kind is not None:
             # the plan in place: every dispatch of ``fn`` counts it
             from .. import fusion
-            fusion.note_index_plans(fn, fusion.IndexPlans(count=1))
+            fusion.note_index_plans(fn, fusion.IndexPlans(
+                count=1, dense=int(kind == "dense")))
         rs = mex.put_small(bounds[:W].astype(np.int64)[:, None])
         rsz = mex.put_small(local_sizes[:, None])
         out = fn(shards.counts_device(), rs, rsz, *leaves)

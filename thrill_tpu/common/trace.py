@@ -54,9 +54,13 @@ Category / name; site; read by:
   program included, with ``index_plans``: the ``ReduceToIndex`` index
   plans the program computes in place (``api/fusion.py
   note_index_plans``; 0 on most, and 0 on the whole-loop program, whose
-  plans its ``loop`` / ``replay`` span counts);
-  ``dispatch_call_s_per_job``, ``index_plans_per_job``,
-  tests/common/test_trace.py, tests/api/test_loop_tree_carry.py. The
+  plans its ``loop`` / ``replay`` span counts): a sorted fold's plan or
+  a dense fold's (first arrivals by masked min), the dense ones also
+  in ``overall_stats()["r2i_dense_plans"]``, on no span, counted here
+  and by ``api/loop.py run_fori`` alike; ``dispatch_call_s_per_job``,
+  ``index_plans_per_job``, tests/common/test_trace.py,
+  tests/api/test_loop_tree_carry.py,
+  tests/api/test_reduce_to_index_dense.py. The
   same choke point counts ``overall_stats()["sort_keys_reused"]``, on
   no span: the sorted key words the program takes from its sort
   (``core/device_sort.py sort_words``, noted at trace time by
@@ -122,7 +126,8 @@ Named scopes (``jax.named_scope``: metadata in the compiled HLO's
 ``op_name``, no record on this spine, no operation) mark device
 operations for a device profile: ``sort_engine``, ``row_move``,
 ``exchange`` / ``send_slice``, ``join_gather``, ``reduce_to_index`` /
-``index_plan`` / ``sorted_fold``, ``segmented_reduce`` / ``run_bounds`` /
+``index_plan`` / ``sorted_fold`` / ``dense_fold`` (the dense fold and its
+plan), ``segmented_reduce`` / ``run_bounds`` /
 ``run_fold``, and since PR 36 ``window`` (``api/ops/window.py``: the
 slices, the halo and the window function) and ``prefix_sum``
 (``api/ops/prefix_sum.py``); read by a throw-away script that joins the

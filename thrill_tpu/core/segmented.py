@@ -14,10 +14,11 @@ friendly.
 
 from __future__ import annotations
 
-from typing import Any, Callable, List, Tuple
+from typing import Any, Callable, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import rowmove
 
@@ -158,7 +159,6 @@ def fields_specializable(flat_specs, leaf_dtypes) -> bool:
     (bool addition differs between numpy and the scan's `+`);
     "min"/"max" need INTEGER dtypes: the order in which NaNs and signed
     zeros meet is the generic scan's to decide, so floats keep it."""
-    import numpy as np
     for s, dt in zip(flat_specs, leaf_dtypes):
         if s == "first":
             continue
@@ -210,7 +210,8 @@ def segmented_reduce_fields(words: List[jnp.ndarray], tree: Any,
 
 
 # ----------------------------------------------------------------------
-# fold over runs sorted by a dense index (ReduceToIndex's 8-byte sums)
+# fold over runs sorted by a dense index (ReduceToIndex's 8-byte sums
+# into more rows than a dense fold takes)
 # ----------------------------------------------------------------------
 # XLA:TPU carries a 64-bit value as a pair of 32-bit ones, so a scatter
 # of 8-byte values is a two-operand scatter: 122-126 ns per update on a
@@ -303,3 +304,74 @@ def sorted_fold_first(leaf: jnp.ndarray, plan):
         first = jnp.take(perm, offsets[:-1], mode="clip")
         return (jnp.take(leaf, first, axis=0),
                 offsets[1:] > offsets[:-1])
+
+
+# ----------------------------------------------------------------------
+# dense fold into a few rows (ReduceToIndex's 8-byte sums, small range)
+# ----------------------------------------------------------------------
+# Where the index range is a few rows, each output row is one masked
+# reduction over the items, and all rows of a leaf are ONE reduce over
+# a [columns, rows, n] shape that XLA fuses with the compare: the leaf is
+# read once, nothing of that shape is written, and there is no sort, no
+# histogram, no gather of n rows and no scan. The work grows with the
+# rows, so it is the engine for a few of them only (api/ops/reduce.py
+# ``DENSE_FOLD_ROWS``). Dropped items carry the dump row ``num_rows``,
+# which no row matches.
+
+class DenseFoldPlan(NamedTuple):
+    """Index plan of a dense fold: what it reads of the index alone."""
+    pos: jnp.ndarray      # [n] int32 target rows, ``num_rows`` = dropped
+    first: jnp.ndarray    # [num_rows] int32 first arrival, n where none
+
+
+def _row_hits(pos: jnp.ndarray, num_rows: int, cols: int) -> jnp.ndarray:
+    """[cols, num_rows, n] bool, ``pos[i] == r``. Compared on the whole
+    shape: a compare broadcast from [num_rows, n] is one that XLA:TPU
+    writes to memory when both halves of a binary64 read it."""
+    shape = (cols, num_rows, pos.shape[0])
+    return jnp.broadcast_to(pos, shape) == jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1)
+
+
+def dense_fold_plan(pos: jnp.ndarray, num_rows: int) -> DenseFoldPlan:
+    """The first arrival of every row: the least ``i`` with ``pos[i] ==
+    r``, one masked min over the items."""
+    n = pos.shape[0]
+    with jax.named_scope("dense_fold"):
+        arrival = jax.lax.broadcasted_iota(jnp.int32, (1, num_rows, n), 2)
+        first = jnp.min(jnp.where(_row_hits(pos, num_rows, 1), arrival, n),
+                        axis=2)[0]
+    return DenseFoldPlan(pos, first)
+
+
+def dense_fold(leaf: jnp.ndarray, pos: jnp.ndarray, num_rows: int,
+               op: str, identity) -> jnp.ndarray:
+    """Per-row ``op`` ("sum" / "min" / "max") of ``leaf`` [n, ...] over
+    the items whose ``pos`` is the row: [num_rows, ...] in the leaf's
+    dtype, ``identity`` where a row has no item."""
+    n, trail = leaf.shape[0], leaf.shape[1:]
+    cols = int(np.prod(trail, dtype=np.int64))
+    with jax.named_scope("dense_fold"):
+        hit = _row_hits(pos, num_rows, cols)
+        vals = jnp.broadcast_to(leaf.reshape(n, cols).T[:, None, :],
+                                hit.shape)
+        masked = jnp.where(hit, vals, jnp.asarray(identity, leaf.dtype))
+        if op == "sum":
+            # in the leaf's dtype: jnp.sum widens narrow integers
+            out = jnp.sum(masked, axis=2, dtype=leaf.dtype)
+        else:
+            out = (jnp.min if op == "min" else jnp.max)(masked, axis=2)
+        return out.T.reshape((num_rows,) + trail)
+
+
+def dense_fold_first(leaf: jnp.ndarray, plan: DenseFoldPlan):
+    """The first arrival of every row off a dense plan: ``(values
+    [num_rows, ...], present [num_rows])``, a gather of ``num_rows``
+    rows; zeros where a row has no item."""
+    n = plan.pos.shape[0]
+    with jax.named_scope("dense_fold"):
+        # a plain take: ``_rows_at``'s u32[n, 2] view of a 64-bit leaf
+        # would copy all n rows to read num_rows of them
+        return (jnp.take(leaf, plan.first, axis=0, mode="fill",
+                         fill_value=0),
+                plan.first < n)

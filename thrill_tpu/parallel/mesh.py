@@ -188,8 +188,10 @@ class _CountedJit:
         # ReduceToIndex index plans that one run of this program
         # computes in place (api/fusion.py note_index_plans): every
         # dispatch adds them to ``r2i_index_plans`` and says so on its
-        # ``dispatch`` span
+        # ``dispatch`` span; of them the dense ones, which also go to
+        # ``r2i_dense_plans``
         self.index_plans = 0
+        self.dense_plans = 0
         # the name the jitted callable carries (module ``jit_<label>``
         # on the device plane) and every host span of this program
         self._trace_label: Optional[str] = label
@@ -227,6 +229,7 @@ class _CountedJit:
         mex = self._mex
         mex.stats_dispatches += 1
         mex.stats_r2i_index_plans += self.index_plans
+        mex.stats_r2i_dense_plans += self.dense_plans
         pres = mex.pressure
         if pres is not None and pres.enabled:
             # rung 1, admission control: estimate this dispatch's
@@ -320,6 +323,7 @@ class _CountedJit:
             # re-donates buffers the failed attempt may have consumed
             fn._donate_base = self
             fn.index_plans = self.index_plans
+            fn.dense_plans = self.dense_plans
             self._donating[donate_argnums] = fn
         return fn
 
@@ -428,6 +432,9 @@ class MeshExec:
         # a whole-loop program one per iteration where the index
         # changes with the carry, and one per dispatch where it does not
         self.stats_r2i_index_plans = 0
+        # of them the plans of a dense fold (first arrivals by masked
+        # min; core/segmented.py dense_fold_plan), counted alike
+        self.stats_r2i_dense_plans = 0
         # sorted key-word arrays the dispatched programs took from their
         # sort's output instead of gathering them by the permutation
         # (core/device_sort.py sort_words), counted where those are
