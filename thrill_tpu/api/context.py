@@ -938,6 +938,12 @@ class Context:
             # send blocks cut as slices of dest-sorted rows by the
             # dispatched exchange programs (data/exchange.py)
             "xchg_send_slices": mex.stats_xchg_send_slices,
+            # rows into the exchanges, those kept on their own worker,
+            # and the exchanges that ran duplicate detection's presence
+            # registers (data/exchange.py, api/ops/reduce.py)
+            "xchg_rows_in": mex.stats_xchg_rows_in,
+            "xchg_rows_local": mex.stats_xchg_rows_local,
+            "dup_detect_exchanges": mex.stats_dup_detect_exchanges,
             "bytes_wire_device": mex.stats_bytes_wire_device,
             "bytes_wire_host": mex.stats_bytes_wire_host,
             "bytes_on_wire": (mex.stats_bytes_wire_device

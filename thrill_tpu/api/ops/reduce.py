@@ -441,22 +441,29 @@ class ReduceNode(DIABase):
                 # keep colliding keys local — wrong results, not just
                 # extra traffic.
                 reg_dt = jnp.uint8 if W < 256 else jnp.int32
-                if reg_dt == jnp.uint8:
-                    # register fill through the Pallas presence kernel
-                    # where it engages (bit-identical: presence is 0/1)
-                    from ...core.pallas_kernels import presence_fill
-                    local = presence_fill(reg, mask, M)
-                else:
-                    local = jnp.zeros(M, reg_dt).at[reg].max(
-                        mask.astype(reg_dt))
-                holders = lax.psum(local, AXIS)
-                mine_only = (jnp.take(holders, reg) == 1) & \
-                    (jnp.take(local, reg) == 1)
+                # the registers' fill, psum and takes, for a profile
+                with jax.named_scope("reduce_by_key"), \
+                        jax.named_scope("dup_detect"):
+                    if reg_dt == jnp.uint8:
+                        # register fill through the Pallas presence
+                        # kernel where it engages (bit-identical:
+                        # presence is 0/1)
+                        from ...core.pallas_kernels import presence_fill
+                        local = presence_fill(reg, mask, M)
+                    else:
+                        local = jnp.zeros(M, reg_dt).at[reg].max(
+                            mask.astype(reg_dt))
+                    holders = lax.psum(local, AXIS)
+                    mine_only = (jnp.take(holders, reg) == 1) & \
+                        (jnp.take(local, reg) == 1)
                 return jnp.where(mine_only, widx.astype(jnp.int32),
                                  hash_dest)
 
+            if dup:
+                mex.stats_dup_detect_exchanges += 1
             pre = exchange.exchange(pre, dest,
-                                    ("reduce_dest", token, W, dup))
+                                    ("reduce_dest", token, W, dup),
+                                    span_fields={"dup": dup, "regs": M})
         # post-phase: final combine (reference: ReduceByHashPostPhase);
         # fusible, so the chain continues across the exchange barrier
         if fusion.enabled():

@@ -84,9 +84,20 @@ Category / name; site; read by:
   instant), ``synced``, ``sort_fused``; ``data/exchange.py``,
   ``api/ops/sort.py``; ``phase_b`` and ``sort_fused`` carry
   ``send_slices``, the send blocks their programs cut as slices (what
-  ``xchg_send_slices`` counts); ``host_plan_s_per_job`` (self time),
-  tests/common/test_doctor.py, test_trace.py (the flight dump names it),
-  tests/data/test_exchange_send_slice.py (the field).
+  ``xchg_send_slices`` counts); ``phase_a`` carries ``rows`` and, from
+  ``ReduceByKey`` (``api/ops/reduce.py ReduceNode._post_exchange``),
+  ``dup`` (duplicate detection's verdict) and ``regs`` (the presence
+  registers' width, 0 without them); ``host_plan_s_per_job`` (self
+  time), tests/common/test_doctor.py, test_trace.py (the flight dump
+  names it), tests/data/test_exchange_send_slice.py (the field),
+  tests/data/test_exchange_row_counters.py (``dup``, ``regs``). On no
+  span, counted where an exchange's traffic is accounted
+  (``data/exchange.py account_traffic``, once per exchange, a healed
+  miss once): ``overall_stats()["xchg_rows_in"]`` (the send matrix's
+  total: ``exchange_rows_per_job``) and ``["xchg_rows_local"]`` (its
+  trace: ``exchange_local_share``); and where ``ReduceNode`` dispatches
+  an exchange whose destination program fills the presence registers,
+  ``["dup_detect_exchanges"]``; tests/data/test_exchange_row_counters.py.
 * ``plan`` / decision kind (instants); ``common/decisions.py`` (each
   ledger record and audit), ``common/doctor.py`` (skew verdict);
   tests/common/test_doctor.py.
@@ -125,15 +136,19 @@ Category / name; site; read by:
 Named scopes (``jax.named_scope``: metadata in the compiled HLO's
 ``op_name``, no record on this spine, no operation) mark device
 operations for a device profile: ``sort_engine``, ``row_move``,
-``exchange`` / ``send_slice``, ``join_gather``, ``reduce_to_index`` /
+``exchange`` / ``send_slice``, ``exchange`` / ``dest_sort`` (phase A's sort
+of the destinations and its gather of every leaf), ``reduce_by_key`` /
+``dup_detect`` (the presence registers' fill, psum and takes),
+``join_gather``, ``reduce_to_index`` /
 ``index_plan`` / ``sorted_fold`` / ``dense_fold`` (the dense fold and its
 plan), ``segmented_reduce`` / ``run_bounds`` /
 ``run_fold``, and since PR 36 ``window`` (``api/ops/window.py``: the
 slices, the halo and the window function) and ``prefix_sum``
 (``api/ops/prefix_sum.py``); read by a throw-away script that joins the
 trace to the HLO (PERF.md section 7 item 9b),
-tests/api/test_suffix_rounds.py (the two new ones, in the lowered
-text).
+tests/api/test_suffix_rounds.py (``window``, ``prefix_sum``, in the
+lowered text), tests/data/test_exchange_row_counters.py (``dest_sort``,
+``dup_detect``, likewise).
 
 No cell of the benchmark runs the last four entries' planes; what nothing
 reads by name is listed under ROADMAP D7 for the PR that folds the

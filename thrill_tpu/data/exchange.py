@@ -563,7 +563,8 @@ def exchange_presorted(mex: MeshExec, treedef, sorted_dest, sorted_leaves,
 
 
 def _phase_a(shards: DeviceShards, dest_builder: Callable,
-             cache_key: Tuple, want_ranges: bool = True):
+             cache_key: Tuple, want_ranges: bool = True,
+             span_fields: Optional[dict] = None):
     """Phase A: destination, local dest-sort, send counts. Returns
     (treedef, sorted_dest, sorted_leaves, send_mat, range_mat) with the
     [W, W] send matrix REPLICATED ON DEVICE — whether the planner syncs
@@ -578,7 +579,7 @@ def _phase_a(shards: DeviceShards, dest_builder: Callable,
     whether anything READS it (the synced plan step, or an optimistic
     miss heal) is again the caller's decision. A caller whose phase B
     never narrows passes ``want_ranges=False`` and skips the analysis
-    entirely."""
+    entirely. ``span_fields`` go on the ``phase_a`` span as they are."""
     mex = shards.mesh_exec
     # an upstream optimistic exchange may still owe its overflow check:
     # heal it before this program bakes the (possibly truncated)
@@ -609,9 +610,12 @@ def _phase_a(shards: DeviceShards, dest_builder: Callable,
             dest = jnp.where(mask, jnp.clip(dest, 0, W - 1), W)
             from ..core.device_sort import sort_words
             from ..core.rowmove import take_rows_multi
-            (sorted_dest,), perm = sort_words([dest.astype(jnp.uint64)])
-            sorted_dest = sorted_dest.astype(jnp.int32)
-            sorted_ls = take_rows_multi([l[0] for l in ls], perm)
+            # the sort of the destinations and the gather behind it
+            with jax.named_scope(SCOPE), jax.named_scope("dest_sort"):
+                (sorted_dest,), perm = sort_words(
+                    [dest.astype(jnp.uint64)])
+                sorted_dest = sorted_dest.astype(jnp.int32)
+                sorted_ls = take_rows_multi([l[0] for l in ls], perm)
             # replicate the [W, W] send-count matrix: every process can
             # then fetch it locally (multi-controller safe host step)
             all_send = send_counts(sorted_dest, W)
@@ -630,7 +634,7 @@ def _phase_a(shards: DeviceShards, dest_builder: Callable,
 
     fa = mex.cached(key_a, build_a)
     with _trace.span_of(getattr(mex, "tracer", None), "exchange",
-                        "phase_a", rows=W * cap):
+                        "phase_a", rows=W * cap, **(span_fields or {})):
         out_a = fa(shards.counts_device(), *leaves)
     sorted_dest, send_mat = out_a[0], out_a[1]
     if nidx:
@@ -643,7 +647,8 @@ def _phase_a(shards: DeviceShards, dest_builder: Callable,
 
 
 def exchange(shards: DeviceShards, dest_builder: Callable, cache_key: Tuple,
-             min_cap: int = 1) -> DeviceShards:
+             min_cap: int = 1, span_fields: Optional[dict] = None
+             ) -> DeviceShards:
     """Move every valid item to the worker computed by ``dest_builder``.
 
     ``dest_builder(tree, valid_mask, worker_index) -> int32 [cap]`` is
@@ -656,6 +661,7 @@ def exchange(shards: DeviceShards, dest_builder: Callable, cache_key: Tuple,
     staying device-resident; a capacity miss is detected by a deferred
     device flag and healed by re-running the synced plan from the
     retained phase-A output (lineage-level, never wrong data).
+    ``span_fields`` go on phase A's ``exchange`` span.
     """
     mex = shards.mesh_exec
     # a loop capture is recording: leaf ranges are VALUES of loop data
@@ -665,7 +671,7 @@ def exchange(shards: DeviceShards, dest_builder: Callable, cache_key: Tuple,
     # no dead per-iteration range reductions
     treedef, sorted_dest, sorted_leaves, send_mat, range_mat = _phase_a(
         shards, dest_builder, cache_key,
-        want_ranges=mex.loop_recorder is None)
+        want_ranges=mex.loop_recorder is None, span_fields=span_fields)
     if mex.num_workers > 1:
         cap = sorted_leaves[0].shape[1] if sorted_leaves else 0
         cap_ident = _dense_cap_ident(cache_key, cap, treedef,
@@ -745,8 +751,11 @@ def account_traffic(mex: MeshExec, S: np.ndarray, item_bytes: int,
     feed the site's hot-slot detector, the ``skew_ratio`` lane fields
     of the exchange log line, and the ``kind=skew`` plan-lane
     instants ``ctx.explain()`` renders."""
-    moved = int(S.sum()) - int(np.trace(S))       # off-diagonal items
+    rows, local = int(S.sum()), int(np.trace(S))
+    moved = rows - local                          # off-diagonal items
     mex.stats_exchanges += 1
+    mex.stats_xchg_rows_in += rows
+    mex.stats_xchg_rows_local += local
     mex.stats_items_moved += moved
     mex.stats_bytes_moved += moved * item_bytes
     sid = mex.slice_id

@@ -381,6 +381,14 @@ class MeshExec:
         # shipped leaves per dense program (a chunk, Sort's fused
         # exchange-merge), one x leaves per 1-factor round
         self.stats_xchg_send_slices = 0
+        # rows that entered an exchange (the send matrix's total) and
+        # those whose destination was their own worker (its trace),
+        # added with the traffic (data/exchange.py account_traffic);
+        # exchanges whose destination program filled ReduceByKey's
+        # duplicate-detection presence registers (api/ops/reduce.py)
+        self.stats_xchg_rows_in = 0
+        self.stats_xchg_rows_local = 0
+        self.stats_dup_detect_exchanges = 0
         # per-exchange-site plan kind ('dense' = optimistic-eligible,
         # 'sync' = the site needs the host plan step every time); the
         # capacity values themselves live in _sticky_caps
