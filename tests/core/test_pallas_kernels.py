@@ -26,6 +26,48 @@ def test_partition_histogram_ignores_sentinel():
     assert got.tolist() == [1, 2, 0, 0]
 
 
+def _ids(order, n, bins):
+    """``n`` ids over ``bins`` bins in ``order``, with the sentinels
+    ``bins`` (the exchange's W) and -1 among them where ``n`` allows."""
+    rng = np.random.default_rng(n + bins)
+    if order == "one_bin":
+        d = np.full(n, bins // 2, np.int32)
+    else:
+        d = rng.integers(0, bins, n).astype(np.int32)
+    d[n // 3::97] = bins
+    d[n // 2::89] = -1
+    return np.sort(d) if order == "sorted" else d
+
+
+@pytest.mark.parametrize("bins", [1, 4, 5, pk.HIST_COMPARE_MAX_BINS,
+                                  pk.HIST_COMPARE_MAX_BINS + 1])
+@pytest.mark.parametrize("order,n", [("random", 3000), ("sorted", 3000),
+                                     ("one_bin", 1000), ("empty", 0)])
+def test_histogram_xla_path_matches_bincount(order, n, bins):
+    """The XLA path, by comparison up to ``HIST_COMPARE_MAX_BINS`` and by
+    scatter-add above it: ``int32[bins]``, sentinels outside
+    ``[0, bins)`` not counted, any input order."""
+    d = _ids(order, n, bins)
+    assert pk.histogram_path(n, bins) == (
+        "compare" if bins <= pk.HIST_COMPARE_MAX_BINS else "scatter")
+    got = pk.partition_histogram(jnp.asarray(d), bins)
+    assert got.dtype == jnp.int32 and got.shape == (bins,)
+    want = np.bincount(d[(d >= 0) & (d < bins)], minlength=bins)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("bins,scatter", [
+    (4, False), (pk.HIST_COMPARE_MAX_BINS + 1, True)])
+def test_send_histogram_lowers_without_scatter(bins, scatter):
+    """``send_counts``' histogram at W = 4 lowers to a compare and a
+    reduce, with no scatter; past the constant the scatter is back, so
+    the text is where a scatter would show."""
+    import jax
+    text = jax.jit(lambda d: pk.partition_histogram(d, bins)).lower(
+        jax.ShapeDtypeStruct((1 << 16,), jnp.int32)).as_text()
+    assert ("scatter" in text) == scatter
+
+
 @pytest.mark.parametrize("n,segs", [(100, 5), (1000, 300)])
 def test_segment_sum_matches_numpy(n, segs):
     rng = np.random.default_rng(n)

@@ -226,3 +226,25 @@ def test_dense_fold_writes_no_row_by_item_array(one_chip):
     assert f"[{n},{rows}]" not in text and f"[{rows},{n}]" not in text
     assert not re.search(r"\b(sort|scatter)\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < n * rows * 4
+
+
+def test_send_counts_compiles_to_one_reduce_on_four_chips(topo):
+    """``send_counts`` at W = 4 over 2^22 rows a chip, as phase A and
+    ``Sort``'s classification run it: the compare fuses into its reduce,
+    so no scatter runs, no ``[n, W]`` array is written and the program
+    needs no temporaries of a column's size."""
+    from thrill_tpu.data import exchange
+    mex = MeshExec(devices=topo.devices)
+    W, n = mex.num_workers, 1 << 22
+    compiled = mex.smap(lambda d: exchange.send_counts(d[0], W), 1,
+                        out_specs=P()).lower(jax.ShapeDtypeStruct(
+                            (W, n), jnp.int32,
+                            sharding=mex.sharded)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bscatter\(", text)
+    # the [W, n] compare lives inside the fusion; the program's own
+    # instructions hold no such array
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(rf"\[({W}|{W + 1}),{n}\]|\[{n},({W}|{W + 1})\]",
+                         entry)
+    assert compiled.memory_analysis().temp_size_in_bytes < n

@@ -1002,6 +1002,9 @@ class Context:
             "r2i_index_plans": mex.stats_r2i_index_plans,
             "r2i_dense_plans": mex.stats_r2i_dense_plans,
             "sort_keys_reused": mex.stats_sort_keys_reused,
+            # send histograms the dispatched programs counted by
+            # comparison (data/exchange.py send_counts)
+            "send_hists_by_compare": mex.stats_send_hists_by_compare,
             "pulls": mex.stats_pulls,
             "loop_replays": mex.stats_loop_replays,
             "loop_fori_iters": mex.stats_loop_fori_iters,

@@ -1025,9 +1025,9 @@ class LoopPlan:
         self.mex.stats_r2i_dense_plans += sum(
             c.fn.dense_plans * (1 if i in hoisted else k)
             for i, c in enumerate(calls))
-        # likewise the calls' sorted key words, taken in every iteration
-        self.mex.stats_sort_keys_reused += k * sum(
-            c.fn.sort_keys_reused for c in calls)
+        # likewise what the calls' traces noted, done in every iteration
+        for c in calls:
+            self.mex.add_noted(c.fn.noted, runs=k)
         return list(out), index_plans
 
 

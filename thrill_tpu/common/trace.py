@@ -64,9 +64,11 @@ Category / name; site; read by:
   same choke point counts ``overall_stats()["sort_keys_reused"]``, on
   no span: the sorted key words the program takes from its sort
   (``core/device_sort.py sort_words``, noted at trace time by
-  ``parallel/mesh.py note_sort_keys_reused``), and ``api/loop.py
-  run_fori`` the calls' in every iteration of a whole-loop dispatch;
-  no metric reads it, tests/core/test_sort_words.py does.
+  ``parallel/mesh.py note``), and ``api/loop.py run_fori`` the calls'
+  in every iteration of a whole-loop dispatch; no metric reads it,
+  tests/core/test_sort_words.py does. ``send_hists_by_compare`` alike:
+  the send histograms counted by comparison (``data/exchange.py
+  send_counts``), tests/data/test_send_hist_compare.py.
 * ``compile`` / program label; ``parallel/mesh.py _on_jax_duration``
   (``jax.monitoring``), a backend compile or cache load under a
   dispatch, by ``emit_span``; ``compile_s_in_window``, and taken out of

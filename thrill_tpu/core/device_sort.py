@@ -224,8 +224,8 @@ def sort_words(words: List[jnp.ndarray]
                 for a in _chunked_sorted(split, CHUNK_ROWS, idt)]
     else:
         arrs = [a[:n] for a in _bitonic_sorted(split, idt)]
-    from ..parallel.mesh import note_sort_keys_reused
-    note_sort_keys_reused(len(words))
+    from ..parallel.mesh import note
+    note("sort_keys_reused", len(words))
     return _unsplit_words(words, arrs[:-1]), arrs[-1].astype(jnp.int32)
 
 

@@ -54,6 +54,13 @@ MAX_ROWS = 1 << 24
 # tests/core/test_tpu_aot_compile.py holds both gates to the compiler)
 PRESFILL_MAX_REGS = 1 << 12
 SEGSUM_MAX_SEGS = 1 << 12
+# The XLA histogram counts up to this many bins by comparison: one fused
+# reduce of ``dest == b`` over every bin, which reads ``dest`` and writes
+# nothing of its length. Its O(n * bins) compares cost 0.6 ms at 5 bins
+# and 18.5 ms at 4,096 where the scatter-add of int64 ones costs 252 and
+# 290 ms (2^22 rows on a v5e, PERF.md); above the largest bin count
+# measured the scatter stays
+HIST_COMPARE_MAX_BINS = 1 << 12
 
 _MISSING = object()
 
@@ -154,14 +161,31 @@ def partition_histogram_pallas(dest: jnp.ndarray, num_bins: int,
     return out[:num_bins, 0]
 
 
-def partition_histogram(dest: jnp.ndarray, num_bins: int) -> jnp.ndarray:
-    """Dispatch: Pallas on TPU when enabled, else jnp.bincount.
+def histogram_path(n: int, num_bins: int) -> str:
+    """Which mechanism :func:`partition_histogram` counts ``n`` ids into
+    ``num_bins`` bins with: ``"pallas"``, ``"compare"`` or
+    ``"scatter"``. Decided by the static shapes (and the Pallas knob)."""
+    if pallas_enabled() and rows_ok(n):
+        return "pallas"
+    return "compare" if num_bins <= HIST_COMPARE_MAX_BINS else "scatter"
 
-    Both paths ignore values outside [0, num_bins) — negative or
-    too-large ids are padding sentinels, never counted.
+
+def partition_histogram(dest: jnp.ndarray, num_bins: int) -> jnp.ndarray:
+    """Count each bin value of ``dest`` into ``int32[num_bins]``: Pallas
+    on TPU when enabled, else a compare-and-sum per bin where the bins
+    are few, else a scatter-add (:func:`histogram_path`).
+
+    Every path ignores values outside [0, num_bins) — negative or
+    too-large ids are padding sentinels, never counted — and none needs
+    ``dest`` sorted.
     """
-    if pallas_enabled() and rows_ok(dest.shape[0]):
+    path = histogram_path(dest.shape[0], num_bins)
+    if path == "pallas":
         return partition_histogram_pallas(dest, num_bins)
+    if path == "compare":
+        bins = jnp.arange(num_bins, dtype=dest.dtype)
+        return jnp.sum(dest[None, :] == bins[:, None], axis=1,
+                       dtype=jnp.int32)
     sanitized = jnp.where((dest >= 0) & (dest < num_bins), dest, num_bins)
     return jnp.bincount(sanitized,
                         length=num_bins + 1)[:num_bins].astype(jnp.int32)

@@ -69,7 +69,7 @@ from ..common.config import (cap_cache_enabled, overlap_enabled,
                              round_up_pow2, xchg_narrow_enabled)
 from ..common.partition import dense_range_bounds
 from ..common.retry import default_policy
-from ..parallel.mesh import AXIS, MeshExec
+from ..parallel.mesh import AXIS, MeshExec, note
 from .shards import DeviceShards
 
 # the name the exchange's device work carries in a device profile (the
@@ -525,8 +525,12 @@ def ship_blocks(x, off, n_send, W: int, M: int):
 def send_counts(dest: jnp.ndarray, W: int) -> jnp.ndarray:
     """Traced helper (inside shard_map): per-destination send histogram,
     all-gathered into the replicated [W, W] matrix every worker needs
-    for the host planning step. ``dest`` uses W for invalid items."""
-    from ..core.pallas_kernels import partition_histogram
+    for the host planning step. ``dest`` uses W for invalid items.
+    Counted in ``overall_stats()["send_hists_by_compare"]`` per dispatch
+    where it counts by comparison."""
+    from ..core.pallas_kernels import histogram_path, partition_histogram
+    if histogram_path(dest.shape[0], W) == "compare":
+        note("send_hists_by_compare")
     send = partition_histogram(dest, W)
     return lax.all_gather(send, AXIS)
 

@@ -64,34 +64,37 @@ def current_program() -> Optional["_CountedJit"]:
     return getattr(_TL, "prog", None)
 
 
-def note_sort_keys_reused(k: int) -> None:
-    """Trace time: the program being traced takes ``k`` sorted key-word
-    arrays from its sort's output (core/device_sort.py ``sort_words``)
-    where it would have gathered them by the permutation. Kept on the
-    traced program (:func:`_noting`); outside one, nothing is noted."""
+def note(kind: str, k: int = 1) -> None:
+    """Trace time: the program being traced does ``k`` of ``kind``, a
+    counter of ``overall_stats()`` that :meth:`MeshExec.add_noted`
+    knows: ``sort_keys_reused`` (sorted key-word arrays taken from a
+    sort's output where they would have been gathered by the
+    permutation, core/device_sort.py ``sort_words``) or
+    ``send_hists_by_compare`` (send histograms counted by comparison,
+    data/exchange.py ``send_counts``). Kept on the traced program
+    (:func:`_noting`); outside one, nothing is noted."""
     notes = getattr(_TL, "notes", None)
     if notes is not None:
-        notes.append(k)
+        notes[kind] = notes.get(kind, 0) + k
 
 
 def _noting(fn: Callable) -> Callable:
-    """``fn`` keeping on itself, as ``sort_keys_reused``, what its trace
-    noted. jax traces a program once and shares that trace between
-    ``lower``, the first call and a donating twin, whichever comes
-    first, so what a trace notes lives on the traced function; a
-    program traced inside another (a call in a whole-loop body) notes
-    on its own."""
+    """``fn`` keeping on itself, as ``noted``, what its trace noted.
+    jax traces a program once and shares that trace between ``lower``,
+    the first call and a donating twin, whichever comes first, so what
+    a trace notes lives on the traced function; a program traced inside
+    another (a call in a whole-loop body) notes on its own."""
     @functools.wraps(fn)
     def traced(*args, **kwargs):
         prev = getattr(_TL, "notes", None)
-        _TL.notes = notes = []
+        _TL.notes = notes = {}
         try:
             return fn(*args, **kwargs)
         finally:
             _TL.notes = prev
-            traced.sort_keys_reused = sum(notes)
+            traced.noted = notes
 
-    traced.sort_keys_reused = 0
+    traced.noted = {}
     return traced
 
 
@@ -198,12 +201,13 @@ class _CountedJit:
         functools.update_wrapper(self, jitted, updated=())
 
     @property
-    def sort_keys_reused(self) -> int:
-        """Sorted key-word arrays one run of this program takes from its
-        sort (:func:`note_sort_keys_reused`): every dispatch adds them to
-        ``sort_keys_reused``; 0 until the program has been traced."""
+    def noted(self) -> Dict[str, int]:
+        """What one run of this program does of each counter its trace
+        noted (:func:`note`): every dispatch adds it
+        (:meth:`MeshExec.add_noted`); empty until the program has been
+        traced."""
         base = self._donate_base or self
-        return getattr(base.raw, "sort_keys_reused", 0)
+        return getattr(base.raw, "noted", {})
 
     def _label(self) -> str:
         return self._trace_label \
@@ -275,7 +279,7 @@ class _CountedJit:
         # dispatch — no allocation, no env reads.
         dt = time.perf_counter() - t0
         # after the call: a program's first call is its trace
-        mex.stats_sort_keys_reused += self.sort_keys_reused
+        mex.add_noted(self.noted)
         if dt < mex._disp_lat_min:
             mex._disp_lat_min = dt
         mex._disp_lat_n += 1
@@ -448,6 +452,10 @@ class MeshExec:
         # (core/device_sort.py sort_words), counted where those are
         # dispatched (_CountedJit._dispatch, api/loop.py run_fori)
         self.stats_sort_keys_reused = 0
+        # send histograms (data/exchange.py send_counts) the dispatched
+        # programs count by comparison, not by a scatter-add, counted
+        # alike
+        self.stats_send_hists_by_compare = 0
         # root ``stage`` spans opened (api/dia_base.py stage_span): one
         # per pull an action or a loop starts; 0 with the tracer off
         self.stats_pulls = 0
@@ -651,6 +659,14 @@ class MeshExec:
             # its threads end with this mesh where nothing closes it
             weakref.finalize(self, w.stop, 0)
         return w
+
+    def add_noted(self, noted: Dict[str, int], runs: int = 1) -> None:
+        """Count ``runs`` runs of a program whose trace noted ``noted``
+        (:func:`note`)."""
+        self.stats_sort_keys_reused += runs * noted.get(
+            "sort_keys_reused", 0)
+        self.stats_send_hists_by_compare += runs * noted.get(
+            "send_hists_by_compare", 0)
 
     def flush_device_records(self, timeout: float = 60.0) -> None:
         """Block until every upload and program handed to the watcher so
